@@ -87,7 +87,7 @@ def make_env(env_id: str | None = None, cfg: EnvConfig | None = None,
         # (smallest input the Nature conv geometry accepts), 3 balls (a
         # 6-step credit horizon); Medium: 11x11 at 44x44, 4 balls (a
         # 10-step horizon, the harder pixel certificate standing in for
-        # ALE, absent from this image; ROUND4_NOTES.md).  Rally — the
+        # ALE, absent from this image).  Rally — the
         # Pong-shaped ADVERSARIAL task (scripted opponent, edge-shot
         # mechanic — toy.RallyEnv); Small: 14-cell court at 42x42, 2
         # points (the CI-scale certificate); full: 21 at 84x84, 3 points

@@ -172,6 +172,19 @@ class FleetRegistry:
                     p.last_any = t
                     self._revive(p)
 
+    def forgive(self, seconds: float) -> None:
+        """The OBSERVER was blind for ``seconds`` — its loop sat inside a
+        blocking dispatch (a first XLA compile is tens of seconds on the
+        chip), during which in-host workers block on the full chunk queue
+        and cannot beat either.  Discount that span from every peer's
+        silence, so only time actually watched counts toward
+        SUSPECT/DEAD; a peer that truly died meanwhile still ages out
+        after ``dead_after_s`` of real observation."""
+        now = self._clock()
+        with self._lock:
+            for p in self.peers.values():
+                p.last_any = min(now, p.last_any + seconds)
+
     # -- the clock-driven half of the machine ------------------------------
 
     def tick(self) -> list[tuple[str, str, str]]:

@@ -340,8 +340,6 @@ class FusedStep:
 
         from jax.sharding import PartitionSpec as P
 
-        from apex_tpu.parallel.mesh import shard_map_compat
-
         chip = self._chip_engine
 
         def per_chip(ts, rs, c, cf, ing, bud, eps, rkeys, skeys):
@@ -362,7 +360,7 @@ class FusedStep:
         ys_spec = dict(metrics=repl, trained=repl, step_mask=repl,
                        sealed=repl, sealed_max=repl, n_trans=repl,
                        done=lanes, ep_ret=lanes, ep_len=lanes)
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             per_chip, mesh=self.mesh,
             in_specs=(repl, shard, shard, shard, repl, repl, shard,
                       P(None, "dp"), P(None, None, "dp")),
@@ -670,10 +668,10 @@ class FusedApexTrainer(ApexTrainer):
                     self._publish()
                     last_publish = now
                     last_pub_step = steps
+                self._drain_stats(steps)    # before the tick (apex.py)
                 if self.respawn_workers and now - last_health >= 5.0:
                     self._health_tick(steps)
                     last_health = now
-                self._drain_stats(steps)
                 if metrics is not None \
                         and steps - self._last_log >= log_every:
                     extra = gap.snapshot()

@@ -13,20 +13,22 @@ Mosaic double-buffers the row DMAs — fetching step ``i+1``'s row while
 step ``i`` writes back.
 
 History: the first version of this kernel hand-rolled the DMAs
-(``make_async_copy`` with a per-row semaphore array).  It passed interpret
-parity and a round-3 standalone on-chip run, then on the round-4 live chip
-it HUNG — and an orphaned on-device DMA wait wedges the device for every
+(``make_async_copy`` with a per-row semaphore array) and hung a live chip
+— and an orphaned on-device DMA wait wedges the device for every
 subsequent client, which is the worst failure mode a replay-path op can
 have.  This rewrite delegates all DMA scheduling/semaphores to Mosaic's
-pipeline machinery precisely to remove that class of deadlock; the
-hand-rolled grouping is gone until the simple form is proven on hardware.
+pipeline machinery precisely to remove that class of deadlock.
 
-The kernel is TPU-only and strictly OPT-IN until it has a clean on-chip
-record (see :func:`resolved_mode`): ``gather_mode="pallas"`` on the replay
-spec, or the process-global ``APEX_GATHER_MODE=pallas`` — which still
-gates per-operand on layout eligibility.  Everything else (CPU CI, the
-virtual mesh, un-opted TPU runs) takes ``jnp.take``; parity is pinned by
-``tests/test_gather.py`` in interpret mode.
+On-chip record: ``chip_smoke.py``'s ``gather_kernel`` stage compiles this
+kernel (not interpreted) on a TPU v5e over the full-width ring the replay
+stores (``u8[2^20, 8, 896]``) with one learner step's 4096 row ids and
+compares it bit-for-bit with ``jnp.take``.  It is correct there; whether it
+is FASTER than the XLA gather has not been measured, so the kernel stays
+strictly OPT-IN (see :func:`resolved_mode`): ``gather_mode="pallas"`` on
+the replay spec, or the process-global ``APEX_GATHER_MODE=pallas`` — which
+still gates per-operand on layout eligibility.  Everything else (CPU CI,
+the virtual mesh, un-opted TPU runs) takes ``jnp.take``; parity is also
+pinned by ``tests/test_gather.py`` in interpret mode.
 
 Mosaic constrains DMA slices of 2-D buffers to (8, 128)-tile boundaries, so
 single-row slices of ``[F, D]`` only lower when each row is itself a whole
@@ -39,8 +41,8 @@ tiling constraint.
 Reference analogue: the torch side pays this cost in
 ``_encode_sample``'s host-side ``np.stack`` of LazyFrames
 (``memory.py:348-362``) — per-sample Python decompression on the replay
-host.  Here it is one compiled device op either way; the kernel removes
-XLA's gather overhead on top.
+host.  Here it is one compiled device op either way; the kernel is meant
+to remove XLA's gather overhead on top (not measured).
 """
 
 from __future__ import annotations
@@ -104,13 +106,13 @@ def resolved_mode(frames: jax.Array, mode: str = "auto") -> str:
     fallback is visible in the recorded JSON.
 
     ``auto`` currently resolves to ``xla`` EVERYWHERE, including eligible
-    TPU layouts: the round-4 live run proved a misbehaving gather kernel
-    doesn't just fail, it can wedge the whole device for every later
-    client (module docstring).  Until the rewritten kernel has a clean
-    on-chip record, the kernel path is strictly opt-in —
-    ``APEX_GATHER_MODE=pallas`` or an explicit ``gather_mode="pallas"`` —
-    and ``bench.py`` attempts that opt-in LAST, after the safe numbers
-    are recorded."""
+    TPU layouts: the kernel is correct on the chip (module docstring) but
+    has not been timed against the XLA gather there, and a misbehaving
+    gather kernel doesn't just fail, it can wedge the whole device for
+    every later client.  Until a trace says it wins, the kernel path is
+    strictly opt-in — ``APEX_GATHER_MODE=pallas`` or an explicit
+    ``gather_mode="pallas"`` — and ``bench.py`` attempts that opt-in
+    LAST, after the other numbers are recorded."""
     if mode != "auto":
         if mode == "pallas":
             # explicit API opt-in gets the same per-operand eligibility

@@ -38,15 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from apex_tpu.parallel.mesh import shard_map_compat
 from apex_tpu.replay.device import ReplayState
 from apex_tpu.training.learner import LearnerCore
 from apex_tpu.training.state import TrainState
-
-
-def _stack_leading(tree_obj: Any, n: int) -> Any:
-    """Tile a pytree with a new leading device axis of size n."""
-    return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), tree_obj)
 
 
 @dataclass(frozen=True)
@@ -79,22 +73,27 @@ class ShardedLearner:
 
     # -- state construction ------------------------------------------------
 
-    def init_replay(self, example_item: Any) -> ReplayState:
+    def init_replay(self, example_item: Any = None) -> ReplayState:
         """Per-chip replay shards, stacked on a sharded leading axis.
 
         Total capacity = ``core.replay.capacity * n_dp`` — capacity scales
         with the slice, which is exactly how HBM grows.
-        """
-        return self.shard_replay_state(self.core.replay.init(example_item))
 
-    def shard_replay_state(self, shard: ReplayState) -> ReplayState:
-        """Tile a freshly-initialized single-shard state onto the sharded
-        leading axis (drivers that already built their replay state pass
-        it here instead of re-deriving an example item)."""
-        stacked = _stack_leading(shard, self.n_dp)
-        sharding = NamedSharding(self.mesh, P("dp"))
-        return jax.tree.map(
-            lambda x: jax.device_put(x, sharding), stacked)
+        The init runs as ONE jitted program whose outputs are declared
+        ``P("dp")``, so every chip materializes only its own ``[1, ...]``
+        slice: no ``[dp, ...]`` array and no single-shard copy ever sits
+        on one device (at 2^19 transitions a shard is ~7.5 GB — a tiled
+        copy on chip 0 would not fit a 16 GB chip).
+        """
+        n = self.n_dp
+
+        def stacked():
+            return jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (n,) + x.shape),
+                self.core.replay.init(example_item))
+
+        return jax.jit(stacked, out_shardings=NamedSharding(
+            self.mesh, P("dp")))()
 
     def replicate_train_state(self, ts: TrainState) -> TrainState:
         return jax.tree.map(
@@ -142,7 +141,7 @@ class ShardedLearner:
 
         shard = P("dp")
         repl = P()
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             per_chip, mesh=self.mesh,
             in_specs=(repl, shard, shard, shard, shard, repl),
             out_specs=(repl, shard, repl),
@@ -171,7 +170,7 @@ class ShardedLearner:
             rs = jax.tree.map(lambda x: x[None], rs)
             return new_ts, rs, metrics
 
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             per_chip, mesh=self.mesh,
             in_specs=(P(), P("dp"), P("dp"), P()),
             out_specs=(P(), P("dp"), P()),
@@ -188,7 +187,7 @@ class ShardedLearner:
             rs = core.replay.add(rs, ingest, prios[0])
             return jax.tree.map(lambda x: x[None], rs)
 
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             per_chip, mesh=self.mesh,
             in_specs=(P("dp"), P("dp"), P("dp")),
             out_specs=P("dp"),

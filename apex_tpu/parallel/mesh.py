@@ -30,23 +30,6 @@ def make_mesh(dp: int | None = None, tp: int = 1,
     return Mesh(arr, ("dp", "tp"))
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs,
-                     check_vma: bool = True):
-    """``jax.shard_map`` across JAX versions.
-
-    The top-level ``jax.shard_map`` (whose replication-check knob is
-    named ``check_vma``) landed after the 0.4.x line this image ships;
-    there the same transform lives at ``jax.experimental.shard_map``
-    with the knob named ``check_rep``.  Every shard_map in the tree goes
-    through this wrapper so the sharded plan runs on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 

@@ -465,6 +465,8 @@ def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
     if args.restore and not args.checkpoint_dir:
         raise SystemExit("--restore requires --checkpoint-dir")
+    from apex_tpu.utils.compile_cache import ensure_compile_cache
+    ensure_compile_cache()      # before the first jit; workers inherit it
     if args.trace_dir:
         # the trace ring reads the env at creation; the flag is its twin
         # (exporting here also covers worker processes, which inherit it)
@@ -501,6 +503,69 @@ def main(argv: list[str] | None = None) -> int:
 
     with profile_ctx:
         return _dispatch(args, cfg, identity)
+
+
+def build_trainer(args: argparse.Namespace, cfg: ApexConfig):
+    """The single-host drivers' trainer (``--role dqn|aql|r2d2|apex``),
+    constructed but not run: ``(trainer, train_kwargs)``.  ``_dispatch``
+    trains it; ``chip_smoke.py`` builds it the same way so the trainer's
+    counters can be read after ``train()`` returns."""
+    if args.role == "dqn":
+        from apex_tpu.training.dqn import DQNTrainer as trainer_cls
+        extra, train_kw = {}, dict(total_frames=args.total_frames)
+    elif args.role == "r2d2":
+        from apex_tpu.training.r2d2 import R2D2Trainer as trainer_cls
+        extra, train_kw = {}, dict(total_frames=args.total_frames)
+    elif args.role == "aql":
+        from apex_tpu.training.aql import AQLTrainer as trainer_cls
+        extra, train_kw = {}, dict(total_frames=args.total_frames)
+    else:
+        if args.family == "aql":
+            from apex_tpu.training.aql import AQLApexTrainer as trainer_cls
+        elif args.family == "r2d2":
+            from apex_tpu.training.r2d2 import R2D2ApexTrainer as trainer_cls
+        else:
+            from apex_tpu.training.apex import ApexTrainer as trainer_cls
+        extra = dict(train_ratio=args.train_ratio,
+                     min_train_ratio=args.min_train_ratio)
+        if args.rollout == "fused":
+            # the whole rollout -> ingest -> sample -> train ->
+            # write-back cycle as one device program per dispatch
+            # (apex_tpu/ondevice), sharded over the --mesh-dp axis;
+            # make_jax_env's ValueError names non-jittable env ids,
+            # the divisibility guards name --n-envs-per-actor /
+            # --batch-size vs --mesh-dp, and the family gate fails
+            # loud before construction
+            if args.family != "dqn":
+                raise NotImplementedError(
+                    f"--rollout fused currently serves the dqn "
+                    f"family only (got {args.family!r}) — aql/r2d2 "
+                    f"slot in behind the same scan hooks "
+                    f"(ROADMAP.md)")
+            from apex_tpu.ondevice.fused import FusedApexTrainer
+            trainer_cls = FusedApexTrainer
+            extra["rollout_len"] = args.rollout_len or None
+            extra["steps_per_dispatch"] = args.steps_per_dispatch
+        elif args.rollout == "ondevice":
+            # co-located Anakin rollouts replace the actor processes;
+            # make_jax_env raises a ValueError naming non-jittable
+            # env ids, and the family gate fails loud before any
+            # trainer construction
+            if args.family != "dqn":
+                raise NotImplementedError(
+                    f"--rollout ondevice currently serves the dqn "
+                    f"family only (got {args.family!r}) — aql/r2d2 "
+                    f"stay on the host pipeline (ROADMAP.md)")
+            from apex_tpu.training.anakin import (AnakinPool,
+                                                  make_anakin_engine)
+            engine = make_anakin_engine(
+                cfg, rollout_len=args.rollout_len or None)
+            extra["pool"] = AnakinPool(cfg, engine)
+        train_kw = dict(total_steps=args.total_steps,
+                        max_seconds=args.max_seconds)
+    return (trainer_cls(cfg, logdir=args.logdir, verbose=args.verbose,
+                        checkpoint_dir=args.checkpoint_dir, **extra),
+            train_kw)
 
 
 def _dispatch(args: argparse.Namespace, cfg: ApexConfig,
@@ -652,64 +717,7 @@ def _dispatch(args: argparse.Namespace, cfg: ApexConfig,
         print(format_fleet_table(snap))
     elif args.role in ("dqn", "aql", "r2d2", "apex"):
         # single-host drivers share one construct -> restore? -> train path
-        if args.role == "dqn":
-            from apex_tpu.training.dqn import DQNTrainer as trainer_cls
-            extra, train_kw = {}, dict(total_frames=args.total_frames)
-        elif args.role == "r2d2":
-            from apex_tpu.training.r2d2 import R2D2Trainer as trainer_cls
-            extra, train_kw = {}, dict(total_frames=args.total_frames)
-        elif args.role == "aql":
-            from apex_tpu.training.aql import AQLTrainer as trainer_cls
-            extra, train_kw = {}, dict(total_frames=args.total_frames)
-        else:
-            if args.family == "aql":
-                from apex_tpu.training.aql import \
-                    AQLApexTrainer as trainer_cls
-            elif args.family == "r2d2":
-                from apex_tpu.training.r2d2 import \
-                    R2D2ApexTrainer as trainer_cls
-            else:
-                from apex_tpu.training.apex import \
-                    ApexTrainer as trainer_cls
-            extra = dict(train_ratio=args.train_ratio,
-                         min_train_ratio=args.min_train_ratio)
-            if args.rollout == "fused":
-                # the whole rollout -> ingest -> sample -> train ->
-                # write-back cycle as one device program per dispatch
-                # (apex_tpu/ondevice), sharded over the --mesh-dp axis;
-                # make_jax_env's ValueError names non-jittable env ids,
-                # the divisibility guards name --n-envs-per-actor /
-                # --batch-size vs --mesh-dp, and the family gate fails
-                # loud before construction
-                if args.family != "dqn":
-                    raise NotImplementedError(
-                        f"--rollout fused currently serves the dqn "
-                        f"family only (got {args.family!r}) — aql/r2d2 "
-                        f"slot in behind the same scan hooks "
-                        f"(ROADMAP.md)")
-                from apex_tpu.ondevice.fused import FusedApexTrainer
-                trainer_cls = FusedApexTrainer
-                extra["rollout_len"] = args.rollout_len or None
-                extra["steps_per_dispatch"] = args.steps_per_dispatch
-            elif args.rollout == "ondevice":
-                # co-located Anakin rollouts replace the actor processes;
-                # make_jax_env raises a ValueError naming non-jittable
-                # env ids, and the family gate fails loud before any
-                # trainer construction
-                if args.family != "dqn":
-                    raise NotImplementedError(
-                        f"--rollout ondevice currently serves the dqn "
-                        f"family only (got {args.family!r}) — aql/r2d2 "
-                        f"stay on the host pipeline (ROADMAP.md)")
-                from apex_tpu.training.anakin import (AnakinPool,
-                                                      make_anakin_engine)
-                engine = make_anakin_engine(
-                    cfg, rollout_len=args.rollout_len or None)
-                extra["pool"] = AnakinPool(cfg, engine)
-            train_kw = dict(total_steps=args.total_steps,
-                            max_seconds=args.max_seconds)
-        t = trainer_cls(cfg, logdir=args.logdir, verbose=args.verbose,
-                        checkpoint_dir=args.checkpoint_dir, **extra)
+        t, train_kw = build_trainer(args, cfg)
         if args.restore:
             t.restore()
         t.train(**train_kw)

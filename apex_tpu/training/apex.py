@@ -182,6 +182,12 @@ class ConcurrentTrainer(CheckpointableTrainer):
     # episode-scalar log index for the stats drain (reset per train()
     # call; an attribute so the fused on-device loop shares the drain)
     _episode_idx = 0
+    # when the stats drain last ran (monotonic): a gap longer than the
+    # beat interval means nobody was watching the fleet — the hot loop
+    # sat in a blocking dispatch, or the driver was between train()
+    # calls — and the registry forgives it that span
+    # (FleetRegistry.forgive)
+    _last_drain = None
 
     # -- param plane -------------------------------------------------------
 
@@ -224,14 +230,16 @@ class ConcurrentTrainer(CheckpointableTrainer):
 
     # -- multi-chip plan (shared by both families) ------------------------
 
-    def _init_sharded(self) -> None:
+    def _init_sharded(self, example_item=None) -> None:
         """dp > 1: shard the replay per chip, pmean grads over ICI,
         round-robin whole chunks across shards (BASELINE.json north star:
         HBM replay + 8-chip learner).  Total replay capacity = per-chip
-        capacity x dp.  Requires ``self.core``/``self.replay_state``/
-        ``self.train_state``/``self.pool`` already built single-shard;
-        AQL's NoisyNet update key is handled by ``ShardedLearner`` via
-        ``core.update_needs_key``."""
+        capacity x dp.  Requires ``self.core``/``self.train_state``/
+        ``self.pool`` already built single-shard; the replay state is
+        built HERE, directly under the dp sharding
+        (:meth:`ShardedLearner.init_replay` — ``example_item`` is what
+        the family's ``replay.init`` takes).  AQL's NoisyNet update key is
+        handled by ``ShardedLearner`` via ``core.update_needs_key``."""
         from apex_tpu.parallel.aggregate import ChunkAggregator
         from apex_tpu.parallel.learner import ShardedLearner
         from apex_tpu.parallel.mesh import make_mesh
@@ -244,7 +252,7 @@ class ConcurrentTrainer(CheckpointableTrainer):
                 f"devices, have {len(devices)}")
         mesh = make_mesh(dp=n, devices=devices[:n])
         sl = ShardedLearner(self.core, mesh)
-        self.replay_state = sl.shard_replay_state(self.replay_state)
+        self.replay_state = sl.init_replay(example_item)
         self.train_state = sl.replicate_train_state(self.train_state)
         self.pool = ChunkAggregator(self.pool, n)
         self._make_sharded_fns(mesh)
@@ -489,11 +497,15 @@ class ConcurrentTrainer(CheckpointableTrainer):
                 # peers run the fleet registry's JOINING/ALIVE/SUSPECT/DEAD
                 # machine (config thresholds in CommsConfig — this
                 # replaced the old hardcoded silent_peers(60.0) report).
+                # drain BEFORE the health tick: after a dispatch that
+                # blocked the loop (a first compile is tens of seconds on
+                # the chip) the drain reads the queued heartbeats and
+                # forgives the span nobody was watching — judging silence
+                # first declares a healthy fleet DEAD
+                self._drain_stats(steps)
                 if self.respawn_workers and now - last_health >= 5.0:
                     self._health_tick(steps)
                     last_health = now
-
-                self._drain_stats(steps)
 
                 # metrics is None until the first train dispatch, so the
                 # gate needs no warm check — and in service mode the
@@ -605,6 +617,11 @@ class ConcurrentTrainer(CheckpointableTrainer):
         """Drain the pool's stat stream: heartbeats into the registry,
         controller snapshots into their sections, timing/episode stats
         into the scalar log.  Shared by both hot loops."""
+        now = time.monotonic()
+        blind = 0.0 if self._last_drain is None else now - self._last_drain
+        self._last_drain = now
+        if blind > self.cfg.comms.heartbeat_interval_s:
+            self.fleet.forgive(blind)
         for stat in self.pool.poll_stats():
             self.stat_drops += getattr(stat, "dropped_stats", 0)
             if isinstance(stat, Heartbeat):
@@ -1244,7 +1261,6 @@ class ConcurrentTrainer(CheckpointableTrainer):
 
         from jax.sharding import PartitionSpec as _P
 
-        from apex_tpu.parallel.mesh import shard_map_compat
         sl._per_chip_batch()    # loud divisibility check, names the knobs
 
         if needs_key:
@@ -1262,7 +1278,7 @@ class ConcurrentTrainer(CheckpointableTrainer):
                 return core.update_from_batch(ts, batch, weights,
                                               axis_name="dp")
             in_specs = (_P(), _P("dp"), _P("dp"))
-        mapped = shard_map_compat(
+        mapped = _jax.shard_map(
             per_chip, mesh=sl.mesh, in_specs=in_specs,
             out_specs=(_P(), _P("dp"), _P()), check_vma=False)
         jitted = _jax.jit(mapped, donate_argnums=(0,))
@@ -1565,10 +1581,10 @@ class ApexTrainer(ConcurrentTrainer):
                                   shm_slot_bytes=slot)
 
         self.n_dp = int(np.prod(lc.mesh_shape))
-        self.replay_state = self.replay.init()
         if self.n_dp > 1:
             self._init_sharded()
         else:
+            self.replay_state = self.replay.init()
             self._fused = self.core.jit_fused_step()
             self._train = self.core.jit_train_step()
             self._ingest = self.core.jit_ingest()

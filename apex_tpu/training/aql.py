@@ -252,7 +252,10 @@ def aql_model_spec(cfg: ApexConfig, env) -> dict:
 def build_aql(cfg: ApexConfig, model_spec: dict, obs_shape, obs_dtype,
               key: jax.Array, cosine_steps: int | None = None,
               frame_spec: tuple | None = None):
-    """(model, train_state, replay, replay_state, core) for either driver.
+    """(model, train_state, replay, example_item, core) for either driver.
+    Nothing replay-sized is allocated here: the driver builds the state
+    with ``replay.init(example_item)``, or — on a dp>1 mesh — directly
+    under the sharding (:meth:`ShardedLearner.init_replay`).
 
     ``cosine_steps``: CosineAnnealingLR horizon for both Adam groups —
     the single-process driver passes ``cfg.aql.cosine_lr_steps``
@@ -296,7 +299,7 @@ def build_aql(cfg: ApexConfig, model_spec: dict, obs_shape, obs_dtype,
         check_hbm_budget(replay.hbm_bytes(), cfg.replay.hbm_budget_gb,
                          "AQL frame-pool replay (frames + a_mu sidecars)",
                          cfg.replay.capacity)
-        replay_state = replay.init()
+        example_item = None             # shapes come from the pool spec
     else:
         replay = DeviceReplay(capacity=cfg.replay.capacity,
                               alpha=cfg.replay.alpha, eps=cfg.replay.eps)
@@ -310,13 +313,12 @@ def build_aql(cfg: ApexConfig, model_spec: dict, obs_shape, obs_dtype,
                          cfg.replay.hbm_budget_gb,
                          "AQL replay (stacked obs + a_mu candidate sets)",
                          cfg.replay.capacity)
-        replay_state = replay.init(example_item)
 
     core = AQLCore(model=model, replay=replay, optimizer=optimizer,
                    batch_size=cfg.learner.batch_size,
                    target_update_interval=cfg.learner.target_update_interval,
                    entropy_coef=cfg.aql.entropy_coef)
-    return model, train_state, replay, replay_state, core
+    return model, train_state, replay, example_item, core
 
 
 class AQLTrainer(CheckpointableTrainer):
@@ -330,11 +332,12 @@ class AQLTrainer(CheckpointableTrainer):
         self.env = make_env(cfg.env.env_id, cfg.env, seed=cfg.env.seed)
         self.model_spec = aql_model_spec(cfg, self.env)
         self.key, build_key = jax.random.split(self.key)
-        (self.model, self.train_state, self.replay, self.replay_state,
+        (self.model, self.train_state, self.replay, example_item,
          self.core) = build_aql(cfg, self.model_spec,
                                 self.env.observation_space.shape,
                                 self.env.observation_space.dtype, build_key,
                                 cosine_steps=cfg.aql.cosine_lr_steps)
+        self.replay_state = self.replay.init(example_item)
         self._train_step = self.core.jit_train_step()
         self._ingest = self.core.jit_ingest()
         self._policy = jax.jit(make_aql_policy_fn(self.model))
@@ -514,7 +517,7 @@ class AQLApexTrainer(ConcurrentTrainer):
             obs_shape, obs_dtype = frame_shape, frame_dtype
 
         self.key, build_key = jax.random.split(self.key)
-        (self.model, self.train_state, self.replay, self.replay_state,
+        (self.model, self.train_state, self.replay, example_item,
          self.core) = build_aql(cfg, self.model_spec, obs_shape, obs_dtype,
                                 build_key, frame_spec=frame_spec)
         eval_model = self.model.clone(noisy_deterministic=True)
@@ -555,8 +558,9 @@ class AQLApexTrainer(ConcurrentTrainer):
 
         self.n_dp = int(np.prod(cfg.learner.mesh_shape))
         if self.n_dp > 1:
-            self._init_sharded()
+            self._init_sharded(example_item)
         else:
+            self.replay_state = self.replay.init(example_item)
             self._fused = self.core.jit_fused_step()
             self._train = self.core.jit_train_step()
             self._ingest = self.core.jit_ingest()

@@ -336,11 +336,14 @@ def r2d2_frame_capacity(cfg: ApexConfig) -> int:
 
 
 def build_r2d2(cfg: ApexConfig, key: jax.Array):
-    """(model_spec, obs_shape, obs_dtype, model, replay, replay_state,
+    """(model_spec, obs_shape, obs_dtype, model, replay, example_item,
     train_state, core) — THE one definition of the family's replay item
     schema and core wiring, shared by the single-process and concurrent
     drivers (two hand-kept copies would let checkpoint bundles and replay
-    layouts silently diverge between them)."""
+    layouts silently diverge between them).  Nothing replay-sized is
+    allocated here: the driver builds the state with
+    ``replay.init(example_item)``, or — on a dp>1 mesh — directly under
+    the sharding (:meth:`ShardedLearner.init_replay`)."""
     rc, lc = cfg.r2d2, cfg.learner
     model_spec, obs_shape, obs_dtype = r2d2_env_specs(cfg)
     model = RecurrentDuelingDQN(**model_spec)
@@ -357,7 +360,7 @@ def build_r2d2(cfg: ApexConfig, key: jax.Array):
         check_hbm_budget(replay.hbm_bytes(), cfg.replay.hbm_budget_gb,
                          "R2D2 replay (pooled sequence storage)",
                          cfg.replay.capacity)
-        replay_state = replay.init()
+        example_item = None             # shapes come from the pool spec
     else:
         replay = DeviceReplay(capacity=cfg.replay.capacity,
                               alpha=cfg.replay.alpha, eps=cfg.replay.eps)
@@ -373,7 +376,6 @@ def build_r2d2(cfg: ApexConfig, key: jax.Array):
                          cfg.replay.hbm_budget_gb,
                          "R2D2 replay (sequence storage)",
                          cfg.replay.capacity)
-        replay_state = replay.init(example_item)
 
     optimizer = make_optimizer(
         lr=lc.lr, decay=lc.rmsprop_decay, eps=lc.rmsprop_eps,
@@ -388,7 +390,7 @@ def build_r2d2(cfg: ApexConfig, key: jax.Array):
                     batch_size=lc.batch_size,
                     target_update_interval=lc.target_update_interval,
                     burn_in=rc.burn_in, n_steps=lc.n_steps)
-    return (model_spec, obs_shape, obs_dtype, model, replay, replay_state,
+    return (model_spec, obs_shape, obs_dtype, model, replay, example_item,
             train_state, core)
 
 
@@ -440,8 +442,9 @@ class R2D2Trainer(CheckpointableTrainer):
         rc, lc = cfg.r2d2, cfg.learner
         self.key, init_key = jax.random.split(self.key)
         (self.model_spec, _obs_shape, _obs_dtype, self.model, self.replay,
-         self.replay_state, self.train_state, self.core) = build_r2d2(
+         example_item, self.train_state, self.core) = build_r2d2(
             cfg, init_key)
+        self.replay_state = self.replay.init(example_item)
         self._train_step = self.core.jit_train_step()
         self._ingest = self.core.jit_ingest()
         self._policy = jax.jit(make_recurrent_policy_fn(self.model))
@@ -617,7 +620,7 @@ class R2D2ApexTrainer(ConcurrentTrainer):
         rc, lc = cfg.r2d2, cfg.learner
         self.key, init_key = jax.random.split(self.key)
         (self.model_spec, obs_shape, obs_dtype, self.model, self.replay,
-         self.replay_state, self.train_state, self.core) = build_r2d2(
+         example_item, self.train_state, self.core) = build_r2d2(
             cfg, init_key)
         self._policy = jax.jit(make_recurrent_policy_fn(self.model))
 
@@ -643,8 +646,9 @@ class R2D2ApexTrainer(ConcurrentTrainer):
 
         self.n_dp = int(np.prod(lc.mesh_shape))
         if self.n_dp > 1:
-            self._init_sharded()
+            self._init_sharded(example_item)
         else:
+            self.replay_state = self.replay.init(example_item)
             self._fused = self.core.jit_fused_step()
             self._train = self.core.jit_train_step()
             self._ingest = self.core.jit_ingest()

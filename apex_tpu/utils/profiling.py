@@ -8,8 +8,9 @@ actually locate a bottleneck:
   TensorBoard/XProf to see per-op HBM + MXU utilization.
 * :func:`flops_per_call` / :func:`mfu` — XLA's own cost analysis for a
   jitted callable, turned into model-FLOPs-utilization given the chip's
-  peak.  This is the honest "how much of the MXU are we using" metric for
-  the fused learner step (bench.py reports it).
+  peak (:func:`device_peak_flops`, keyed by ``device_kind``).  This is
+  the honest "how much of the MXU are we using" metric for the fused
+  learner step (bench.py reports it).
 """
 
 from __future__ import annotations
@@ -23,14 +24,27 @@ import jax
 
 from apex_tpu.utils.metrics import percentile  # noqa: F401 (re-export)
 
-# bf16 peak FLOPs/s per chip for common TPU generations (public specs);
-# bench/callers can override explicitly.
+# bf16 peak FLOP/s of one chip, keyed by ``jax.devices()[0].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s).  A device that is not in the table is an error, not a
+# default — a utilization against the wrong peak is worse than none.
 PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
+    "TPU v5 lite": 197e12,
 }
-DEFAULT_PEAK = PEAK_FLOPS["v5e"]
+
+
+def device_peak_flops(device_kind: str | None = None) -> float:
+    """Peak bf16 FLOP/s for ``device_kind`` (default: the first JAX
+    device's).  Raises ``KeyError`` for a device the table does not know."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s on record for device_kind={device_kind!r} "
+            f"(known: {sorted(PEAK_FLOPS)}) — add it to "
+            f"apex_tpu.utils.profiling.PEAK_FLOPS with its source") from None
 
 
 @contextlib.contextmanager
@@ -45,20 +59,20 @@ def trace(logdir: str) -> Iterator[None]:
 
 def flops_per_call(jitted, *args, **kwargs) -> float | None:
     """XLA-estimated FLOPs of one call of a jitted function, or None when
-    the backend exposes no cost analysis (e.g. some CPU builds)."""
-    try:
-        compiled = jitted.lower(*args, **kwargs).compile()
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, list):      # one entry per device program
-            analysis = analysis[0]
-        return float(analysis["flops"])
-    except Exception:
+    the backend's cost analysis carries no FLOP count (some CPU builds).
+    Lowering and compile errors propagate."""
+    analysis = jitted.lower(*args, **kwargs).compile().cost_analysis()
+    if isinstance(analysis, list):      # one entry per device program
+        analysis = analysis[0] if analysis else None
+    if not analysis or "flops" not in analysis:
         return None
+    return float(analysis["flops"])
 
 
 def mfu(flops: float | None, calls_per_sec: float,
-        peak_flops: float = DEFAULT_PEAK) -> float | None:
-    """Model-FLOPs-utilization in [0, 1]."""
+        peak_flops: float) -> float | None:
+    """Model-FLOPs-utilization in [0, 1] against ``peak_flops``
+    (:func:`device_peak_flops` looks the running chip's up)."""
     if flops is None or peak_flops <= 0:
         return None
     return flops * calls_per_sec / peak_flops

@@ -232,8 +232,9 @@ def test_aql_fused_multi_step_matches_sequential(key):
     obs_shape = env.observation_space.shape
     spec = aql_model_spec(cfg, env)
     env.close()
-    model, ts, replay, rs, core = build_aql(
+    model, ts, replay, example_item, core = build_aql(
         cfg, spec, obs_shape, np.float32, key)
+    rs = replay.init(example_item)
     t = model.total_sample
     a_dim = spec["action_dim"]
     k_steps = 3
@@ -418,7 +419,7 @@ def test_discrete_policy_returns_int_actions(key):
 def test_discrete_aql_trainer_mechanics():
     """The full single-process AQL pipeline on a Discrete env (CartPole):
     spec routing, candidate storage, fused two-loss step, eval — the
-    capability the r3 framework refused (VERDICT missing #4)."""
+    capability the r3 framework refused."""
     cfg = small_test_config(capacity=1024, batch_size=16,
                             env_id="ApexCartPole-v0")
     cfg = cfg.replace(aql=dataclasses.replace(
@@ -437,7 +438,7 @@ def test_discrete_aql_trainer_mechanics():
 
 @pytest.mark.slow
 def test_aql_pixel_frame_pool_pipeline():
-    """Pixel AQL end to end (VERDICT r3 weak #4): 84x84x4 uint8 Catch
+    """Pixel AQL end to end: 84x84x4 uint8 Catch
     through the FRAME-POOL replay with a_mu sidecars — actor workers use
     the chunk-builder family, the learner's fused step gathers stacks on
     device and re-scores the shipped candidate sets.  Also exercises the

@@ -77,6 +77,33 @@ def test_registry_state_machine_and_rejoin_accounting():
     assert m["alive"] == 1 and m["dead_to_alive"] == 1 and m["rejoins"] == 1
 
 
+def test_registry_forgives_the_span_its_observer_was_blind():
+    """A learner loop blocked inside a dispatch (a first compile is tens
+    of seconds on the chip) watches nobody, and in-host workers blocked
+    on the full chunk queue cannot beat meanwhile: that span is not
+    silence.  Forgiven, only watched time ages a peer — and a peer that
+    really died still goes SUSPECT -> DEAD on schedule afterwards."""
+    t = [0.0]
+    comms = CommsConfig(suspect_after_s=2.0, dead_after_s=5.0)
+    reg = FleetRegistry(comms, clock=lambda: t[0])
+    reg.observe(Heartbeat("actor-0"))
+    reg.observe(Heartbeat("actor-1"))
+    reg.tick()
+
+    t[0] = 40.0                             # the observer's 40 s compile
+    reg.forgive(40.0)
+    assert reg.tick() == []
+    assert reg.metrics()["alive"] == 2 and reg.metrics()["deaths"] == 0
+
+    t[0] = 43.0                             # actor-0 resumed; actor-1
+    reg.observe(Heartbeat("actor-0"))       # died during the stall
+    assert reg.tick() == [("actor-1", ALIVE, SUSPECT)]
+    t[0] = 46.0
+    reg.observe(Heartbeat("actor-0"))
+    assert reg.tick() == [("actor-1", SUSPECT, DEAD)]
+    assert reg.peers["actor-0"].state == ALIVE
+
+
 def test_registry_merges_self_reported_rejoins_and_seen_liveness():
     """fleet_rejoins survives a learner restart: a FRESH registry credits
     the fleet's self-reported park->resume cycles; chunk-arrival times

@@ -170,4 +170,4 @@ def test_profiling_flops_and_mfu(key):
         util = profiling.mfu(flops, calls_per_sec=1000.0,
                              peak_flops=1e12)
         assert 0 < util < 1
-    assert profiling.mfu(None, 10.0) is None
+    assert profiling.mfu(None, 10.0, peak_flops=1e12) is None

@@ -8,7 +8,7 @@ import pytest
 
 from apex_tpu.models.dueling import DuelingDQN
 from apex_tpu.parallel.learner import ShardedLearner
-from apex_tpu.parallel.mesh import make_mesh, shard_map_compat
+from apex_tpu.parallel.mesh import make_mesh
 from apex_tpu.training.learner import build_learner
 
 
@@ -183,7 +183,7 @@ def test_dp8_update_matches_single_device_math(key):
         return new_ts, m
 
     shard = lambda x: x.reshape((8, 8) + x.shape[1:])  # noqa: E731
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         per_chip, mesh=mesh, in_specs=(P(), P("dp"), P("dp")),
         out_specs=(P(), P()), check_vma=False)
     ts8, m8 = jax.jit(mapped)(ts, jax.tree.map(shard, batch),
@@ -224,7 +224,7 @@ def test_apex_trainer_on_virtual_mesh():
 
 
 def test_sharded_is_weights_correct_under_skew(key):
-    """VERDICT r3 weak #5: the dp-sharded IS weights must be the correct
+    """The dp-sharded IS weights must be the correct
     bias correction for the sampler actually used — per-shard stratified
     draws — under a heavily skewed, bursty priority distribution, with a
     globally consistent normalizer (PERMethods.is_weights docstring).
@@ -272,7 +272,7 @@ def test_sharded_is_weights_correct_under_skew(key):
                                   axis_name="dp")
         return w[None], idx[None]
 
-    sample = jax.jit(shard_map_compat(
+    sample = jax.jit(jax.shard_map(
         per_chip, mesh=mesh, in_specs=(P("dp"), P("dp")),
         out_specs=(P("dp"), P("dp")), check_vma=False))
     w, idx = sample(rs, sl.device_keys(jax.random.key(3)))
@@ -340,3 +340,54 @@ def test_aql_trainer_on_virtual_mesh():
     p = jax.tree.leaves(t.train_state.params)[0]
     assert p.sharding.is_fully_replicated
     assert np.isfinite(t.evaluate(episodes=1, max_steps=30))
+
+
+def test_sharded_replay_is_born_sharded(monkeypatch):
+    """dp=4 construction never materializes an array whose every shard
+    sits on one device: ``replay.init`` runs only INSIDE the sharded jit
+    (tracers, no concrete single-shard state first), nothing is
+    ``device_put``, and every leaf of the result is laid out over four
+    distinct devices right after construction — a full-width shard is
+    ~7.5 GB, so a tiled or single-shard copy on chip 0 does not fit."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from apex_tpu.config import small_test_config
+    from apex_tpu.replay.frame_pool import FramePoolReplay
+    from apex_tpu.training.apex import ApexTrainer
+
+    concrete_inits = []
+    real_init = FramePoolReplay.init
+
+    def spying_init(self, example_item=None):
+        state = real_init(self, example_item)
+        if not isinstance(state.frames, jax.core.Tracer):
+            concrete_inits.append(state.frames.shape)
+        return state
+
+    def no_device_put(*a, **kw):
+        raise AssertionError("replay state must not be device_put: it is "
+                             "built under its sharding")
+
+    monkeypatch.setattr(FramePoolReplay, "init", spying_init)
+    cfg = small_test_config(capacity=512, batch_size=16, n_actors=2)
+    cfg = cfg.replace(learner=dataclasses.replace(cfg.learner,
+                                                  mesh_shape=(4,)))
+    trainer = ApexTrainer(cfg)
+    assert concrete_inits == []
+    rs = trainer.replay_state
+
+    monkeypatch.setattr(jax, "device_put", no_device_put)
+    rs2 = trainer.sharded.init_replay()
+
+    want = NamedSharding(trainer.sharded.mesh, P("dp"))
+    devices = set(jax.devices()[:4])
+    for state in (rs, rs2):
+        for leaf in jax.tree.leaves(state):
+            assert leaf.shape[0] == 4
+            assert leaf.sharding.is_equivalent_to(want, leaf.ndim)
+            shards = leaf.addressable_shards
+            assert {s.device for s in shards} == devices
+            assert all(s.data.shape[0] == 1 for s in shards)
+    np.testing.assert_array_equal(np.asarray(rs.size), np.zeros(4))
