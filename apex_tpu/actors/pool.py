@@ -242,10 +242,8 @@ def worker_loop(actor_id: int, cfg: ApexConfig, family, chunk_queue,
         for msg in family.poll_msgs():
             beat.note_chunk()
             obs_spans.mark_send(msg, version)
-            t0 = time.perf_counter()
-            chunk_queue.put(("chunk", actor_id, msg))     # blocks when full
-            ring.complete("chunk_put", t0, time.perf_counter() - t0,
-                          track="chunk-drain")
+            with ring.span("chunk_put", "chunk-drain"):
+                chunk_queue.put(("chunk", actor_id, msg))  # blocks when full
         if terminated or truncated:
             try:
                 stat_queue.put_nowait(

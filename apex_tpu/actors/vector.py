@@ -400,7 +400,7 @@ def vector_worker_loop(actor_id: int, cfg: ApexConfig, family, chunk_queue,
     ring = get_ring()
     # attach the trace ring to the family's existing timers: every
     # policy-wait/env-step/drain phase and every dispatch gap becomes a
-    # trace event on this role's track (sampled, bounded, host-only)
+    # trace event on this role's track (bounded, host-only)
     family.phase.ring = ring
     family.phase.track = "actor-phases"
     family.gap.ring = ring

@@ -499,11 +499,13 @@ _TIMING_CALLS = {"perf_counter", "monotonic", "perf_counter_ns",
 
 def _is_trace_context(expr: ast.AST) -> bool:
     """``with trace(...)`` / ``profiling.trace(...)`` /
-    ``jax.profiler.trace(...)`` — the sanctioned profiling scopes."""
+    ``jax.profiler.trace(...)``, or a trace ring's ``span(...)`` (one
+    ring event and one profiler annotation over the block) — the
+    sanctioned profiling scopes."""
     if not isinstance(expr, ast.Call):
         return False
     name = call_name(expr) or ""
-    return name == "trace" or name.endswith("_trace")
+    return name in ("trace", "span") or name.endswith("_trace")
 
 
 @register
@@ -894,7 +896,7 @@ class DeviceArrayOnMpQueue(Rule):
 #: span/ring emission calls of the obs plane (apex_tpu/obs) — host-side
 #: observability primitives that record NOTHING per call once traced
 _OBS_EMIT_NAMES = {"stamp", "stamp_spans", "mark_send"}
-_OBS_RING_METHODS = {"complete", "complete_wall", "instant"}
+_OBS_RING_METHODS = {"complete", "complete_wall", "instant", "span"}
 
 
 @register

@@ -7,11 +7,15 @@ Three surfaces, one unit of account — a frame chunk:
   at each hop (sealed -> send -> recv -> merge -> stage -> consume ->
   prio_wb), never into tensor payloads, so the merge/stack bit-parity
   contracts of the ingest pipeline are untouched.  The learner joins
-  them against its publish-time ledger into the two headline
-  histograms: *frame-age-at-train* and *param-propagation-lag*.
-* :mod:`apex_tpu.obs.trace` — a bounded, sampled, host-only trace-event
-  ring per process, dumped as Chrome trace-event JSON (perfetto-loadable)
-  on exit, periodically, or on SIGUSR2; :mod:`apex_tpu.obs.merge` aligns
+  them against its publish-time ledger into the headline histograms:
+  *frame-age-at-train*, *param-propagation-lag* (seconds) and *policy
+  lag* (learner steps since the version a chunk was sent under left).
+* :mod:`apex_tpu.obs.trace` — a bounded, host-only trace-event ring per
+  process, dumped as Chrome trace-event JSON (perfetto-loadable) on
+  exit, periodically, or on SIGUSR2; its ``span`` is the one span
+  primitive (a ring event and a ``jax.profiler.TraceAnnotation`` over
+  the same interval, so the profiler's trace names the same phases on
+  the device's clock); :mod:`apex_tpu.obs.merge` aligns
   the per-process clocks (heartbeat-derived offsets when a
   ``fleet_summary.json`` is present) into ONE fleet timeline.
 * :mod:`apex_tpu.obs.metrics` — Prometheus text exposition served from
@@ -34,7 +38,8 @@ Two judging layers sit on top of those signals:
   sampled each tick, emitting the machine-readable ``SOAK_*.json``
   artifact (compliance %, alert timeline, throughput vs offered load).
 
-Everything here is stdlib-only and hot-loop-safe: clock reads and deque
+Everything here is stdlib-only (the profiler annotation is imported when
+the first live span asks for it) and hot-loop-safe: clock reads and deque
 appends, no device syncs (apexlint J006) — and apexlint J010 flags any
 clock read or span emission that strays inside jit/shard_map scope.
 """

@@ -31,12 +31,16 @@ it).  Stamping is first-wins per hop, so a double-instrumented path
 (socket recv + pipeline poll) keeps the earlier, truer time.
 
 The learner-side join lives in :class:`LearnerObs`: a bounded
-publish-time ledger (version -> publish clocks) plus the two headline
-:class:`LatencyHistogram`\\ s — *frame-age-at-train* (consume wall -
-sealed wall) and *param-propagation-lag* (consume mono - publish mono of
-the version the chunk was ACTED under: how long a published policy takes
-to come back as trainable experience, the Ape-X staleness loop measured
-end to end).
+publish-time ledger (version -> publish clocks and learner step) plus
+the headline :class:`LatencyHistogram`\\ s — *frame-age-at-train*
+(consume wall - sealed wall), *param-propagation-lag* (consume mono -
+publish mono of the version the chunk was ACTED under: how long a
+published policy takes to come back as trainable experience, the Ape-X
+staleness loop measured end to end) and *policy lag*, the same loop in
+learner steps (steps now - step at which that version left).  ``pv`` is
+the version the worker held when it SENT the chunk (:func:`mark_send`),
+so both lags are those of the chunk's last transitions and a lower
+bound for its first.
 
 Everything is stdlib + host clocks: safe on hot loops (J006), and J010
 flags any of these calls straying into jit/shard_map trace scope.
@@ -135,8 +139,9 @@ def merge_spans(msgs: list, hop: str = "merge") -> list:
 
 
 class LatencyHistogram:
-    """Bounded sliding-window histogram (seconds): record floats, read
-    nearest-rank percentiles.  Pure host bookkeeping."""
+    """Bounded sliding-window histogram (seconds, or whatever unit the
+    caller records): record floats, read nearest-rank percentiles.  Pure
+    host bookkeeping."""
 
     def __init__(self, window: int = 4096):
         self._vals: deque[float] = deque(maxlen=window)
@@ -152,6 +157,10 @@ class LatencyHistogram:
         if v > self.max:
             self.max = v
 
+    def quantile(self, q: float) -> float:
+        """Nearest-rank ``q`` of the window, in the recorded unit."""
+        return percentile(sorted(self._vals), q)
+
     def snapshot(self) -> dict:
         s = sorted(self._vals)
         return {
@@ -165,22 +174,24 @@ class LatencyHistogram:
 
 
 class LearnerObs:
-    """Learner-side span join: publish ledger + the two headline
-    histograms + sampled chunk-lineage trace events.
+    """Learner-side span join: publish ledger + the headline histograms
+    + chunk-lineage trace events.
 
     Call order per consumed slot (both the pipelined and serial drains):
     :meth:`pre_consume` immediately before the dispatch (stamps
     ``consume``), :meth:`post_consume` right after the dispatch call
     returns (stamps ``prio_wb``, feeds the histograms, emits lineage
-    events).  :meth:`note_publish` records each version's publish time —
-    the join key for param-propagation-lag.
+    events and one ``consume`` event per span with ``pv``, ``lag_steps``
+    and ``age_s``).  :meth:`note_publish` records each version's publish
+    time and learner step — the join key for both lags.
     """
 
     def __init__(self, ring=None, max_versions: int = 1024,
                  clock=time.monotonic, wall=time.time):
         self.frame_age = LatencyHistogram()
         self.param_lag = LatencyHistogram()
-        self._pub: OrderedDict[int, tuple[float, float]] = OrderedDict()
+        self.policy_lag = LatencyHistogram()        # learner steps
+        self._pub: OrderedDict[int, tuple] = OrderedDict()
         self._max_versions = max_versions
         self.ring = ring
         self._clock = clock
@@ -189,17 +200,30 @@ class LearnerObs:
 
     # -- publish ledger ----------------------------------------------------
 
-    def note_publish(self, version: int) -> None:
-        self._pub[int(version)] = (self._clock(), self._wall())
+    def note_publish(self, version: int, step: int | None = None) -> None:
+        """``step``: the learner's update count as the version leaves."""
+        self._pub[int(version)] = (self._clock(), self._wall(), step)
         while len(self._pub) > self._max_versions:
             self._pub.popitem(last=False)
+
+    def _publish_step(self, pv: int) -> int | None:
+        """The step at which version ``pv`` left; for a version the
+        ledger no longer (or never) held, that of the first later one it
+        does hold — the lag then reads too low, as it does anyway (module
+        docstring), never too high."""
+        pub = self._pub.get(pv)
+        if pub is None:
+            pub = next((p for v, p in self._pub.items() if v >= pv), None)
+        return None if pub is None else pub[2]
 
     # -- consume join ------------------------------------------------------
 
     def pre_consume(self, spans) -> None:
         stamp_spans(spans, "consume")
 
-    def post_consume(self, spans) -> None:
+    def post_consume(self, spans, step: int | None = None) -> None:
+        """``step``: the learner's update count now (policy lag in steps
+        is left out where the caller gives none)."""
         if not spans:
             return
         stamp_spans(spans, "prio_wb")
@@ -208,18 +232,28 @@ class LearnerObs:
             self.spans_consumed += 1
             hops = span.get("hops", {})
             sealed = hops.get("sealed")
+            age = None
             if sealed is not None:
                 # wall clocks: the only pair comparable across the
                 # actor->learner process (or host) boundary
                 age = now_wall - sealed[1]
                 if age >= 0:
                     self.frame_age.record(age)
-            pub = self._pub.get(int(span.get("pv", -1)))
+            pv = int(span.get("pv", -1))
+            pub = self._pub.get(pv)
             if pub is not None:
                 # mono clocks: publish and consume both happen HERE
                 self.param_lag.record(max(0.0, now_mono - pub[0]))
-            if self.ring is not None:
+            lag_steps = None
+            left_at = None if step is None else self._publish_step(pv)
+            if left_at is not None:
+                lag_steps = max(0, int(step) - int(left_at))
+                self.policy_lag.record(lag_steps)
+            if self.ring is not None and self.ring.enabled:
                 self._emit_lineage(span)
+                self.ring.instant("consume", track="chunk-lineage",
+                                  args={"pv": pv, "lag_steps": lag_steps,
+                                        "age_s": age})
 
     def _emit_lineage(self, span: dict) -> None:
         """One trace event per consecutive hop pair, on the learner
@@ -246,6 +280,8 @@ class LearnerObs:
             "obs_frame_age_p99_s": fa["p99_s"],
             "obs_param_lag_p50_s": pl["p50_s"],
             "obs_param_lag_p99_s": pl["p99_s"],
+            "obs_policy_lag_p50_steps": self.policy_lag.quantile(0.50),
+            "obs_policy_lag_p99_steps": self.policy_lag.quantile(0.99),
             "obs_spans_consumed": self.spans_consumed,
         }
 

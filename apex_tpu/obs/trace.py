@@ -1,4 +1,4 @@
-"""Per-role trace ring: bounded, sampled, host-only Chrome trace events.
+"""Per-role trace ring: bounded, host-only Chrome trace events.
 
 One :class:`TraceRing` per process, enabled when ``APEX_TRACE_DIR`` is
 set (else :func:`get_ring` returns a disabled stub whose methods cost one
@@ -8,9 +8,17 @@ families' :class:`~apex_tpu.utils.profiling.PhaseTimer` /
 pipeline's staging thread, and the learner's chunk-lineage join
 (:class:`apex_tpu.obs.spans.LearnerObs`) — all of which record plain
 host clock reads into a ``deque(maxlen=...)``: no device sync ever
-(apexlint J006), no lock on the append path (GIL-atomic), and a
-``sample`` stride bounds the recording rate independently of the ring
-bound.
+(apexlint J006), no lock on the append path (GIL-atomic); the ring's
+capacity is its only bound.
+
+:meth:`TraceRing.span` is the one span primitive: a context manager that
+records one complete event in the ring AND holds a
+``jax.profiler.TraceAnnotation`` of the same name over the same
+interval, so the span is also in the profiler's ``.xplane.pb``, on the
+device trace's clock (its ``args`` come back as the event's stats).  It
+is live when the ring is enabled or a profiler trace was started through
+:func:`apex_tpu.utils.profiling.trace` (``--profile-dir``); otherwise it
+returns one shared no-op object.
 
 Two timebases per event: ``perf`` (``time.perf_counter`` — in-process
 phases/gaps) and ``wall`` (``time.time`` — chunk-lineage hops, whose
@@ -39,21 +47,85 @@ from collections import deque
 
 #: env knobs (read at ring creation)
 TRACE_DIR_ENV = "APEX_TRACE_DIR"
-SAMPLE_ENV = "APEX_TRACE_SAMPLE"
 CAPACITY_ENV = "APEX_TRACE_CAPACITY"
 FLUSH_ENV = "APEX_TRACE_FLUSH_S"
+
+
+class _NoSpan:
+    """What :meth:`TraceRing.span` hands out when nothing is live."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, or False
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first live span
+    (this module stays importable without JAX); False where it is not to
+    be had."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:           # no JAX in this role: ring only
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+class _Span:
+    """One live span: a ring event (recorded at exit, so :meth:`note` can
+    add args until then) and a profiler annotation (its args are those
+    known at entry)."""
+
+    __slots__ = ("ring", "name", "track", "args", "t0", "ann")
+
+    def __init__(self, ring, name, track, args):
+        self.ring, self.name, self.track, self.args = ring, name, track, args
+        ann = _annotation()
+        self.ann = ann(name, **(args or {})) if ann else None
+
+    def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        self.ring.complete(self.name, self.t0, dur, track=self.track,
+                           args=self.args)
+        return False
+
+    def note(self, **args) -> None:
+        """Args known only once the span's work is under way (they reach
+        the ring event; the annotation was named at entry)."""
+        self.args = {**self.args, **args} if self.args else args
 
 
 class TraceRing:
     """Bounded ring of trace events for one process."""
 
     def __init__(self, label: str, enabled: bool = True,
-                 capacity: int = 65536, sample: int = 1):
+                 capacity: int = 65536):
         self.label = label
         self.enabled = enabled
-        self.sample = max(1, int(sample))
+        # spans are live while the ring records or a profiler trace runs
+        self.live = enabled
         self._events: deque[tuple] = deque(maxlen=capacity)
-        self._n = 0
         self._tracks: dict[str, int] = {}
         self._tracks_lock = threading.Lock()
         # wall<->perf anchor: dump converts perf-timebase events to wall
@@ -77,11 +149,23 @@ class TraceRing:
         """One complete ("X") event on the perf_counter timebase."""
         if not self.enabled:
             return
-        self._n += 1
-        if self._n % self.sample:
-            return
         self._events.append(("perf", name, t0_perf, dur_s,
                              self._tid(track), args))
+
+    def span(self, name: str, track: str | None = None,
+             args: dict | None = None):
+        """``with ring.span("dispatch", track, {"it": 7}):`` — one ring
+        event and one profiler annotation over the block (module
+        docstring).  Not live: the shared no-op, for one attribute
+        check."""
+        if not self.live:
+            return _NO_SPAN
+        return _Span(self, name, track, args)
+
+    def annotate(self, on: bool) -> None:
+        """A profiler trace starts (ends): spans hold their annotation
+        even where the ring itself records nothing."""
+        self.live = self.enabled or bool(on)
 
     def complete_wall(self, name: str, t0_wall: float, dur_s: float,
                       track: str | None = None,
@@ -89,9 +173,6 @@ class TraceRing:
         """One complete event whose start is a WALL timestamp (lineage
         hops stamped in another process)."""
         if not self.enabled:
-            return
-        self._n += 1
-        if self._n % self.sample:
             return
         self._events.append(("wall", name, t0_wall, dur_s,
                              self._tid(track), args))
@@ -221,8 +302,7 @@ def get_ring() -> TraceRing:
             _RING = TraceRing(
                 label=f"pid{os.getpid()}",
                 enabled=d is not None,
-                capacity=int(os.environ.get(CAPACITY_ENV, "65536")),
-                sample=int(os.environ.get(SAMPLE_ENV, "1")))
+                capacity=int(os.environ.get(CAPACITY_ENV, "65536")))
             if d is not None:
                 _install_triggers()
     return _RING

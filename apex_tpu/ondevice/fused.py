@@ -240,14 +240,20 @@ class FusedStep:
         return ts, rs, bud, metrics, smask
 
     def _macro(self, eng, eps, carry, xs):
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
         ts, rs, c, cf, ing, bud = carry
         rkey, skeys = xs
-        c, cf, out = eng._dispatch(ts.params, eps, c, cf, rkey)
+        # trace scopes: ``rollout`` here; the masked chunk ingests, the
+        # sampling, the update and the write-back below carry the step
+        # program's five names from where that work lives (replay/,
+        # training/learner.td_update)
+        with jax.named_scope("rollout"):
+            c, cf, out = eng._dispatch(ts.params, eps, c, cf, rkey)
+            prios = acting_priorities(out)                   # [B, M, K]
         B, M = eng.B, eng.M
-        prios = acting_priorities(out)                       # [B, M, K]
         sealed = out["sealed"]                               # [B]
         mask = jnp.arange(M, dtype=jnp.int32)[None, :] < sealed[:, None]
 
@@ -529,6 +535,8 @@ class FusedApexTrainer(ApexTrainer):
     CLI/role wiring.
     """
 
+    _loop_track = "learner-fused-loop"
+
     def __init__(self, config: ApexConfig | None = None,
                  logdir: str | None = None, verbose: bool = False,
                  publish_min_seconds: float = 0.2,
@@ -583,11 +591,11 @@ class FusedApexTrainer(ApexTrainer):
         if self.actor_timing is None:
             self.actor_timing = {}
         set_process_label("learner")
-        ring = get_ring()
+        ring = self._ring = get_ring()
         if self._obs is None:
             self._obs = obs_spans.LearnerObs(ring=ring)
         gap = self._dispatch_gap = DispatchGapTimer(
-            ring=ring, track="learner-fused-loop")
+            ring=ring, track=self._loop_track)
         if self.fleet is None:
             self.fleet = FleetRegistry(cfg.comms)
         pool.start()
@@ -616,74 +624,84 @@ class FusedApexTrainer(ApexTrainer):
                 stop = self._stop_requested
                 if now > t_end or (stop is not None and stop.is_set()):
                     break
-                gap.about_to_dispatch()
-                (self.train_state, self.replay_state, self.key,
-                 info) = self.fused.dispatch(
-                    self.train_state, self.replay_state, self.key)
-                gap.dispatch_returned()
-                if info["train_steps"]:
-                    self.steps_rate.tick(info["train_steps"])
-                    if info["metrics"] is not None:
-                        metrics = info["metrics"]
-                self.ingested += info["transitions"]
-                self.frames_rate.tick(info["transitions"])
-                for stat in info["stats"]:
-                    self.log.scalars(
-                        {"episode_reward": stat.reward,
-                         "episode_length": stat.length,
-                         "actor_id": stat.actor_id}, self._episode_idx)
-                    self._episode_idx += 1
-                # hybrid: host-actor chunks absorb between dispatches
-                # (ingest-only — the fused program owns the train cadence)
-                for msg in pool.poll_chunks(64, timeout=0):
-                    self.replay_state = self._ingest(
-                        self.replay_state, msg["payload"],
-                        jnp.asarray(msg["priorities"]))
-                    n_new = int(msg["n_trans"])
-                    self.ingested += n_new
-                    self.frames_rate.tick(n_new)
-                    self.fused.note_external_ingest(n_new)
-                beat.tick(info["frames"])
-                hb = beat.maybe_beat(self.param_version)
-                if hb is not None:
-                    self.fleet.observe(hb)
+                self._pass += 1
+                with self._span("loop_iter", kind="fused"):
+                    with self._dispatch(
+                            "fused", self.fused.dispatch,
+                            program="jit_" + self.fused._jit.__name__) as call:
+                        (self.train_state, self.replay_state, self.key,
+                         info) = call(self.train_state, self.replay_state,
+                                      self.key)
+                    if info["train_steps"]:
+                        self.steps_rate.tick(info["train_steps"])
+                        if info["metrics"] is not None:
+                            metrics = info["metrics"]
+                    self.ingested += info["transitions"]
+                    self.frames_rate.tick(info["transitions"])
+                    for stat in info["stats"]:
+                        self.log.scalars(
+                            {"episode_reward": stat.reward,
+                             "episode_length": stat.length,
+                             "actor_id": stat.actor_id}, self._episode_idx)
+                        self._episode_idx += 1
+                    # hybrid: host-actor chunks absorb between dispatches
+                    # (ingest-only — the fused program owns the train cadence)
+                    with self._span("poll_slot"):
+                        msgs = pool.poll_chunks(64, timeout=0)
+                    for msg in msgs:
+                        self.replay_state = self._ingest(
+                            self.replay_state, msg["payload"],
+                            jnp.asarray(msg["priorities"]))
+                        n_new = int(msg["n_trans"])
+                        self.ingested += n_new
+                        self.frames_rate.tick(n_new)
+                        self.fused.note_external_ingest(n_new)
+                    beat.tick(info["frames"])
+                    hb = beat.maybe_beat(self.param_version)
+                    if hb is not None:
+                        self.fleet.observe(hb)
 
-                steps = self.steps_rate.total
-                if (self.checkpointer is not None
-                        and steps - self._last_save
-                        >= cfg.learner.save_interval):
-                    self.save_checkpoint()
-                    self._last_save = steps
-                if steps:
-                    due = (now - last_publish >= self.publish_min_seconds
-                           and (steps - last_pub_step
-                                >= cfg.learner.publish_interval
-                                or now - last_publish
-                                > 10 * self.publish_min_seconds))
-                else:
-                    due = (getattr(pool, "needs_warmup_republish", False)
-                           and now - last_publish
-                           > 10 * self.publish_min_seconds)
-                if due:
-                    self._publish()
-                    last_publish = now
-                    last_pub_step = steps
-                self._drain_stats(steps)    # before the tick (apex.py)
-                if self.respawn_workers and now - last_health >= 5.0:
-                    self._health_tick(steps)
-                    last_health = now
-                if metrics is not None \
-                        and steps - self._last_log >= log_every:
-                    extra = gap.snapshot()
-                    if self._obs is not None:
-                        extra |= self._obs.scalars()
-                    self.log.scalars(
-                        {k: float(v) for k, v in metrics.items()}
-                        | {"bps": self.steps_rate.rate,
-                           "fps": self.frames_rate.rate,
-                           "param_version": self.param_version,
-                           "ingested": self.ingested} | extra, steps)
-                    self._last_log = steps
+                    steps = self.steps_rate.total
+                    if (self.checkpointer is not None
+                            and steps - self._last_save
+                            >= cfg.learner.save_interval):
+                        with self._span("checkpoint"):
+                            self.save_checkpoint()
+                        self._last_save = steps
+                    if steps:
+                        due = (now - last_publish >= self.publish_min_seconds
+                               and (steps - last_pub_step
+                                    >= cfg.learner.publish_interval
+                                    or now - last_publish
+                                    > 10 * self.publish_min_seconds))
+                    else:
+                        due = (getattr(pool, "needs_warmup_republish", False)
+                               and now - last_publish
+                               > 10 * self.publish_min_seconds)
+                    if due:
+                        self._publish()
+                        last_publish = now
+                        last_pub_step = steps
+                    with self._span("drain_stats"):
+                        self._drain_stats(steps)  # before the tick (apex.py)
+                    if self.respawn_workers and now - last_health >= 5.0:
+                        with self._span("health_tick"):
+                            self._health_tick(steps)
+                        last_health = now
+                    if metrics is not None \
+                            and steps - self._last_log >= log_every:
+                        with self._span("log_scalars"):
+                            extra = gap.snapshot()
+                            if self._obs is not None:
+                                extra |= self._obs.scalars()
+                            self.log.scalars(
+                                {k: float(v) for k, v in metrics.items()}
+                                | {"bps": self.steps_rate.rate,
+                                   "fps": self.frames_rate.rate,
+                                   "param_version": self.param_version,
+                                   "ingested": self.ingested} | extra,
+                                steps)
+                        self._last_log = steps
         finally:
             if self._fleet_status is not None:
                 self._fleet_status.stop()

@@ -19,11 +19,14 @@ class PERMethods:
     """Mixin over frozen replay specs with ``alpha``/``eps`` fields and
     states carrying ``sum_tree``/``min_tree``/``size``/``max_priority``."""
 
+    @jax.named_scope("writeback")
     def update_priorities(self, state, idx: jax.Array,
                           priorities: jax.Array):
         """Store ``priority ** alpha`` and track the running max
         (``memory.py:300-320``).  Duplicate ``idx`` entries must carry equal
-        values (they do on every call path: duplicates share batch rows)."""
+        values (they do on every call path: duplicates share batch rows).
+        Traced under the scope ``writeback`` (one of the step program's
+        five, see :func:`apex_tpu.training.learner.td_update`)."""
         p_alpha = self._to_tree_priority(priorities)
         sum_tree, min_tree = tree_ops.update_both(
             state.sum_tree, state.min_tree, idx, p_alpha)

@@ -91,6 +91,7 @@ class DeviceReplay(PERMethods):
 
     # -- mutation (pure) ---------------------------------------------------
 
+    @jax.named_scope("ingest")
     def add(self, state: ReplayState, batch: Any,
             priorities: jax.Array) -> ReplayState:
         """Fused ring-write + priority set for K transitions."""
@@ -123,8 +124,11 @@ class DeviceReplay(PERMethods):
         """Returns ``(batch, weights, idx)``; weights normalized by max
         weight (globally, via collectives, when ``axis_name`` names a
         sharded mesh axis — see :meth:`PERMethods.is_weights`)."""
-        idx = tree_ops.stratified_sample(state.sum_tree, key, batch_size,
-                                         state.size)
-        batch = jax.tree.map(lambda s: s[idx], state.storage)
-        weights = self.is_weights(state, idx, beta, axis_name=axis_name)
+        with jax.named_scope("sample"):
+            idx = tree_ops.stratified_sample(state.sum_tree, key, batch_size,
+                                             state.size)
+        with jax.named_scope("gather"):
+            batch = jax.tree.map(lambda s: s[idx], state.storage)
+        with jax.named_scope("sample"):
+            weights = self.is_weights(state, idx, beta, axis_name=axis_name)
         return batch, weights, idx

@@ -200,6 +200,7 @@ class FramePoolReplay(PERMethods):
 
     # -- mutation (pure) ---------------------------------------------------
 
+    @jax.named_scope("ingest")
     def add(self, state: FramePoolState, chunk: dict,
             priorities: jax.Array, valid=None) -> FramePoolState:
         """Ingest one self-contained chunk (see module docstring).
@@ -354,20 +355,24 @@ class FramePoolReplay(PERMethods):
         have aged out of the ring are redirected to the newest slot.  i32
         wraparound in the epoch difference is safe for ages < 2^31.
         """
-        idx = tree_ops.stratified_sample(state.sum_tree, key, batch_size,
-                                         state.size)
-        age = state.f_epoch - state.frame_epoch[idx]
-        newest = (state.pos - 1) % self.capacity
-        idx = jnp.where(age <= self.f_capacity, idx, newest)
-        batch = dict(
-            obs=self._gather_stacks(state, state.obs_ids[idx]),
-            action=state.action[idx],
-            reward=state.reward[idx],
-            next_obs=self._gather_stacks(state, state.next_ids[idx]),
-            discount=state.discount[idx],
-            **{name: state.extras[name][idx] for name, _ in self.extra_spec},
-        )
-        weights = self.is_weights(state, idx, beta, axis_name=axis_name)
+        with jax.named_scope("sample"):
+            idx = tree_ops.stratified_sample(state.sum_tree, key, batch_size,
+                                             state.size)
+            age = state.f_epoch - state.frame_epoch[idx]
+            newest = (state.pos - 1) % self.capacity
+            idx = jnp.where(age <= self.f_capacity, idx, newest)
+        with jax.named_scope("gather"):
+            batch = dict(
+                obs=self._gather_stacks(state, state.obs_ids[idx]),
+                action=state.action[idx],
+                reward=state.reward[idx],
+                next_obs=self._gather_stacks(state, state.next_ids[idx]),
+                discount=state.discount[idx],
+                **{name: state.extras[name][idx]
+                   for name, _ in self.extra_spec},
+            )
+        with jax.named_scope("sample"):
+            weights = self.is_weights(state, idx, beta, axis_name=axis_name)
         return batch, weights, idx
 
     def _gather_stacks(self, state: FramePoolState,

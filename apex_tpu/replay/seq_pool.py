@@ -170,6 +170,7 @@ class SequenceFramePoolReplay(PERMethods):
 
     # -- mutation (pure) ---------------------------------------------------
 
+    @jax.named_scope("ingest")
     def add(self, state: SequenceFramePoolState, chunk: dict,
             priorities: jax.Array) -> SequenceFramePoolState:
         """Ingest one self-contained pooled sequence message.
@@ -255,21 +256,24 @@ class SequenceFramePoolReplay(PERMethods):
         """Stratified PER sample; returns ``(batch, weights, idx)`` with
         the SAME batch schema as the stacked sequence layout — ``obs``
         gathered ``[B, T, *frame_shape]`` from the ring."""
-        idx = tree_ops.stratified_sample(state.sum_tree, key, batch_size,
-                                         state.size)
-        age = state.f_epoch - state.frame_epoch[idx]
-        newest = (state.pos - 1) % self.capacity
-        idx = jnp.where(age <= self.f_capacity, idx, newest)
-        batch = dict(
-            obs=self._gather_sequences(state, state.obs_ids[idx]),
-            action=state.action[idx],
-            reward=state.reward[idx],
-            discount=state.discount[idx],
-            mask=state.mask[idx],
-            state_c=state.state_c[idx],
-            state_h=state.state_h[idx],
-        )
-        weights = self.is_weights(state, idx, beta, axis_name=axis_name)
+        with jax.named_scope("sample"):
+            idx = tree_ops.stratified_sample(state.sum_tree, key, batch_size,
+                                             state.size)
+            age = state.f_epoch - state.frame_epoch[idx]
+            newest = (state.pos - 1) % self.capacity
+            idx = jnp.where(age <= self.f_capacity, idx, newest)
+        with jax.named_scope("gather"):
+            batch = dict(
+                obs=self._gather_sequences(state, state.obs_ids[idx]),
+                action=state.action[idx],
+                reward=state.reward[idx],
+                discount=state.discount[idx],
+                mask=state.mask[idx],
+                state_c=state.state_c[idx],
+                state_h=state.state_h[idx],
+            )
+        with jax.named_scope("sample"):
+            weights = self.is_weights(state, idx, beta, axis_name=axis_name)
         return batch, weights, idx
 
     def _gather_sequences(self, state: SequenceFramePoolState,

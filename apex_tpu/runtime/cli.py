@@ -465,12 +465,13 @@ def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
     if args.restore and not args.checkpoint_dir:
         raise SystemExit("--restore requires --checkpoint-dir")
-    from apex_tpu.utils.compile_cache import ensure_compile_cache
-    ensure_compile_cache()      # before the first jit; workers inherit it
     if args.trace_dir:
         # the trace ring reads the env at creation; the flag is its twin
         # (exporting here also covers worker processes, which inherit it)
         os.environ["APEX_TRACE_DIR"] = args.trace_dir
+    from apex_tpu.utils.compile_cache import ensure_compile_cache
+    # before the first jit; workers inherit it
+    ensure_compile_cache(traced=bool(args.profile_dir))
     if args.tenant:
         # the tenant namespace reads the env at each qualification site
         # (tenancy/namespace.current_tenant); exporting here covers the

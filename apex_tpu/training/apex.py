@@ -25,6 +25,7 @@ independent processes, and the learner thread blocks only on device results.
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 import jax
@@ -104,6 +105,12 @@ class ConcurrentTrainer(CheckpointableTrainer):
     _ingest_multi = None
     _dispatch_gap = None
     _pipeline_last_stats = None
+    # host spans of the hot loop (_span / _dispatch): the process's trace
+    # ring, the track the loop's events go to, and the pass under way
+    _ring = None
+    _loop_track = "learner-hot-loop"
+    _pass = 0
+    _pass_kind = "idle"
     # checkpoint/log bookkeeping persists ACROSS train() calls: a driver
     # interleaving short train() bursts with eval must still hit its
     # save/log cadence (per-call resets would silence both whenever
@@ -193,9 +200,15 @@ class ConcurrentTrainer(CheckpointableTrainer):
 
     def _publish(self) -> None:
         self.param_version += 1
+        with self._span("publish_handoff", version=self.param_version):
+            self._publish_params()
+
+    def _publish_params(self) -> None:
         if self._obs is not None:
-            # the param-propagation-lag join key: when THIS version left
-            self._obs.note_publish(self.param_version)
+            # the join key of both policy lags: when, and at which
+            # learner step, THIS version left
+            self._obs.note_publish(self.param_version,
+                                   self.steps_rate.total)
         if self._pipeline is not None:
             # hand the staging thread an on-device COPY: the hot loop's
             # next fused step donates train_state, which would invalidate
@@ -285,6 +298,8 @@ class ConcurrentTrainer(CheckpointableTrainer):
         def _train(ts, rs, key, beta):
             return train(ts, rs, _keys(key), beta)
 
+        # _dispatch names a program after its callable
+        _fused.__name__, _train.__name__ = fused.__name__, train.__name__
         self._fused, self._train, self._ingest = _fused, _train, ingest
 
     # -- main loop ---------------------------------------------------------
@@ -302,11 +317,11 @@ class ConcurrentTrainer(CheckpointableTrainer):
         from apex_tpu.obs.trace import get_ring, set_process_label
         from apex_tpu.utils.profiling import DispatchGapTimer
         set_process_label("learner")
-        ring = get_ring()
+        ring = self._ring = get_ring()
         if self._obs is None:
             self._obs = obs_spans.LearnerObs(ring=ring)
         gap = self._dispatch_gap = DispatchGapTimer(ring=ring,
-                                                    track="learner-hot-loop")
+                                                    track=self._loop_track)
         client = self.replay_client
         if client is not None and self._train_batch is None:
             # dp>1 included: _make_batch_train shards the service batch
@@ -372,164 +387,176 @@ class ConcurrentTrainer(CheckpointableTrainer):
                 stop = self._stop_requested
                 if now > t_end or (stop is not None and stop.is_set()):
                     break
-                # ``warm`` gates the LOCAL replay's train paths (train-only
-                # steps, fused chunk-train) — in service mode the local
-                # pool only fills through the fallback, so those paths
-                # stay cold until it genuinely warms.  The ratio budget
-                # and floor run on the EFFECTIVE ingest count (local +
-                # what the shard fleet reports), so service-mode training
-                # is budgeted against real fleet-wide ingest.
-                warm = self.ingested >= cfg.replay.warmup
-                ingested_eff = self.ingested + (
-                    client.ingested_total() if client is not None else 0)
-                consumed = self.steps_rate.total * self.core.batch_size
-                budget = (float("inf") if self.train_ratio is None
-                          else ingested_eff * self.train_ratio
-                          / self.core.batch_size)
-                # Replay-ratio floor: learner behind -> pause draining so the
-                # bounded chunk queue backpressures the actor fleet.  The
-                # EFFECTIVE floor is None while the dead-fleet reaction
-                # has relaxed it (see _react_to_fleet).
-                floor = self._min_ratio_effective()
-                behind = (warm and floor is not None
-                          and consumed < ingested_eff * floor)
+                self._pass += 1
+                self._pass_kind = "idle"
+                with self._span("loop_iter") as this_pass:
+                    # ``warm`` gates the LOCAL replay's train paths (train-only
+                    # steps, fused chunk-train) — in service mode the local
+                    # pool only fills through the fallback, so those paths
+                    # stay cold until it genuinely warms.  The ratio budget
+                    # and floor run on the EFFECTIVE ingest count (local +
+                    # what the shard fleet reports), so service-mode training
+                    # is budgeted against real fleet-wide ingest.
+                    warm = self.ingested >= cfg.replay.warmup
+                    ingested_eff = self.ingested + (
+                        client.ingested_total() if client is not None else 0)
+                    consumed = self.steps_rate.total * self.core.batch_size
+                    budget = (float("inf") if self.train_ratio is None
+                              else ingested_eff * self.train_ratio
+                              / self.core.batch_size)
+                    # Replay-ratio floor: learner behind -> pause draining so the
+                    # bounded chunk queue backpressures the actor fleet.  The
+                    # EFFECTIVE floor is None while the dead-fleet reaction
+                    # has relaxed it (see _react_to_fleet).
+                    floor = self._min_ratio_effective()
+                    behind = (warm and floor is not None
+                              and consumed < ingested_eff * floor)
 
-                got_data = False
-                if pipeline is not None:
-                    # pipelined: consume ready-on-device slots; the
-                    # staging thread already polled/decoded/merged/staged
-                    # while the previous dispatch ran.  Service mode
-                    # consumes even when "behind" — behind means the
-                    # learner owes MORE training, and batch slots are
-                    # exactly that
-                    slot = None
-                    if not behind or client is not None:
-                        slot = pipeline.poll_slot(
-                            timeout=0 if (warm or client is not None)
-                            else 0.05)
-                    if slot is not None:
-                        got_data = True
-                        m = self._consume_slot(slot, warm, budget,
-                                               target_steps)
-                        if m is not None:
-                            metrics = m
-                else:
-                    if client is not None \
-                            and self.steps_rate.total < budget:
-                        # serial service path: one pre-sampled batch per
-                        # iteration, write-back shipped inline
-                        item = client.poll_batch(timeout=0.02)
-                        if item is not None:
+                    got_data = False
+                    if pipeline is not None:
+                        # pipelined: consume ready-on-device slots; the
+                        # staging thread already polled/decoded/merged/staged
+                        # while the previous dispatch ran.  Service mode
+                        # consumes even when "behind" — behind means the
+                        # learner owes MORE training, and batch slots are
+                        # exactly that
+                        slot = None
+                        if not behind or client is not None:
+                            with self._span("poll_slot"):
+                                slot = pipeline.poll_slot(
+                                    timeout=0 if (warm or client is not None)
+                                    else 0.05)
+                        if slot is not None:
                             got_data = True
-                            m = self._consume_slot(
-                                self._host_batch_slot(item), warm, budget,
-                                target_steps)
+                            m = self._consume_slot(slot, warm, budget,
+                                                   target_steps)
                             if m is not None:
                                 metrics = m
-                    # serial: scan dispatch (config.scan_steps > 1) asks
-                    # for K chunks only when the learner can take all K
-                    # steps within BOTH the ratio budget and the
-                    # remaining total_steps contract ("run total_steps
-                    # MORE updates" — a K-dispatch must not overshoot
-                    # it) — exactly the chunk-backlog regime where
-                    # dispatch latency, not data supply, bounds throughput
-                    want = 1
-                    if (self._multi is not None and warm
-                            and target_steps - self.steps_rate.total
-                            >= self.scan_steps
-                            and self.steps_rate.total + self.scan_steps - 1
-                            < budget):
-                        want = self.scan_steps
+                    else:
+                        if client is not None \
+                                and self.steps_rate.total < budget:
+                            # serial service path: one pre-sampled batch per
+                            # iteration, write-back shipped inline
+                            with self._span("poll_slot"):
+                                item = client.poll_batch(timeout=0.02)
+                            if item is not None:
+                                got_data = True
+                                m = self._consume_slot(
+                                    self._host_batch_slot(item), warm, budget,
+                                    target_steps)
+                                if m is not None:
+                                    metrics = m
+                        # serial: scan dispatch (config.scan_steps > 1) asks
+                        # for K chunks only when the learner can take all K
+                        # steps within BOTH the ratio budget and the
+                        # remaining total_steps contract ("run total_steps
+                        # MORE updates" — a K-dispatch must not overshoot
+                        # it) — exactly the chunk-backlog regime where
+                        # dispatch latency, not data supply, bounds throughput
+                        want = 1
+                        if (self._multi is not None and warm
+                                and target_steps - self.steps_rate.total
+                                >= self.scan_steps
+                                and self.steps_rate.total + self.scan_steps - 1
+                                < budget):
+                            want = self.scan_steps
 
-                    msgs = []
-                    if not behind:
-                        msgs = pool.poll_chunks(want,
-                                                timeout=0 if warm else 0.05)
-                    if msgs:
-                        got_data = True
-                        m = self._drain_serial(msgs, want, warm, budget)
-                        if m is not None:
-                            metrics = m
-                if not got_data and warm \
-                        and self.steps_rate.total < budget:
-                    k = self._dispatch_key()
-                    gap.about_to_dispatch()
-                    self.train_state, self.replay_state, metrics = \
-                        self._train(self.train_state, self.replay_state, k,
-                                    jnp.float32(self._beta()))
-                    gap.dispatch_returned()
-                    self.steps_rate.tick()
-                elif not got_data and warm:
-                    time.sleep(0.002)   # replay-ratio cap reached
+                        msgs = []
+                        if not behind:
+                            with self._span("poll_slot"):
+                                msgs = pool.poll_chunks(
+                                    want, timeout=0 if warm else 0.05)
+                        if msgs:
+                            got_data = True
+                            m = self._drain_serial(msgs, want, warm, budget)
+                            if m is not None:
+                                metrics = m
+                    if not got_data and warm \
+                            and self.steps_rate.total < budget:
+                        k = self._dispatch_key()
+                        with self._dispatch("train", self._train,
+                                            beta=self._beta) as call:
+                            self.train_state, self.replay_state, metrics = \
+                                call(self.train_state, self.replay_state, k)
+                        self.steps_rate.tick()
+                    elif not got_data and warm:
+                        with self._span("ratio_sleep"):
+                            time.sleep(0.002)   # replay-ratio cap reached
 
-                steps = self.steps_rate.total
-                if (self.checkpointer is not None
-                        and steps - self._last_save
-                        >= cfg.learner.save_interval):
-                    self.save_checkpoint()
-                    self._last_save = steps
-                # Pre-first-step republish (slow cadence) is needed only for
-                # socket pools: a TCP subscriber that joined after the
-                # initial publish would otherwise never receive params
-                # (PUB/SUB has no replay — the zmq slow-joiner race) and an
-                # actor fleet without params produces no chunks: deadlock.
-                # mp pools have pre-existing queues, so the initial publish
-                # cannot be lost and warmup republishes would only burn the
-                # ingest thread on param serialization.
-                if steps:
-                    due = (now - last_publish >= self.publish_min_seconds
-                           and (steps - last_pub_step
-                                >= cfg.learner.publish_interval
-                                or now - last_publish
-                                > 10 * self.publish_min_seconds))
-                else:
-                    due = (getattr(pool, "needs_warmup_republish", False)
-                           and now - last_publish
-                           > 10 * self.publish_min_seconds)
-                if due:
-                    self._publish()
-                    last_publish = now
-                    last_pub_step = steps
+                    steps = self.steps_rate.total
+                    if (self.checkpointer is not None
+                            and steps - self._last_save
+                            >= cfg.learner.save_interval):
+                        with self._span("checkpoint"):
+                            self.save_checkpoint()
+                        self._last_save = steps
+                    # Pre-first-step republish (slow cadence) is needed only for
+                    # socket pools: a TCP subscriber that joined after the
+                    # initial publish would otherwise never receive params
+                    # (PUB/SUB has no replay — the zmq slow-joiner race) and an
+                    # actor fleet without params produces no chunks: deadlock.
+                    # mp pools have pre-existing queues, so the initial publish
+                    # cannot be lost and warmup republishes would only burn the
+                    # ingest thread on param serialization.
+                    if steps:
+                        due = (now - last_publish >= self.publish_min_seconds
+                               and (steps - last_pub_step
+                                    >= cfg.learner.publish_interval
+                                    or now - last_publish
+                                    > 10 * self.publish_min_seconds))
+                    else:
+                        due = (getattr(pool, "needs_warmup_republish", False)
+                               and now - last_publish
+                               > 10 * self.publish_min_seconds)
+                    if due:
+                        self._publish()
+                        last_publish = now
+                        last_pub_step = steps
 
-                # Failure detection (beyond the reference, SURVEY.md §5.3:
-                # its fleets never notice actor death): crashed workers are
-                # logged and respawned on the same ladder slot; remote
-                # peers run the fleet registry's JOINING/ALIVE/SUSPECT/DEAD
-                # machine (config thresholds in CommsConfig — this
-                # replaced the old hardcoded silent_peers(60.0) report).
-                # drain BEFORE the health tick: after a dispatch that
-                # blocked the loop (a first compile is tens of seconds on
-                # the chip) the drain reads the queued heartbeats and
-                # forgives the span nobody was watching — judging silence
-                # first declares a healthy fleet DEAD
-                self._drain_stats(steps)
-                if self.respawn_workers and now - last_health >= 5.0:
-                    self._health_tick(steps)
-                    last_health = now
+                    # Failure detection (beyond the reference, SURVEY.md §5.3:
+                    # its fleets never notice actor death): crashed workers are
+                    # logged and respawned on the same ladder slot; remote
+                    # peers run the fleet registry's JOINING/ALIVE/SUSPECT/DEAD
+                    # machine (config thresholds in CommsConfig — this
+                    # replaced the old hardcoded silent_peers(60.0) report).
+                    # drain BEFORE the health tick: after a dispatch that
+                    # blocked the loop (a first compile is tens of seconds on
+                    # the chip) the drain reads the queued heartbeats and
+                    # forgives the span nobody was watching — judging silence
+                    # first declares a healthy fleet DEAD
+                    with self._span("drain_stats"):
+                        self._drain_stats(steps)
+                    if self.respawn_workers and now - last_health >= 5.0:
+                        with self._span("health_tick"):
+                            self._health_tick(steps)
+                        last_health = now
 
-                # metrics is None until the first train dispatch, so the
-                # gate needs no warm check — and in service mode the
-                # LOCAL pool never warms while shard batches train fine
-                if metrics is not None \
-                        and steps - self._last_log >= log_every:
-                    extra = gap.snapshot()
-                    if pipeline is not None:
-                        extra |= {f"pipeline_{k}": v
-                                  for k, v in pipeline.stats.items()}
-                    if self._obs is not None:
-                        extra |= self._obs.scalars()
-                    if client is not None:
-                        extra |= {"service_batches": client.batches,
-                                  "service_steps": self.service_steps,
-                                  "service_ingested":
-                                      client.ingested_total()}
-                    self.log.scalars(
-                        {k: float(v) for k, v in metrics.items()}
-                        | {"bps": self.steps_rate.rate,
-                           "fps": self.frames_rate.rate,
-                           "param_version": self.param_version,
-                           "ingested": ingested_eff} | extra, steps)
-                    self._last_log = steps
+                    # metrics is None until the first train dispatch, so the
+                    # gate needs no warm check — and in service mode the
+                    # LOCAL pool never warms while shard batches train fine
+                    if metrics is not None \
+                            and steps - self._last_log >= log_every:
+                        with self._span("log_scalars"):
+                            extra = gap.snapshot()
+                            if pipeline is not None:
+                                extra |= {f"pipeline_{k}": v
+                                          for k, v in pipeline.stats.items()}
+                            if self._obs is not None:
+                                extra |= self._obs.scalars()
+                            if client is not None:
+                                extra |= {
+                                    "service_batches": client.batches,
+                                    "service_steps": self.service_steps,
+                                    "service_ingested":
+                                        client.ingested_total()}
+                            self.log.scalars(
+                                {k: float(v) for k, v in metrics.items()}
+                                | {"bps": self.steps_rate.rate,
+                                   "fps": self.frames_rate.rate,
+                                   "param_version": self.param_version,
+                                   "ingested": ingested_eff} | extra, steps)
+                        self._last_log = steps
+                    this_pass.note(kind=self._pass_kind)
         finally:
             if pipeline is not None:
                 # stop staging BEFORE the pool teardown (the staging
@@ -1184,12 +1211,106 @@ class ConcurrentTrainer(CheckpointableTrainer):
         would have left in ``self.key`` (so mid-train checkpoints and
         post-train ``self.key`` stay bit-identical to a serial run of
         the same dispatch count)."""
-        pipe = self._pipeline
-        if pipe is not None and pipe.keys is not None:
-            placed, self.key = pipe.keys.take()
-            return placed
-        self.key, k = jax.random.split(self.key)
-        return k
+        with self._span("dispatch_key"):
+            pipe = self._pipeline
+            if pipe is not None and pipe.keys is not None:
+                placed, self.key = pipe.keys.take()
+                return placed
+            self.key, k = jax.random.split(self.key)
+            return k
+
+    # -- host spans + the one dispatch site --------------------------------
+
+    def _span(self, name: str, **args):
+        """One named phase of the loop pass under way, on the hot loop's
+        track and carrying the pass number ``it``: a ring event and a
+        profiler annotation while tracing is live
+        (:meth:`apex_tpu.obs.trace.TraceRing.span`), else the ring's
+        shared no-op for one attribute check."""
+        ring = self._ring
+        if ring is None:
+            from apex_tpu.obs.trace import get_ring
+            ring = self._ring = get_ring()
+        if not ring.live:
+            return ring.span(name)
+        return ring.span(name, self._loop_track, {"it": self._pass, **args})
+
+    def _pre_consume(self, spans) -> None:
+        """Chunk-lineage join, first half (stamps ``consume``)."""
+        if spans:
+            with self._span("obs_join", n=len(spans)):
+                self._obs.pre_consume(spans)
+
+    def _post_consume(self, spans) -> None:
+        """Second half: ``prio_wb``, the age and lag histograms, the
+        lineage events."""
+        if spans:
+            with self._span("obs_join", n=len(spans)):
+                self._obs.post_consume(spans, self.steps_rate.total)
+
+    @contextlib.contextmanager
+    def _dispatch(self, kind: str, fn, beta=None,
+                  program: str | None = None):
+        """One device dispatch, in the one place every dispatch of the
+        loop goes through::
+
+            with self._dispatch("fused", self._fused, self._beta) as call:
+                self.train_state, self.replay_state, metrics = call(
+                    self.train_state, self.replay_state, payload, prios, k)
+
+        The block is the interval ``host_gap`` leaves out, as the
+        hand-written sites had it: it opens with
+        ``gap.about_to_dispatch()``, ``beta()`` (a host float) becomes
+        the step's last operand inside it (the eager convert, timed apart
+        as ``beta``), ``call`` is ``fn`` under a ``dispatch`` span, and the
+        caller's assignment of the results (``adopt``, inside
+        ``dispatch``: the donated state's Python objects die there) still
+        lies before ``gap.dispatch_returned()``.  ``kind`` names the pass
+        (``loop_iter``'s arg); ``program`` is the name XLA gives ``fn``,
+        its module's name in a profiler trace, so a device program can be
+        put beside the pass that issued it."""
+        self._pass_kind = kind
+        gap = self._dispatch_gap
+        gap.about_to_dispatch()
+        tail = ()
+        if beta is not None:
+            with self._span("beta"):
+                tail = (jnp.float32(beta()),)
+        with contextlib.ExitStack() as spans:
+            def call(*args):
+                spans.enter_context(self._span(
+                    "dispatch", program=program or "jit_" + fn.__name__))
+                out = fn(*args, *tail)
+                spans.enter_context(self._span("adopt"))
+                return out
+            yield call
+        gap.dispatch_returned()
+
+    def _dispatch_fused(self, payload, prios):
+        """Ingest one chunk and train one step (``_fused``); the step's
+        metrics."""
+        k = self._dispatch_key()
+        with self._dispatch("fused", self._fused, beta=self._beta) as call:
+            self.train_state, self.replay_state, metrics = call(
+                self.train_state, self.replay_state, payload, prios, k)
+        return metrics
+
+    def _dispatch_scan(self, payload, prios, j: int, betas):
+        """``j`` stacked chunks, ``j`` steps in one program (``_multi``),
+        its per-step keys split off one chain key; the stacked metrics."""
+        k = self._dispatch_key()
+        with self._dispatch("scan", self._multi) as call:
+            with self._span("dispatch_key"):
+                keys = jax.random.split(k, j)
+            self.train_state, self.replay_state, mm = call(
+                self.train_state, self.replay_state, payload, prios, keys,
+                betas)
+        return mm
+
+    def _dispatch_ingest(self, payload, prios) -> None:
+        """Absorb one payload without training (``_ingest``)."""
+        with self._dispatch("ingest", self._ingest) as call:
+            self.replay_state = call(self.replay_state, payload, prios)
 
     def _pipeline_state(self):
         """Counter snapshot for the staging thread's grouping decisions.
@@ -1309,16 +1430,14 @@ class ConcurrentTrainer(CheckpointableTrainer):
         write-back to its owning shard (via the staging thread when the
         pipeline is live — the device_get must not land on the hot
         loop)."""
-        gap = self._dispatch_gap
-        gap.about_to_dispatch()
-        if slot.update_key is not None:
-            k = jax.random.wrap_key_data(jnp.asarray(slot.update_key))
-            self.train_state, prios, metrics = self._train_batch(
-                self.train_state, slot.payload, slot.prios, k)
-        else:
-            self.train_state, prios, metrics = self._train_batch(
-                self.train_state, slot.payload, slot.prios)
-        gap.dispatch_returned()
+        with self._dispatch("batch", self._train_batch) as call:
+            key = ()
+            if slot.update_key is not None:
+                with self._span("dispatch_key"):
+                    key = (jax.random.wrap_key_data(
+                        jnp.asarray(slot.update_key)),)
+            self.train_state, prios, metrics = call(
+                self.train_state, slot.payload, slot.prios, *key)
         self.steps_rate.tick()
         self.service_steps += 1
         if self._pipeline is not None:
@@ -1338,10 +1457,8 @@ class ConcurrentTrainer(CheckpointableTrainer):
         dispatch, everything else is absorbed ingest-only (the
         replay-ratio cap is re-checked at consume time, so a stale
         staging prediction can only under-train, never over-train)."""
-        gap = self._dispatch_gap
-        obs = self._obs
-        if obs is not None and slot.spans:
-            obs.pre_consume(slot.spans)     # "consume": dispatch issued
+        spans = slot.spans if self._obs is not None else ()
+        self._pre_consume(spans)            # "consume": dispatch issued
         metrics = None
         if slot.kind == "batch":
             # shard-sampled: always trained (a staged batch skipped here
@@ -1349,8 +1466,7 @@ class ConcurrentTrainer(CheckpointableTrainer):
             # will never get; the budget re-check already gated the PULL,
             # so overshoot is bounded by the staged depth)
             metrics = self._consume_batch_slot(slot)
-            if obs is not None and slot.spans:
-                obs.post_consume(slot.spans)
+            self._post_consume(spans)
             return metrics
         if slot.kind == "scan":
             j = slot.chunks
@@ -1365,13 +1481,7 @@ class ConcurrentTrainer(CheckpointableTrainer):
                     np.float32)
                 # scan slots exist only on the single-shard plan, so the
                 # key is a raw chain key here — never prefetcher output
-                k = self._dispatch_key()
-                gap.about_to_dispatch()
-                self.train_state, self.replay_state, mm = \
-                    self._multi(self.train_state, self.replay_state,
-                                slot.payload, slot.prios,
-                                jax.random.split(k, j), betas)
-                gap.dispatch_returned()
+                mm = self._dispatch_scan(slot.payload, slot.prios, j, betas)
                 metrics = jax.tree.map(lambda x: x.mean(0), mm)
                 self.steps_rate.tick(j)
                 self.scan_dispatches += 1
@@ -1379,28 +1489,17 @@ class ConcurrentTrainer(CheckpointableTrainer):
                 if self._ingest_multi is None:
                     from apex_tpu.training.learner import make_multi_ingest
                     self._ingest_multi = make_multi_ingest(self.core)
-                gap.about_to_dispatch()
-                self.replay_state = self._ingest_multi(
-                    self.replay_state, slot.payload, slot.prios)
-                gap.dispatch_returned()
+                with self._dispatch("ingest", self._ingest_multi) as call:
+                    self.replay_state = call(self.replay_state,
+                                             slot.payload, slot.prios)
         elif slot.kind == "single" and warm \
                 and self.steps_rate.total < budget:
-            k = self._dispatch_key()
-            gap.about_to_dispatch()
-            self.train_state, self.replay_state, metrics = \
-                self._fused(self.train_state, self.replay_state,
-                            slot.payload, slot.prios, k,
-                            jnp.float32(self._beta()))
-            gap.dispatch_returned()
+            metrics = self._dispatch_fused(slot.payload, slot.prios)
             self.steps_rate.tick()
         else:
             # merged ingest payloads, and singles the cap says to absorb
-            gap.about_to_dispatch()
-            self.replay_state = self._ingest(self.replay_state,
-                                             slot.payload, slot.prios)
-            gap.dispatch_returned()
-        if obs is not None and slot.spans:
-            obs.post_consume(slot.spans)    # "prio_wb" + the two joins
+            self._dispatch_ingest(slot.payload, slot.prios)
+        self._post_consume(spans)           # "prio_wb" + the joins
         self.ingested += slot.n_trans
         self.frames_rate.tick(slot.n_trans)
         return metrics
@@ -1409,7 +1508,6 @@ class ConcurrentTrainer(CheckpointableTrainer):
                       budget: float):
         """The serial (pipeline-off) drain of one poll's messages.
         Returns metrics or None."""
-        gap = self._dispatch_gap
         obs = self._obs
         if obs is not None:
             for m in msgs:
@@ -1433,16 +1531,9 @@ class ConcurrentTrainer(CheckpointableTrainer):
             betas = np.asarray(
                 [self._beta(self.ingested + int(o))
                  for o in offsets], np.float32)
-            k = self._dispatch_key()
-            if spans:
-                obs.pre_consume(spans)
-            gap.about_to_dispatch()
-            self.train_state, self.replay_state, mm = \
-                self._multi(self.train_state, self.replay_state,
-                            payload, prios, jax.random.split(k, j), betas)
-            gap.dispatch_returned()
-            if spans:
-                obs.post_consume(spans)
+            self._pre_consume(spans)
+            mm = self._dispatch_scan(payload, prios, j, betas)
+            self._post_consume(spans)
             # scalar observability coarsens to per-dispatch under scan:
             # report the mean over the j stacked steps
             metrics = jax.tree.map(lambda x: x.mean(0), mm)
@@ -1456,28 +1547,17 @@ class ConcurrentTrainer(CheckpointableTrainer):
             n_new = int(msg["n_trans"])
             payload = msg["payload"]
             spans = obs_spans.spans_of(msg) if obs is not None else ()
-            if spans:
-                obs.pre_consume(spans)
+            self._pre_consume(spans)
             # The replay-ratio cap applies on the chunk path too: an
             # over-budget learner ingests WITHOUT the fused train half,
             # so the documented ``train_ratio`` really is the ceiling
             # (ingesting raises the budget for later steps).
             if warm and self.steps_rate.total < budget:
-                k = self._dispatch_key()
-                gap.about_to_dispatch()
-                self.train_state, self.replay_state, metrics = \
-                    self._fused(self.train_state, self.replay_state,
-                                payload, prios, k,
-                                jnp.float32(self._beta()))
-                gap.dispatch_returned()
+                metrics = self._dispatch_fused(payload, prios)
                 self.steps_rate.tick()
             else:
-                gap.about_to_dispatch()
-                self.replay_state = self._ingest(
-                    self.replay_state, payload, prios)
-                gap.dispatch_returned()
-            if spans:
-                obs.post_consume(spans)
+                self._dispatch_ingest(payload, prios)
+            self._post_consume(spans)
             self.ingested += n_new
             self.frames_rate.tick(n_new)
         return metrics

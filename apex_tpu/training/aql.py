@@ -71,47 +71,54 @@ class AQLCore:
 
     # -- update body -------------------------------------------------------
 
+    @jax.named_scope("update")
     def update_from_batch(self, ts: TrainState, batch, weights,
                           key: jax.Array, axis_name: str | None = None):
+        """The two-loss update, under the same trace scopes as
+        :func:`apex_tpu.training.learner.td_update`: ``update`` with
+        ``loss_grad``, ``optimizer`` and ``target_sync`` inside it."""
         k_online, k_target = jax.random.split(key)
 
         def q_loss_fn(params):
             return aql_q_loss(self._score, params, ts.target_params, batch,
                               weights, k_online, k_target)
 
-        (loss_q, aux), q_grads = jax.value_and_grad(
-            q_loss_fn, has_aux=True)(ts.params)
-        # argmax-Q candidate under the same online noise draw, straight from
-        # the loss pass — no second scoring of the candidate set
-        best_idx = aux.best_idx
+        with jax.named_scope("loss_grad"):
+            (loss_q, aux), q_grads = jax.value_and_grad(
+                q_loss_fn, has_aux=True)(ts.params)
+            # argmax-Q candidate under the same online noise draw, straight
+            # from the loss pass — no second scoring of the candidate set
+            best_idx = aux.best_idx
 
-        def p_loss_fn(params):
-            return aql_proposal_loss(self._log_prob, params, batch,
-                                     best_idx, self.entropy_coef)
+            def p_loss_fn(params):
+                return aql_proposal_loss(self._log_prob, params, batch,
+                                         best_idx, self.entropy_coef)
 
-        loss_p, p_grads = jax.value_and_grad(p_loss_fn)(ts.params)
+            loss_p, p_grads = jax.value_and_grad(p_loss_fn)(ts.params)
 
-        # merge by label: proposal leaves from the proposal pass, the rest
-        # from the Q pass — neither loss can touch the other group
-        from apex_tpu.ops.losses import aql_param_labels
-        labels = aql_param_labels(ts.params)
-        grads = jax.tree.map(
-            lambda lbl, qg, pg: pg if lbl == "proposal" else qg,
-            labels, q_grads, p_grads)
+            # merge by label: proposal leaves from the proposal pass, the
+            # rest from the Q pass — neither loss can touch the other group
+            from apex_tpu.ops.losses import aql_param_labels
+            labels = aql_param_labels(ts.params)
+            grads = jax.tree.map(
+                lambda lbl, qg, pg: pg if lbl == "proposal" else qg,
+                labels, q_grads, p_grads)
 
-        if axis_name is not None:
-            grads = jax.lax.pmean(grads, axis_name)
-            loss_q = jax.lax.pmean(loss_q, axis_name)
-            loss_p = jax.lax.pmean(loss_p, axis_name)
+            if axis_name is not None:
+                grads = jax.lax.pmean(grads, axis_name)
+                loss_q = jax.lax.pmean(loss_q, axis_name)
+                loss_p = jax.lax.pmean(loss_p, axis_name)
 
-        updates, opt_state = self.optimizer.update(grads, ts.opt_state,
-                                                   ts.params)
-        params = optax.apply_updates(ts.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.optimizer.update(grads, ts.opt_state,
+                                                       ts.params)
+            params = optax.apply_updates(ts.params, updates)
         step = ts.step + 1
-        target_params = jax.lax.cond(
-            step % self.target_update_interval == 0,
-            lambda: jax.tree.map(jnp.copy, params),
-            lambda: ts.target_params)
+        with jax.named_scope("target_sync"):
+            target_params = jax.lax.cond(
+                step % self.target_update_interval == 0,
+                lambda: jax.tree.map(jnp.copy, params),
+                lambda: ts.target_params)
 
         q_mean, td_mean = aux.q_taken.mean(), aux.td_abs.mean()
         if axis_name is not None:

@@ -510,11 +510,9 @@ class IngestPipeline:
                         item = self.client.poll_batch(timeout=0)
                         if item is not None:
                             self._idle.clear()
-                            t0 = time.perf_counter()
-                            slot = self._build_batch_slot(item)
-                            self.ring.complete("stage_batch", t0,
-                                               time.perf_counter() - t0,
-                                               track="ingest-staging")
+                            with self.ring.span("stage_batch",
+                                                "ingest-staging"):
+                                slot = self._build_batch_slot(item)
                             self._put(slot)
                             continue
                 if st.behind:
@@ -528,6 +526,8 @@ class IngestPipeline:
                     self._idle.set()
                     continue
                 self._idle.clear()
+                # named by the slot it built, known only afterwards: a
+                # ring event written after the fact (no annotation)
                 t0 = time.perf_counter()
                 slot = self._build_slot(msgs[0], st)
                 self.ring.complete(f"stage_{slot.kind}", t0,
@@ -670,15 +670,12 @@ class IngestPipeline:
                 if not self._wb_q:
                     return
                 shard, seq, idx, prios = self._wb_q.popleft()
-            t0 = time.perf_counter()
-            self.client.push_priorities(shard, seq, np.asarray(idx),
-                                        np.asarray(jax.device_get(prios),
-                                                   np.float32))
+            with self.ring.span("prio_writeback", "ingest-staging",
+                                {"shard": shard}):
+                self.client.push_priorities(
+                    shard, seq, np.asarray(idx),
+                    np.asarray(jax.device_get(prios), np.float32))
             self.stats["writebacks"] += 1
-            self.ring.complete("prio_writeback", t0,
-                               time.perf_counter() - t0,
-                               track="ingest-staging",
-                               args={"shard": shard})
 
     def _single_slot(self, msg: dict, planned: int = 1) -> StagedSlot:
         self.stats["slots"] += 1
@@ -734,15 +731,15 @@ class IngestPipeline:
         if req is None:
             return
         version, params = req
-        t0 = time.perf_counter()
-        if getattr(self.pool, "accepts_device_params", False):
-            # co-located on-device rollouts (training/anakin.py): the pool
-            # consumes the device copy directly — params never leave the
-            # device; the pool device_gets internally only when an inner
-            # socket fleet needs wire params (still on THIS thread)
-            self.pool.publish_params(version, params)
-        else:
-            self.pool.publish_params(version, jax.device_get(params))
+        with self.ring.span("publish", "ingest-staging",
+                            {"version": version}):
+            if getattr(self.pool, "accepts_device_params", False):
+                # co-located on-device rollouts (training/anakin.py): the
+                # pool consumes the device copy directly — params never
+                # leave the device; the pool device_gets internally only
+                # when an inner socket fleet needs wire params (still on
+                # THIS thread)
+                self.pool.publish_params(version, params)
+            else:
+                self.pool.publish_params(version, jax.device_get(params))
         self.stats["publishes"] += 1
-        self.ring.complete("publish", t0, time.perf_counter() - t0,
-                           track="ingest-staging", args={"version": version})

@@ -115,6 +115,7 @@ class LearnerCore:
         return jax.jit(self.fused_multi_step, donate_argnums=(0, 1))
 
 
+@jax.named_scope("update")
 def td_update(optimizer, target_update_interval: int,
               train_state: TrainState, loss_fn, axis_name: str | None):
     """The single-optimizer TD update body: loss/grads -> (optional
@@ -129,21 +130,30 @@ def td_update(optimizer, target_update_interval: int,
     exception (:class:`apex_tpu.training.aql.AQLCore`).
 
     Returns ``(train_state, priorities, metrics)``.
+
+    Traced under the scope ``update`` (with ``loss_grad``, ``optimizer``
+    and ``target_sync`` inside it): one of the five names a profiler
+    trace groups the step program's device time by — ``ingest``,
+    ``sample``, ``gather``, ``update``, ``writeback``; the other four
+    live in :mod:`apex_tpu.replay`.
     """
-    (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-        train_state.params)
-    if axis_name is not None:
-        grads = jax.lax.pmean(grads, axis_name)         # ICI all-reduce
-        loss = jax.lax.pmean(loss, axis_name)
-    updates, opt_state = optimizer.update(
-        grads, train_state.opt_state, train_state.params)
-    params = optax.apply_updates(train_state.params, updates)
+    with jax.named_scope("loss_grad"):
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            train_state.params)
+        if axis_name is not None:
+            grads = jax.lax.pmean(grads, axis_name)     # ICI all-reduce
+            loss = jax.lax.pmean(loss, axis_name)
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(
+            grads, train_state.opt_state, train_state.params)
+        params = optax.apply_updates(train_state.params, updates)
 
     step = train_state.step + 1
-    target_params = jax.lax.cond(
-        step % target_update_interval == 0,
-        lambda: jax.tree.map(jnp.copy, params),
-        lambda: train_state.target_params)
+    with jax.named_scope("target_sync"):
+        target_params = jax.lax.cond(
+            step % target_update_interval == 0,
+            lambda: jax.tree.map(jnp.copy, params),
+            lambda: train_state.target_params)
 
     q_mean = aux.q_taken.mean()
     td_mean = aux.td_abs.mean()

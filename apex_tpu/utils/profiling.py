@@ -49,11 +49,18 @@ def device_peak_flops(device_kind: str | None = None) -> float:
 
 @contextlib.contextmanager
 def trace(logdir: str) -> Iterator[None]:
-    """``with trace("/tmp/prof"): run_steps()`` -> XProf dump in logdir."""
+    """``with trace("/tmp/prof"): run_steps()`` -> XProf dump in logdir.
+    The process's spans (:meth:`apex_tpu.obs.trace.TraceRing.span`) hold
+    their profiler annotations meanwhile, so the dump names the loop's
+    host phases beside the device's programs."""
+    from apex_tpu.obs.trace import get_ring
+    ring = get_ring()
     jax.profiler.start_trace(logdir)
+    ring.annotate(True)
     try:
         yield
     finally:
+        ring.annotate(False)
         jax.profiler.stop_trace()
 
 
@@ -90,9 +97,9 @@ class PhaseTimer:
     loop.
 
     ``ring``: an optional :class:`apex_tpu.obs.trace.TraceRing` — when
-    attached, every completed phase also lands in the per-role trace ring
-    as one Chrome trace event (host clock reads only; apexlint J006/J010
-    stay clean).
+    attached, every phase also goes through the ring's ``span`` (one
+    Chrome trace event and one profiler annotation while tracing is live;
+    host clock reads only, apexlint J006/J010 stay clean).
     """
 
     def __init__(self, ring=None, track: str | None = None):
@@ -105,12 +112,13 @@ class PhaseTimer:
     def phase(self, name: str) -> Iterator[None]:
         t = time.perf_counter()
         try:
-            yield
+            if self.ring is None:
+                yield
+            else:
+                with self.ring.span(name, self.track):
+                    yield
         finally:
-            dur = time.perf_counter() - t
-            self.add(name, dur)
-            if self.ring is not None:
-                self.ring.complete(name, t, dur, track=self.track)
+            self.add(name, time.perf_counter() - t)
 
     def add(self, name: str, seconds: float) -> None:
         self._acc[name] = self._acc.get(name, 0.0) + seconds

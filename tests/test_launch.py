@@ -15,11 +15,14 @@ REPO = Path(__file__).resolve().parent.parent
 
 # -- compile cache placed from outside ---------------------------------------
 
-def _cache_probe(env_value: str | None) -> dict:
+def _cache_probe(env_value: str | None, trace_dir: str | None = None) -> dict:
     """Run the helper in a FRESH interpreter (jax reads the variable at
     import, and the helper mutates process-wide state)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("APEX_TRACE_DIR", None)
+    if trace_dir is not None:
+        env["APEX_TRACE_DIR"] = trace_dir
     if env_value is not None:
         env["JAX_COMPILATION_CACHE_DIR"] = env_value
     code = (
@@ -29,7 +32,9 @@ def _cache_probe(env_value: str | None) -> dict:
         "import jax\n"
         "print(json.dumps({'path': path,\n"
         "    'env': os.environ.get('JAX_COMPILATION_CACHE_DIR'),\n"
-        "    'jax': jax.config.jax_compilation_cache_dir}))\n")
+        "    'jax': jax.config.jax_compilation_cache_dir,\n"
+        "    'metadata_in_key':\n"
+        "    jax.config.jax_compilation_cache_include_metadata_in_key}))\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=120,
                          check=True).stdout
@@ -40,6 +45,17 @@ def test_compile_cache_honours_the_operators_directory(tmp_path):
     got = _cache_probe(str(tmp_path / "elsewhere"))
     assert got["path"] == got["env"] == got["jax"] \
         == str(tmp_path / "elsewhere")
+    # nothing else configured: an edit to traced source costs no compile
+    assert got["metadata_in_key"] is False
+
+
+def test_a_traced_run_counts_metadata_in_the_cache_key(tmp_path):
+    # a cache from before a trace scope moved must not serve its programs
+    # to the run that reads the scopes
+    got = _cache_probe(str(tmp_path / "elsewhere"),
+                       trace_dir=str(tmp_path / "ring"))
+    assert got["path"] == got["jax"] == str(tmp_path / "elsewhere")
+    assert got["metadata_in_key"] is True
 
 
 def test_compile_cache_defaults_to_a_fixed_in_tree_path():
@@ -49,6 +65,7 @@ def test_compile_cache_defaults_to_a_fixed_in_tree_path():
     assert got["path"] == want
     assert got["env"] == want          # exported: spawned workers share it
     assert got["jax"] == want
+    assert got["metadata_in_key"] is False
     assert not re.search(r"tmp|\d{3,}", os.path.relpath(want, REPO))
     ignored = (REPO / ".gitignore").read_text().split()
     assert ".jax_cache/" in ignored

@@ -1,0 +1,765 @@
+"""The span primitive (``TraceRing.span``), the learner loop's phases, the
+step program's trace scopes, policy lag in steps, and the benchmark's
+reductions of all three (``benchmark/spans.py`` and its readers).
+
+Tier-1: a scripted pool and fake clocks on the CPU; the device side is
+checked on a synthetic ``.xplane.pb`` written here byte by byte and on the
+trace recorded on the chip beside ``benchmark/tests/fixtures/small``."""
+
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+import re
+import struct
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.config import small_test_config
+from apex_tpu.obs import trace as obs_trace
+from apex_tpu.obs.spans import LearnerObs
+from apex_tpu.obs.trace import TraceRing
+from benchmark import spans
+from tests.test_ingest_pipeline import ScriptedPool
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXTURES = os.path.join(ROOT, "benchmark", "tests", "fixtures")
+NEW_METRICS = (
+    "loop_prep_share_pct", "loop_dispatch_share_pct",
+    "loop_unnamed_share_pct", "idle_unattributed_pct", "ingest_ms",
+    "sample_ms", "gather_ms", "update_ms", "writeback_ms",
+    "step_unscoped_share_pct", "policy_lag_p95_steps")
+
+
+# -- the span primitive --------------------------------------------------------
+
+class _FakeAnnotation:
+    entered: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        _FakeAnnotation.entered.append((self.name, self.kwargs))
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_span_off_reads_no_clock_and_records_nothing(monkeypatch):
+    ring = TraceRing("learner", enabled=False)
+
+    def boom():
+        raise AssertionError("clock read with tracing off")
+
+    monkeypatch.setattr(time, "perf_counter", boom)
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _FakeAnnotation)
+    _FakeAnnotation.entered = []
+    first = ring.span("dispatch", "learner-hot-loop", {"it": 1})
+    with first as sp:
+        sp.note(kind="fused")
+    assert ring.span("beta") is first           # one shared no-op object
+    assert not _FakeAnnotation.entered
+    assert not [ev for ev in ring.to_chrome()["traceEvents"]
+                if ev.get("ph") != "M"]
+
+
+def test_span_on_makes_one_ring_event_and_one_annotation(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _FakeAnnotation)
+    _FakeAnnotation.entered = []
+    ring = TraceRing("learner", enabled=True)
+    with ring.span("loop_iter", "learner-hot-loop", {"it": 7}) as sp:
+        sp.note(kind="train")
+    events = [ev for ev in ring.to_chrome()["traceEvents"]
+              if ev.get("ph") == "X"]
+    assert len(events) == 1
+    assert events[0]["name"] == "loop_iter" and events[0]["dur"] >= 0
+    assert events[0]["args"] == {"it": 7, "kind": "train"}
+    assert _FakeAnnotation.entered == [("loop_iter", {"it": 7})]
+    # a profiler trace alone (--profile-dir): the annotation, no ring event
+    quiet = TraceRing("learner", enabled=False)
+    quiet.annotate(True)
+    with quiet.span("dispatch", args={"program": "jit_train_step"}):
+        pass
+    assert _FakeAnnotation.entered[-1] == (
+        "dispatch", {"program": "jit_train_step"})
+    assert len(quiet.to_chrome()["traceEvents"]) == 1      # the label only
+    quiet.annotate(False)
+    assert quiet.span("dispatch") is obs_trace._NO_SPAN
+
+
+def test_real_annotation_is_importable_and_nests():
+    ring = TraceRing("learner", enabled=True)
+    with ring.span("loop_iter", args={"it": 0}):
+        with ring.span("dispatch", args={"it": 0, "program": "jit_x"}):
+            pass
+    names = [ev["name"] for ev in ring.to_chrome()["traceEvents"]
+             if ev.get("ph") == "X"]
+    assert names == ["dispatch", "loop_iter"]       # recorded at exit
+
+
+# -- the learner loop's phases -------------------------------------------------------
+
+def _scripted_messages(n: int = 24) -> list[dict]:
+    from apex_tpu.actors.pool import drain_builder_chunks
+    from apex_tpu.obs import spans as obs_spans
+    from apex_tpu.replay.frame_chunks import FrameChunkBuilder
+
+    rng = np.random.default_rng(31)
+    builder = FrameChunkBuilder(3, 0.99, 1, (4,), chunk_transitions=8,
+                                frame_dtype=np.float32)
+    msgs: list[dict] = []
+    while len(msgs) < n:
+        builder.begin_episode(rng.normal(size=4).astype(np.float32))
+        ep_len = int(rng.integers(4, 30))
+        for t in range(ep_len):
+            builder.add_step(int(rng.integers(0, 2)), float(rng.normal()),
+                             rng.normal(size=2).astype(np.float32),
+                             rng.normal(size=4).astype(np.float32),
+                             terminated=t == ep_len - 1, truncated=False)
+        msgs.extend(drain_builder_chunks(builder))
+    msgs = msgs[:n]
+    for msg in msgs:
+        obs_spans.mark_send(msg, param_version=1)
+    return msgs
+
+
+def _small_trainer(msgs, pipeline: bool, scan_steps: int = 1):
+    from apex_tpu.training.apex import ApexTrainer
+
+    cfg = small_test_config(capacity=256, batch_size=8, n_actors=1)
+    cfg = cfg.replace(
+        replay=dataclasses.replace(cfg.replay, warmup=32),
+        learner=dataclasses.replace(cfg.learner, target_update_interval=50,
+                                    ingest_pipeline=pipeline,
+                                    scan_steps=scan_steps))
+    return ApexTrainer(cfg, pool=ScriptedPool(msgs),
+                       publish_min_seconds=30.0, respawn_workers=False)
+
+
+@pytest.fixture(scope="module")
+def traced_run(tmp_path_factory):
+    """One small trainer run on the CPU with the trace ring live; the
+    ring's Chrome events and the trainer."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("APEX_TRACE_DIR", str(tmp_path_factory.mktemp("ring")))
+    mp.setenv("APEX_TRACE_FLUSH_S", "0")            # no flusher thread
+    obs_trace.reset_for_tests()
+    try:
+        trainer = _small_trainer(_scripted_messages(), pipeline=False)
+        trainer.train(total_steps=12, max_seconds=120, log_every=4)
+        chrome = obs_trace.get_ring().to_chrome()
+    finally:
+        mp.undo()
+        obs_trace.reset_for_tests()
+    return chrome, trainer
+
+
+def _on_track(chrome: dict, track: str) -> list[dict]:
+    tid = next(ev["tid"] for ev in chrome["traceEvents"]
+               if ev.get("name") == "thread_name"
+               and ev["args"]["name"] == track)
+    return [ev for ev in chrome["traceEvents"]
+            if ev.get("ph") == "X" and ev["tid"] == tid]
+
+
+def test_loop_iter_spans_hold_their_children(traced_run):
+    chrome, trainer = traced_run
+    events = _on_track(chrome, "learner-hot-loop")
+    passes = [ev for ev in events if ev["name"] == "loop_iter"]
+    assert len(passes) >= 12
+    its = [ev["args"]["it"] for ev in passes]
+    assert its == sorted(set(its))                  # one span a pass
+    assert {ev["args"]["kind"] for ev in passes} <= {
+        "fused", "train", "ingest", "idle"}
+    assert {"fused", "ingest"} <= {ev["args"]["kind"] for ev in passes}
+    by_it = {ev["args"]["it"]: ev for ev in passes}
+    children = [ev for ev in events
+                if ev["name"] not in ("loop_iter", "host_gap")]
+    assert {"poll_slot", "dispatch_key", "beta", "dispatch", "obs_join",
+            "drain_stats", "log_scalars"} <= {ev["name"] for ev in children}
+    for ev in children:
+        it = ev["args"]["it"]
+        if it not in by_it:             # the publish before the first pass
+            assert ev["name"] == "publish_handoff"
+            continue
+        parent = by_it[it]
+        assert parent["ts"] - 1 <= ev["ts"]
+        assert ev["ts"] + ev["dur"] <= parent["ts"] + parent["dur"] + 1
+    programs = {ev["args"]["program"] for ev in children
+                if ev["name"] == "dispatch"}
+    assert programs == {"jit_fused_step", "jit_ingest"}
+
+
+def _host_gap_leaves_out_the_dispatch_interval(events: list[dict]) -> None:
+    """``host_gap`` runs from ``gap.dispatch_returned()`` to the next
+    ``gap.about_to_dispatch()``, as the hand-written sites had it: the
+    eager convert of beta, the jitted call and the hand-over of its
+    results (``adopt``, inside ``dispatch``) all lie outside every gap."""
+    def edges(name):
+        return sorted((ev["ts"], ev["ts"] + ev["dur"]) for ev in events
+                      if ev["name"] == name)
+
+    dispatches, adopts = edges("dispatch"), edges("adopt")
+    assert len(adopts) == len(dispatches)
+    for (d0, d1), (a0, a1) in zip(dispatches, adopts):
+        assert d0 <= a0 and a1 <= d1 + 0.2          # adopt ends dispatch
+    for g0, g1 in edges("host_gap"):
+        for name in ("beta", "dispatch", "adopt"):
+            for a, b in edges(name):                # 0.1 us rounding
+                assert b <= g0 + 0.2 or a >= g1 - 0.2, (name, a, b, g0, g1)
+    # a gap opens where a dispatch's interval closes
+    ends = [d1 for _d0, d1 in dispatches]
+    for g0, _g1 in edges("host_gap"):
+        assert min(abs(g0 - d1) for d1 in ends) < 200
+
+
+def test_one_dispatch_span_per_host_gap_and_host_gap_unchanged(traced_run):
+    chrome, trainer = traced_run
+    events = _on_track(chrome, "learner-hot-loop")
+    gaps = [ev for ev in events if ev["name"] == "host_gap"]
+    dispatches = [ev for ev in events if ev["name"] == "dispatch"]
+    assert gaps and abs(len(dispatches) - len(gaps)) <= 1
+    assert all("args" not in ev for ev in gaps)     # as it always was
+    _host_gap_leaves_out_the_dispatch_interval(events)
+    assert trainer._dispatch_gap.count == len(gaps)
+    # the ring's reduction sees the same passes, and the spans account
+    # for the loop: what no child covers is a small part of a pass
+    red = spans.loop_phases([ev for ev in chrome["traceEvents"]
+                             if ev.get("ph") in ("X", "i")])
+    assert red["by_name"]["dispatch"]["n"] == len(dispatches)
+    assert sum(red["kinds"].values()) == red["by_name"]["loop_iter"]["n"]
+    assert 0 <= red["loop_self_s"] < red["by_name"]["loop_iter"]["s"]
+    lags = spans.policy_lag([ev for ev in chrome["traceEvents"]
+                             if ev.get("ph") in ("X", "i")])
+    assert lags and min(lags) >= 0
+    assert trainer.log.history and any(
+        tag.endswith("obs_policy_lag_p50_steps")
+        for tag in trainer.log.history)
+
+
+def test_pipelined_loop_spans_and_train_alone_kind(tmp_path, monkeypatch):
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        trainer = _small_trainer(_scripted_messages(12), pipeline=True)
+        trainer.train(total_steps=30, max_seconds=120, log_every=10)
+        chrome = obs_trace.get_ring().to_chrome()
+    finally:
+        obs_trace.reset_for_tests()
+    events = _on_track(chrome, "learner-hot-loop")
+    kinds = {ev["args"]["kind"] for ev in events
+             if ev["name"] == "loop_iter"}
+    assert "train" in kinds and ("fused" in kinds or "ingest" in kinds)
+    staged = {ev["name"] for ev in _on_track(chrome, "ingest-staging")}
+    # the staging thread's events keep the names an operator knows
+    assert staged & {"stage_single", "stage_merged"}
+    assert staged <= {"stage_single", "stage_merged", "stage_scan",
+                      "stage_batch", "publish", "prio_writeback"}
+    _host_gap_leaves_out_the_dispatch_interval(events)
+    sizes = {name: getattr(trainer, name)._cache_size()
+             for name in ("_fused", "_train")}
+    assert set(sizes.values()) <= {0, 1}            # no second program
+
+
+def test_scan_dispatch_splits_its_keys_outside_the_gap(tmp_path, monkeypatch):
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        trainer = _small_trainer(_scripted_messages(), pipeline=False,
+                                 scan_steps=2)
+        trainer.train(total_steps=12, max_seconds=120, log_every=4)
+        chrome = obs_trace.get_ring().to_chrome()
+    finally:
+        obs_trace.reset_for_tests()
+    assert trainer.scan_dispatches > 0
+    events = _on_track(chrome, "learner-hot-loop")
+    assert "scan" in {ev["args"]["kind"] for ev in events
+                      if ev["name"] == "loop_iter"}
+    scans = [ev for ev in events if ev["name"] == "dispatch"
+             and ev["args"]["program"] == "jit_fused_multi_step"]
+    assert len(scans) == trainer.scan_dispatches
+    _host_gap_leaves_out_the_dispatch_interval(events)
+    # the split into per-step keys is evaluated after the gap closed, as
+    # the call expression had it: the dispatch_key span just before each
+    # scan dispatch overlaps no host_gap
+    gaps = [(ev["ts"], ev["ts"] + ev["dur"]) for ev in events
+            if ev["name"] == "host_gap"]
+    keys = sorted((ev["ts"], ev["ts"] + ev["dur"]) for ev in events
+                  if ev["name"] == "dispatch_key")
+    for d in scans:
+        a, b = max(k for k in keys if k[0] <= d["ts"])
+        assert all(b <= g0 + 0.2 or a >= g1 - 0.2 for g0, g1 in gaps)
+
+
+# -- the step program's scopes -------------------------------------------------------
+
+def _hlo_ops(lowered) -> list[tuple[str, str]]:
+    """``(opcode, op_name)`` of every instruction of the compiled program
+    (compiled: XLA's inliner is what prefixes the operations of a called
+    computation, a scan's body among them, with their caller's path)."""
+    text = lowered.compile().as_text()
+    return re.findall(r"= \S+ ([a-z][\w\-]*)\(.*?op_name=\"([^\"]+)\"",
+                      text)
+
+
+def _lowered(family: str, program: str):
+    k_build, key = jax.random.split(jax.random.key(0))
+    beta = jnp.float32(0.4)
+    if family == "dqn":
+        msgs = _scripted_messages(2)
+        trainer = _small_trainer([], pipeline=False)
+        ts, rs = trainer.train_state, trainer.replay_state
+        if program == "fused":
+            return trainer._fused.lower(
+                ts, rs, msgs[0]["payload"],
+                jnp.asarray(msgs[0]["priorities"]), key, beta)
+        return trainer._train.lower(ts, rs, key, beta)
+    if family == "aql":
+        from apex_tpu.envs.registry import make_env
+        from apex_tpu.training.aql import aql_model_spec, build_aql
+        cfg = small_test_config(capacity=256, batch_size=16,
+                                env_id="ApexContinuousNav-v0")
+        cfg = cfg.replace(aql=dataclasses.replace(
+            cfg.aql, propose_sample=4, uniform_sample=4))
+        env = make_env(cfg.env.env_id, cfg.env, seed=0)
+        spec = aql_model_spec(cfg, env)
+        obs_shape = env.observation_space.shape
+        env.close()
+        _model, ts, replay, item, core = build_aql(
+            cfg, spec, obs_shape, np.float32, k_build)
+    else:
+        from apex_tpu.training.r2d2 import build_r2d2
+        cfg = small_test_config(capacity=256, batch_size=8)
+        *_, replay, item, ts, core = build_r2d2(cfg, k_build)
+    rs = jax.eval_shape(lambda: replay.init(item))
+    return core.jit_train_step().lower(ts, rs, key, beta)
+
+
+@pytest.mark.parametrize("family,program", [
+    ("dqn", "fused"), ("dqn", "train"), ("aql", "train"), ("r2d2", "train")])
+def test_step_program_carries_the_scopes(family, program):
+    ops = _hlo_ops(_lowered(family, program))
+    want = set(spans.SCOPES) - (set() if program == "fused" else {"ingest"})
+    seen = {spans.scope_of(name + ":") for _op, name in ops}
+    assert want <= seen, (want, seen)
+    heavy = [(op, name) for op, name in ops
+             if op in ("dot", "convolution")]
+    assert heavy
+    for op, name in heavy:
+        assert spans.scope_of(name + ":") in spans.SCOPES, (op, name)
+    inner = {part for _op, name in ops for part in name.split("/")}
+    assert {"loss_grad", "optimizer", "target_sync"} <= inner
+
+
+def test_scope_of_reads_the_path_not_the_primitive():
+    assert spans.scope_of(
+        "jit(fused_step)/update/loss_grad/jvp(DuelingDQN)/Conv_0/"
+        "conv_general_dilated:") == "update"
+    assert spans.scope_of("jit(train_step)/sample/while/body/gather:") \
+        == "sample"
+    assert spans.scope_of("jit(train_step)/gather:") is None
+    assert spans.scope_of("jit(train_step)/dot_general:") is None
+    assert spans.scope_of(None) is None
+
+
+# -- policy lag in learner steps -----------------------------------------------------
+
+def test_learner_obs_lag_steps_with_gaps_and_an_evicted_version():
+    ring = TraceRing("learner", enabled=True)
+    obs = LearnerObs(ring=ring, max_versions=3, clock=lambda: 0.0,
+                     wall=lambda: 10.0)
+    for version, step in ((1, 0), (2, 25), (4, 75), (5, 100), (6, 125)):
+        obs.note_publish(version, step)         # 3 never noted; 1, 2 evicted
+    assert list(obs._pub) == [4, 5, 6]
+
+    def consume(pv, step):
+        span = {"pv": pv, "hops": {"sealed": (0.0, 8.0)}}
+        obs.pre_consume([span])
+        obs.post_consume([span], step)
+        return ring.to_chrome()["traceEvents"][-1]["args"]
+
+    assert consume(5, 130)["lag_steps"] == 30
+    # evicted (or never noted): the first later version the ledger holds,
+    # so the lag reads too low, never too high
+    assert consume(2, 130)["lag_steps"] == 55
+    assert consume(3, 130)["lag_steps"] == 55
+    # a version from the future (another learner life): nothing to join
+    assert consume(9, 130)["lag_steps"] is None
+    # no step given: seconds only, as before
+    assert consume(5, None)["lag_steps"] is None
+    last = ring.to_chrome()["traceEvents"][-1]
+    assert last["name"] == "consume" and last["ph"] == "i"
+    assert last["args"]["age_s"] == pytest.approx(2.0)
+    assert obs.policy_lag.count == 3
+    sc = obs.scalars()
+    assert sc["obs_policy_lag_p50_steps"] == 55
+    assert sc["obs_policy_lag_p99_steps"] == 55
+
+
+def test_publish_records_the_step_the_version_left_at():
+    trainer = _small_trainer([], pipeline=False)
+    trainer._obs = LearnerObs()
+    trainer.steps_rate.tick(40)
+    trainer._publish()
+    assert trainer._obs._pub[trainer.param_version][2] == 40
+
+
+# -- the readers -----------------------------------------------------------------------
+
+def _reader(name: str):
+    path = os.path.join(ROOT, "benchmark", "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location("metric_" + name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_reader_returns_none_without_ring_or_trace(name, monkeypatch):
+    monkeypatch.delenv("APEX_TRACE_DIR", raising=False)
+    obs_trace.reset_for_tests()
+    said = []
+    ctx = dict(window_s=51.0, open={"wall": 0.0}, close={"wall": 4e9},
+               trace=None, traced_s=None,
+               traffic={"step_programs": {"jit_train_step": {}}},
+               say=said.append)
+    try:
+        assert _reader(name).read(ctx) is None
+    finally:
+        obs_trace.reset_for_tests()
+
+
+def test_benchmark_json_lists_the_new_readers_last():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    tail = bench["per_layer"][-len(NEW_METRICS):]
+    assert [m["name"] for m in tail] == list(NEW_METRICS)
+    assert all(m["workloads"] == ["dqn_hostfed"] for m in tail)
+    layers = {m["layer"] for m in bench["per_layer"][:-len(NEW_METRICS)]}
+    assert {m["layer"] for m in tail} <= layers
+
+
+def test_ring_reductions_on_hand_made_events():
+    def x(name, ts, dur, **args):
+        return {"name": name, "ph": "X", "tid": 1000, "ts": ts, "dur": dur,
+                "args": args}
+
+    events = [
+        x("loop_iter", 0, 1000, it=1, kind="fused"),
+        x("dispatch_key", 10, 290, it=1),
+        x("beta", 310, 90, it=1),
+        x("dispatch", 400, 100, it=1, program="jit_fused_step"),
+        {"name": "host_gap", "ph": "X", "tid": 1000, "ts": 5, "dur": 395},
+        x("loop_iter", 1005, 995, it=2, kind="train"),
+        x("dispatch", 1300, 100, it=2, program="jit_train_step"),
+        {"name": "stage_single", "ph": "X", "tid": 1001, "ts": 0, "dur": 900},
+        {"name": "consume", "ph": "i", "tid": 1002, "ts": 50,
+         "args": {"pv": 3, "lag_steps": 40, "age_s": 0.5}},
+        {"name": "consume", "ph": "i", "tid": 1002, "ts": 60,
+         "args": {"pv": 3, "lag_steps": None, "age_s": 0.5}},
+    ]
+    red = spans.loop_phases(events)
+    assert red["by_name"]["loop_iter"] == {"n": 2, "s": pytest.approx(1995e-6)}
+    assert red["by_name"]["dispatch"]["n"] == 2
+    assert "host_gap" not in red["by_name"]
+    assert "stage_single" not in red["by_name"]
+    assert red["loop_self_s"] == pytest.approx((1995 - 480 - 100) * 1e-6)
+    assert red["kinds"] == {"fused": 1, "train": 1}
+    assert red["programs"]["jit_train_step"]["n"] == 1
+    assert spans.policy_lag(events) == [40.0]
+    assert spans.loop_phases([e for e in events
+                              if e["name"] != "loop_iter"]) is None
+    window = spans.ring_window({"traceEvents": events}, 0.0, 0.0011)
+    assert len(window) == len(events) - 1           # the late dispatch is out
+
+
+# -- the wire format and the trace's reductions ---------------------------------------------
+
+def _varint(n: int) -> bytes:
+    n &= (1 << 64) - 1
+    out = bytearray()
+    while True:
+        b = n & 0x7F
+        n >>= 7
+        out.append(b | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _field(number: int, value) -> bytes:
+    if isinstance(value, int):
+        return _varint(number << 3) + _varint(value)
+    if isinstance(value, float):
+        return _varint(number << 3 | 1) + struct.pack("<d", value)
+    if isinstance(value, str):
+        value = value.encode()
+    return _varint(number << 3 | 2) + _varint(len(value)) + value
+
+
+def _msg(*fields) -> bytes:
+    return b"".join(_field(n, v) for n, v in fields)
+
+
+STAT_IDS = {"tf_op": 1, "source": 2, "hlo_category": 3, "program": 4,
+            "it": 5, "jit_train_step": 6, "flops": 7}
+
+
+def _stat(name: str, value, ref: bool = False) -> bytes:
+    kind = 7 if ref else {int: 4, float: 2, str: 5}[type(value)]
+    return _msg((1, STAT_IDS[name]),
+                (kind, STAT_IDS[value] if ref else value))
+
+
+def _plane(name: str, metadata: dict, lines: dict) -> bytes:
+    """``metadata``: ``{id: (name, [stat, ...])}``; ``lines``: ``{name:
+    (timestamp ns, [(metadata id, offset us, duration us, [stat, ...])])}``
+    """
+    fields = [(2, name)]
+    for line_name, (t0_ns, events) in lines.items():
+        evs = [(4, _msg((1, m), (2, int(off * 1e6)), (3, int(dur * 1e6)),
+                        *[(4, s) for s in stats]))
+               for m, off, dur, stats in events]
+        fields.append((3, _msg((2, line_name), (3, t0_ns), *evs)))
+    for meta_id, (meta_name, stats) in metadata.items():
+        fields.append((4, _msg((1, meta_id), (2, _msg(
+            (1, meta_id), (2, meta_name), *[(5, s) for s in stats])))))
+    for stat_name, stat_id in STAT_IDS.items():
+        fields.append((5, _msg((1, stat_id), (2, _msg(
+            (1, stat_id), (2, stat_name))))))
+    return _msg(*fields)
+
+
+@pytest.fixture(scope="module")
+def synthetic_trace(tmp_path_factory):
+    """Two passes of the loop and what they put on the device, times in
+    microseconds: pass 1 dispatches ``jit_fused_step`` (after an eager
+    ``jit__threefry_split``), pass 2 ``jit_train_step``."""
+    fused = "jit(fused_step)/"
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(123)", []),
+        2: ("jit_train_step(456)", []),
+        3: ("jit__threefry_split(9)", []),
+        10: ("%scatter = u8[8]", [_stat("tf_op", fused + "ingest/scatter:")]),
+        11: ("%while = s32[32]", [_stat("tf_op", fused + "sample/while:")]),
+        12: ("%fusion.body = s32[32]", []),         # inherits the while's
+        13: ("%gather = u8[32]", [
+            _stat("tf_op", fused + "gather/gather:"), _stat("flops", 0)]),
+        14: ("%conv = bf16[32]", [_stat(
+            "tf_op", fused + "update/loss_grad/jvp(DuelingDQN)/Conv_0/"
+            "conv_general_dilated:")]),
+        15: ("%scatter.2 = f32[32]", [
+            _stat("tf_op", fused + "writeback/scatter-add:")]),
+        16: ("%copy.3 = u8[32]", [_stat("source", "frame_pool.py:385"),
+                                  _stat("hlo_category", "data formatting")]),
+        17: ("%rng = u32[2]", [_stat("tf_op", "jit(_threefry_split)/x:")]),
+    }, {
+        "XLA Modules": (0, [(3, 100, 20, []), (1, 450, 450, []),
+                            (2, 1350, 350, [])]),
+        "XLA Ops": (0, [
+            (17, 100, 20, []),
+            (10, 450, 50, []), (11, 500, 100, []), (12, 510, 40, []),
+            (13, 600, 100, []), (14, 700, 100, []), (15, 800, 50, []),
+            (16, 850, 30, []),
+            (11, 1350, 100, []), (13, 1450, 100, []), (14, 1550, 100, []),
+            (15, 1650, 50, [])]),
+    })
+
+    def ann(it, program=None):
+        stats = [_stat("it", it)]
+        if program == "jit_train_step":
+            stats.append(_stat("program", program, ref=True))
+        elif program:
+            stats.append(_stat("program", program))
+        return stats
+
+    host = _plane("/host:CPU", {
+        1: ("loop_iter", []), 2: ("dispatch_key", []), 3: ("beta", []),
+        4: ("dispatch", []), 5: ("PjitFunction(fused_step)", []),
+        6: ("stage_single", []),
+    }, {
+        "python3": (1, [                # 1 ns = 0.001 us into the session
+            (1, 0, 1000, ann(1)), (2, 10, 290, ann(1)), (3, 310, 90, ann(1)),
+            (4, 400, 100, ann(1, "jit_fused_step")), (5, 405, 90, []),
+            (1, 1005, 995, ann(2)), (2, 1010, 190, ann(2)),
+            (3, 1210, 90, ann(2)), (4, 1300, 100, ann(2, "jit_train_step")),
+        ]),
+        "python3 ": (0, [(6, 0, 2000, [])]),
+    })
+    path = tmp_path_factory.mktemp("xplane") / "synthetic.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, host),
+                          (1, _plane("/host:metadata", {}, {}))))
+    return str(path)
+
+
+def test_wire_reader_reads_planes_lines_events_and_metadata(synthetic_trace):
+    planes = spans.read_xspace(synthetic_trace)
+    assert [p.name for p in planes] == ["/device:TPU:0", "/host:CPU",
+                                        "/host:metadata"]
+    device, host = planes[0], planes[1]
+    ops = device.line("XLA Ops")
+    assert len(ops) == 12 and ops[1][:3] == (10, 450_000_000, 50_000_000)
+    assert device.meta(14)["stats"]["tf_op"].endswith("conv_general_dilated:")
+    assert device.meta(16)["stats"] == {
+        "source": "frame_pool.py:385", "hlo_category": "data formatting"}
+    assert device.meta(13)["stats"]["flops"] == 0
+    loop = host.line("python3")
+    assert loop[0][1] == 1000                       # the line's timestamp
+    assert spans._stats(loop[3][3], host.stat_names) == {
+        "it": 1, "program": "jit_fused_step"}
+    assert spans._stats(loop[8][3], host.stat_names)["program"] \
+        == "jit_train_step"                         # by reference
+
+
+def test_host_attribution_owns_idle_time_and_matches_one_clock(
+        synthetic_trace):
+    host = spans.host_attribution(spans.read_xspace(synthetic_trace))
+    us = 1e-6
+    assert host["idle_s"] == pytest.approx(800 * us)
+    got = {k: round(v / us, 2) for k, v in host["idle_by_span"].items()}
+    assert got == {"dispatch_key": 370.0, "beta": 180.0, "dispatch": 100.0,
+                   "loop_iter (own)": 145.0}
+    # under no span at all 5 us, under loop_iter alone 145: no phase's
+    assert host["idle_unattributed_s"] == pytest.approx(150 * us, rel=1e-3)
+    # no program before its dispatch: nothing to move, one reading
+    assert host["clock_skew_floor_us"] == 0.0
+    assert host["idle_by_span_moved"] == host["idle_by_span"]
+    assert host["idle_unattributed_moved_s"] == host["idle_unattributed_s"]
+    assert host["annotations"] == {"loop_iter": 2, "dispatch_key": 2,
+                                   "beta": 2, "dispatch": 2}
+    assert host["inside_dispatch"][0][0] == "PjitFunction(fused_step)"
+    clock = host["one_clock"]
+    assert (clock["dispatches"], clock["modules"], clock["paired"]) \
+        == (2, 2, 2)
+    assert clock["shift"] == 0 and clock["mismatched"] == 0
+    assert clock["started_before_dispatch"] == 0
+    assert clock["offset_us_median"] == pytest.approx(50.0, abs=0.01)
+
+
+def test_idle_seconds_by_span_follow_the_alignment():
+    ps = 1_000_000                                  # 1 us
+    segments = spans._leaf_segments([
+        (0, 100 * ps, "loop_iter"), (10 * ps, 40 * ps, "dispatch_key"),
+        (40 * ps, 60 * ps, "beta"), (60 * ps, 90 * ps, "dispatch")])
+    busy = [(0, 20 * ps), (70 * ps, 95 * ps), (120 * ps, 130 * ps)]
+    idle, by_span, loose = spans._idle_by_span(busy, segments)
+    assert idle == 75 * ps
+    assert {k: round(v * 1e6, 3) for k, v in by_span.items()} == {
+        "dispatch_key": 20.0, "beta": 20.0, "dispatch": 10.0,
+        "loop_iter (own)": 5.0}
+    assert loose == 25 * ps             # 5 under the pass alone, 20 beyond
+    # the device 10 us later: the same gaps lie under later spans
+    later = [(a + 10 * ps, b + 10 * ps) for a, b in busy]
+    idle, by_span, loose = spans._idle_by_span(later, segments)
+    assert idle == 75 * ps
+    assert {k: round(v * 1e6, 3) for k, v in by_span.items()} == {
+        "dispatch_key": 10.0, "beta": 20.0, "dispatch": 20.0}
+    assert loose == 25 * ps
+
+
+def test_device_scopes_account_for_the_step_programs(synthetic_trace):
+    planes = spans.read_xspace(synthetic_trace)
+    red = spans.device_scopes(planes, ("jit_fused_step", "jit_train_step"))
+    us = 1e-6
+    fused = red["programs"]["jit_fused_step"]
+    assert fused["calls"] == 1
+    assert fused["seconds"] == pytest.approx(450 * us)
+    assert {k: round(v / us, 2) for k, v in fused["scopes"].items()} == {
+        "ingest": 50.0, "sample": 100.0, "gather": 100.0, "update": 100.0,
+        "writeback": 50.0}
+    assert fused["unscoped_s"] == pytest.approx(30 * us)
+    assert red["unscoped_ops"][0][0] == "%copy.3 = u8[32]"
+    assert red["unscoped_ops"][0][1]["source"] == "frame_pool.py:385"
+    t = spans.scope_totals(red)
+    assert t["scopes"]["ingest"] == {"s": pytest.approx(50 * us), "calls": 1}
+    assert t["scopes"]["sample"] == {"s": pytest.approx(200 * us), "calls": 2}
+    assert t["module_s"] == pytest.approx(800 * us)
+    assert t["scoped_s"] + t["unscoped_ops_s"] + t["between_ops_s"] \
+        == pytest.approx(t["module_s"])
+    assert t["between_ops_s"] == pytest.approx(20 * us)
+    # a trace from before the scopes: nothing to read, and no error
+    assert spans.device_scopes(planes, ("jit__threefry_split",)) is None
+    small = spans.read_xspace(os.path.join(FIXTURES, "small.xplane.pb"))
+    assert spans.device_scopes(small, ("jit_fused_step",
+                                       "jit_train_step")) is None
+    assert spans.host_attribution(small) is None
+
+
+# -- the trace recorded on the chip ------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def scoped_fixture():
+    with open(os.path.join(FIXTURES, "scoped.expected.json")) as f:
+        want = json.load(f)
+    red = spans.reduce_file(os.path.join(FIXTURES, "scoped.xplane.pb"),
+                            ("jit_fused_step", "jit_train_step"))
+    return want, red
+
+
+def test_fixture_scopes_add_up_to_the_programs_device_seconds(scoped_fixture):
+    want, red = scoped_fixture
+    assert want["device"] == "TPU v5 lite"
+    programs = red["scopes"]["programs"]
+    assert {n: p["calls"] for n, p in programs.items()} \
+        == want["recorded"]["programs"]
+    for name, p in programs.items():
+        assert all(s > 0 for scope, s in p["scopes"].items()
+                   if scope != "ingest"), (name, p)
+        assert p["unscoped_s"] > 0          # the multiply under no scope
+    assert programs["jit_fused_step"]["scopes"]["ingest"] > 0
+    assert programs["jit_train_step"]["scopes"]["ingest"] == 0
+    t = red["scopes"]["totals"]
+    assert t["scoped_s"] == pytest.approx(
+        sum(v["s"] for v in t["scopes"].values()))
+    assert t["scoped_s"] + t["unscoped_ops_s"] + t["between_ops_s"] \
+        == pytest.approx(t["module_s"], rel=1e-12)
+    assert 0 <= t["between_ops_s"] < 0.1 * t["module_s"]
+    assert t["scopes"]["ingest"]["calls"] == 3
+    assert t["scopes"]["update"]["calls"] == 6
+    # the body of the fori_loop counts under its scope, its own time apart
+    assert t["scopes"]["sample"]["s"] > t["scopes"]["writeback"]["s"]
+
+
+def test_fixture_annotations_are_as_recorded_and_on_the_device_clock(
+        scoped_fixture):
+    want, red = scoped_fixture
+    host = red["host"]
+    recorded = {k: v for k, v in want["recorded"].items() if k != "programs"}
+    assert host["annotations"] == recorded
+    clock = host["one_clock"]
+    assert clock["paired"] == clock["dispatches"] == clock["modules"] == 6
+    assert clock["mismatched"] == 0 and clock["shift"] == 0
+    # the session's clocks agree to within a millisecond, and are read as
+    # written: every program of this trace reads as started before its
+    # dispatch, which the reduction reports and does not correct
+    assert abs(clock["offset_us_median"]) < 2000
+    assert clock["started_before_dispatch"] == 6
+    assert host["clock_skew_floor_us"] == pytest.approx(
+        -clock["offset_us_min"])
+    # ... and shares the idle seconds out a second time with the device
+    # moved later by that floor: the same seconds, a little elsewhere
+    assert sum(host["idle_by_span_moved"].values()) == pytest.approx(
+        sum(host["idle_by_span"].values()), rel=1e-3)
+    assert host["idle_by_span_moved"] != host["idle_by_span"]
+    # what lies under loop_iter alone names no phase: unattributed
+    assert host["idle_unattributed_s"] == pytest.approx(
+        host["idle_s"] - sum(s for name, s in host["idle_by_span"].items()
+                             if name != "loop_iter (own)"))
+    assert host["idle_unattributed_s"] \
+        >= host["idle_by_span"]["loop_iter (own)"]
+    assert set(host["idle_by_span"]) <= {
+        "dispatch_key", "beta", "dispatch", "loop_iter (own)"}
+    assert any(name.startswith("PjitFunction(")
+               for name, _v in host["inside_dispatch"])
+    assert json.loads(json.dumps(red)) == want["reduced"]
+    assert spans.main([os.path.join(FIXTURES, "scoped.xplane.pb"),
+                       "--check"]) == 0
