@@ -50,6 +50,7 @@ from apex_tpu.serving.deploy import ServingStat
 from apex_tpu.tenancy.scheduler import TenancyStat
 from apex_tpu.training.checkpoint import (CheckpointableTrainer,
                                           Checkpointer)
+from apex_tpu.training.ingest_pipeline import KeyBlocks
 from apex_tpu.training.learner import LearnerCore
 from apex_tpu.training.state import create_train_state
 from apex_tpu.utils.metrics import MetricLogger, RateCounter
@@ -111,6 +112,15 @@ class ConcurrentTrainer(CheckpointableTrainer):
     _loop_track = "learner-hot-loop"
     _pass = 0
     _pass_kind = "idle"
+    # the per-pass operands no pass launches a program for: the key
+    # chain's blocks (_dispatch_key; made on first use, kept across
+    # train() calls) and the device scalar of the host float beta() last
+    # returned (_dispatch)
+    _key_blocks = None
+    _beta_host = None
+    _beta_dev = None
+    beta_puts = 0            # dispatches that transferred a new float
+    beta_reused = 0          # dispatches that took the scalar held
     # checkpoint/log bookkeeping persists ACROSS train() calls: a driver
     # interleaving short train() bursts with eval must still hit its
     # save/log cadence (per-call resets would silence both whenever
@@ -537,7 +547,11 @@ class ConcurrentTrainer(CheckpointableTrainer):
                     if metrics is not None \
                             and steps - self._last_log >= log_every:
                         with self._span("log_scalars"):
-                            extra = gap.snapshot()
+                            extra = gap.snapshot() | {
+                                "loop_key_refills": self._blocks().refills,
+                                "loop_keys_served": self._blocks().served,
+                                "loop_beta_puts": self.beta_puts,
+                                "loop_beta_reused": self.beta_reused}
                             if pipeline is not None:
                                 extra |= {f"pipeline_{k}": v
                                           for k, v in pipeline.stats.items()}
@@ -1204,20 +1218,46 @@ class ConcurrentTrainer(CheckpointableTrainer):
 
     def _dispatch_key(self):
         """One dispatch's PRNG key, advancing the key chain exactly as
-        the serial loop's ``self.key, k = split(self.key)`` does.  While
-        a sharded pipelined run is live, the pipeline's KeyPrefetcher
-        owns the chain: it hands back keys already split per chip and
-        placed over the mesh, plus the chain state the inline split
-        would have left in ``self.key`` (so mid-train checkpoints and
-        post-train ``self.key`` stay bit-identical to a serial run of
-        the same dispatch count)."""
+        the serial loop's ``self.key, k = split(self.key)`` would, without
+        a program launched for it in this pass.  ``self.key`` is the
+        chain; whoever assigns it (this method, construction, a checkpoint
+        restore, ``evaluate()``) is followed from there.
+
+        Single-shard plan: the trainer's :class:`KeyBlocks` hands out
+        pairs ``(k_i, chain_{i+1})`` of a block one program made, for as
+        long as ``self.key`` IS the chain object it handed out last; a
+        ``self.key`` assigned from outside, or a block run dry, is
+        answered with a block made from that very ``self.key`` here, on
+        the loop thread (ring instant ``key_refill``: the one pass in
+        ``KEY_BLOCK`` that launches), so keys and ``self.key`` are
+        bit-identical to the eager chain at every dispatch count and a
+        checkpoint taken mid-block saves the next key's parent.
+
+        While a sharded pipelined run is live, the pipeline's
+        KeyPrefetcher owns the chain instead (seeded with ``self.key``):
+        it hands back keys already split per chip and placed over the
+        mesh, plus the chain state the inline split would have left in
+        ``self.key``."""
         with self._span("dispatch_key"):
             pipe = self._pipeline
             if pipe is not None and pipe.keys is not None:
                 placed, self.key = pipe.keys.take()
                 return placed
-            self.key, k = jax.random.split(self.key)
+            blocks = self._blocks()
+            taken = blocks.refills
+            k, self.key = blocks.take(self.key)
+            if blocks.refills != taken:
+                self._ring.instant(
+                    "key_refill", self._loop_track,
+                    {"it": self._pass, "served": blocks.served - 1,
+                     "beta_puts": self.beta_puts,
+                     "beta_reused": self.beta_reused})
             return k
+
+    def _blocks(self) -> KeyBlocks:
+        if self._key_blocks is None:
+            self._key_blocks = KeyBlocks()
+        return self._key_blocks
 
     # -- host spans + the one dispatch site --------------------------------
 
@@ -1261,9 +1301,14 @@ class ConcurrentTrainer(CheckpointableTrainer):
         The block is the interval ``host_gap`` leaves out, as the
         hand-written sites had it: it opens with
         ``gap.about_to_dispatch()``, ``beta()`` (a host float) becomes
-        the step's last operand inside it (the eager convert, timed apart
-        as ``beta``), ``call`` is ``fn`` under a ``dispatch`` span, and the
-        caller's assignment of the results (``adopt``, inside
+        the step's last operand inside it (timed apart as ``beta``): the
+        device scalar held from the last dispatch while the float is the
+        same, which it is between two chunks and for good once the anneal
+        ends (counter ``beta_reused``); else a 4-byte transfer of
+        ``np.float32(b)`` (``beta_puts``).  Never a program, and always
+        the strong-typed uncommitted ``f32[]`` a ``jnp.float32`` gives, so
+        the same compiled step.  ``call`` is ``fn`` under a ``dispatch``
+        span, and the caller's assignment of the results (``adopt``, inside
         ``dispatch``: the donated state's Python objects die there) still
         lies before ``gap.dispatch_returned()``.  ``kind`` names the pass
         (``loop_iter``'s arg); ``program`` is the name XLA gives ``fn``,
@@ -1275,7 +1320,14 @@ class ConcurrentTrainer(CheckpointableTrainer):
         tail = ()
         if beta is not None:
             with self._span("beta"):
-                tail = (jnp.float32(beta()),)
+                b = beta()
+                if b != self._beta_host:
+                    self._beta_host = b
+                    self._beta_dev = jax.device_put(np.float32(b))
+                    self.beta_puts += 1
+                else:
+                    self.beta_reused += 1
+                tail = (self._beta_dev,)
         with contextlib.ExitStack() as spans:
             def call(*args):
                 spans.enter_context(self._span(

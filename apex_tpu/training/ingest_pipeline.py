@@ -69,9 +69,9 @@ stages group-granular slots:
   single-shard merge contract per shard;
 * per-chip PRNG keys are PRE-SPLIT and PRE-PLACED by a
   :class:`KeyPrefetcher` that owns the trainer's dispatch key chain: the
-  serial loop pays a host ``jax.random.split`` + sharded ``device_put``
-  inside every dispatch (``ShardedLearner.device_keys``); the prefetcher
-  generates the exact same chain ahead of time on the staging side.
+  serial loop pays a sharded ``device_put`` inside every dispatch
+  (``ShardedLearner.device_keys``); the prefetcher generates the exact
+  same chain ahead of time on the staging side.
 """
 
 from __future__ import annotations
@@ -225,7 +225,14 @@ def merge_group_messages(msgs: list[dict], n_dp: int) -> dict:
 class KeyPrefetcher:
     """Pre-split, pre-placed per-chip PRNG keys for the sharded plan.
 
-    Owns the trainer's dispatch key chain while the pipeline is live.
+    Owns the trainer's dispatch key chain while a sharded pipeline is
+    live, and only then: it is seeded with the trainer's ``self.key`` when
+    ``train()`` builds the pipeline, every ``take()`` is assigned back to
+    ``self.key``, and when the pipeline stops the trainer's own
+    ``_dispatch_key`` goes on from that ``self.key`` (:class:`KeyBlocks`,
+    the single-shard plan's way, follows whatever ``self.key`` is and
+    holds no chain of its own) — one chain, one owner at a time.
+
     Entry i is ``(device_keys(k_i), chain_{i+1})`` where ``chain_{i+1},
     k_i = split(chain_i)`` — the EXACT per-dispatch sequence the serial
     loop produces with ``self.key, k = split(self.key)`` followed by
@@ -264,6 +271,56 @@ class KeyPrefetcher:
             if not self._queue:
                 self._gen()
             return self._queue.popleft()
+
+
+#: dispatch keys one launch of the key-chain program makes
+KEY_BLOCK = 64
+
+
+@jax.jit
+def _split_block(chain):
+    """``KEY_BLOCK`` sequential ``chain, k = split(chain)`` in ONE program:
+    ``[(k_i, chain_{i+1})]``, every key a scalar array of its own, so
+    that handing a pair out launches nothing.  Bit-identical to the eager
+    chain."""
+    def step(chain, _):
+        chain, k = jax.random.split(chain)
+        return chain, (k, chain)
+    _, (ks, chains) = jax.lax.scan(step, chain, None, length=KEY_BLOCK)
+    return [(ks[i], chains[i]) for i in range(KEY_BLOCK)]
+
+
+class KeyBlocks:
+    """The single-shard plan's dispatch keys, a block per launch.
+
+    The trainer's ``self.key`` stays the one chain: ``take(chain)`` is
+    given it and answers ``(k, chain_after)`` with the
+    :class:`KeyPrefetcher` contract (the caller assigns ``chain_after``
+    back), popped off a block of :data:`KEY_BLOCK` pairs that
+    :func:`_split_block` made.  A block is held by IDENTITY: it serves
+    only while the chain passed in is the very object handed out last.
+    A chain assigned from outside (checkpoint restore, ``evaluate()``) or
+    a block run dry gets a block made from the chain passed in, so the
+    keys are the eager chain's at every count whatever else touches
+    ``self.key``.  Loop thread only: the one pass in :data:`KEY_BLOCK`
+    that runs dry pays the launch and its ``2 * KEY_BLOCK`` output
+    buffers (about 0.13 ms of host time each on the chip, PERF.md, PR 27).
+    """
+
+    def __init__(self):
+        self._pairs: list = []          # the block in hand, next pair last
+        self._chain = None              # the chain object handed out last
+        self.refills = 0                # blocks made
+        self.served = 0                 # keys handed out
+
+    def take(self, chain):
+        """``(k, chain_after)`` going on from ``chain``."""
+        if chain is not self._chain or not self._pairs:
+            self._pairs = _split_block(chain)[::-1]
+            self.refills += 1
+        k, self._chain = self._pairs.pop()
+        self.served += 1
+        return k, self._chain
 
 
 @dataclass
