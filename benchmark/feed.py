@@ -25,12 +25,18 @@ def program_seed(seed: int) -> int:
     return (lo ^ (hi * 2654435)) % (2 ** 31 - 1)
 
 
-def make_weights(shape_tree, seed: int):
+def make_weights(shape_tree, seed: int, init_rule=None):
     """Float32 weights with the tree and shapes of ``shape_tree``.
 
-    Kernels: ``N(0, 2 / fan_in)``; biases and other vectors: ``N(0, 0.01^2)``;
-    NoisyNet ``*_sigma`` leaves: the constant ``0.4 / sqrt(fan_in)`` of
-    Fortunato et al. (fan-in read from the sibling ``w_sigma``)."""
+    The family's reference module may say how a leaf is drawn:
+    ``init_rule(path, shape)``, ``path`` the leaf's keys from the root,
+    returns ``("normal", std)``, ``("const", value)`` or ``None``.  Where
+    there is no rule, or it returns ``None``: kernels ``N(0, 2 / fan_in)``
+    with the fan-in every axis but the last; biases and other vectors
+    ``N(0, 0.01^2)``; NoisyNet ``*_sigma`` leaves the constant
+    ``0.4 / sqrt(fan_in)`` of Fortunato et al. (fan-in read from the
+    sibling ``w_sigma``).  A leaf's key is ``fold_in(key, i)`` by its place
+    in flattening order whatever rule draws it."""
     import jax
     import jax.numpy as jnp
 
@@ -41,6 +47,13 @@ def make_weights(shape_tree, seed: int):
                for path, _ in flat]
     fan_of_parent = {par: shp[0] for par, name, shp
                      in zip(parents, names, shapes) if name == "w_sigma"}
+    rules = [init_rule(par + (name,), shape) if init_rule else None
+             for par, name, shape in zip(parents, names, shapes)]
+    for rule, par, name in zip(rules, parents, names):
+        if rule is not None and rule[0] not in ("normal", "const"):
+            raise ValueError(f"init_rule gave {rule!r} for "
+                             f"{'/'.join(par + (name,))}")
+
     @jax.jit
     def draw(lo, hi):
         # the seed is an argument, not a constant of the program: one
@@ -50,7 +63,12 @@ def make_weights(shape_tree, seed: int):
         out = []
         for i, (shape, name, par) in enumerate(zip(shapes, names, parents)):
             k = jax.random.fold_in(key, i)
-            if name.endswith("_sigma"):
+            if rules[i] is not None:
+                kind, value = rules[i]
+                out.append(jnp.full(shape, value, jnp.float32)
+                           if kind == "const" else
+                           jax.random.normal(k, shape, jnp.float32) * value)
+            elif name.endswith("_sigma"):
                 fan = fan_of_parent.get(par, shape[0])
                 out.append(jnp.full(shape, 0.4 / math.sqrt(fan),
                                     jnp.float32))

@@ -11,6 +11,7 @@ is the benchmark's own.
 
 from __future__ import annotations
 
+import functools
 import gc
 import importlib
 import json
@@ -94,19 +95,21 @@ def metrics_for(bench: dict, workload: str, kind: str) -> list[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-# -- leaves by parameter path -------------------------------------------------
+# -- leaves by parameter path, and their norms where they live ----------------
 
 def _dict_keys(path) -> tuple[str, ...]:
     return tuple(str(k.key) for k in path if hasattr(k, "key"))
 
 
-def leaves_by_path(tree) -> dict[tuple, np.ndarray]:
+def by_path(tree) -> dict[tuple, object]:
+    """The tree's leaves keyed by parameter path, where they are (device
+    or host): nothing is copied."""
     import jax
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    return {_dict_keys(p): np.asarray(x, np.float32) for p, x in flat}
+    return {_dict_keys(p): x for p, x in flat}
 
 
-def moment_by_path(opt_state, field: str) -> dict[tuple, np.ndarray]:
+def moment_by_path(opt_state, field: str) -> dict[tuple, object]:
     """The optimizer's first-moment leaves keyed by parameter path,
     wherever the optimizer's chain keeps them (``.mu`` of RMSprop's
     state)."""
@@ -117,25 +120,58 @@ def moment_by_path(opt_state, field: str) -> dict[tuple, np.ndarray]:
         if field not in names or not hasattr(leaf, "shape"):
             continue
         after = path[len(names) - 1 - names[::-1].index(field) + 1:]
-        out[_dict_keys(after)] = np.asarray(leaf, np.float32)
+        out[_dict_keys(after)] = leaf
     return out
+
+
+@functools.cache
+def _norm_programs():
+    """The two jitted reductions ``leaf_norms`` runs: every leaf's L2 norm,
+    and that of the difference of two trees, in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    def norm(x):
+        return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
+
+    return (jax.jit(lambda xs: [norm(x) for x in xs]),
+            jax.jit(lambda xs, ys: [norm(x - y) for x, y in zip(xs, ys)]))
+
+
+def leaf_norms(leaves: dict, minus: dict | None = None) -> dict[tuple, float]:
+    """The L2 norm of every leaf (of ``leaf - minus[path]`` where ``minus``
+    is given), in float32, in one program on the device the leaves live
+    on: a scalar a leaf comes to the host, no leaf does."""
+    import jax
+    norms, norms_of_change = _norm_programs()
+    xs = list(leaves.values())
+    got = (norms(xs) if minus is None else
+           norms_of_change(xs, [minus[k] for k in leaves]))
+    return {k: float(n) for k, n in zip(leaves, jax.device_get(got))}
+
+
+def drop_norm_programs() -> None:
+    """Unload what ``leaf_norms`` compiled.  A loaded executable holds
+    device memory (194 KB each for ``dqn``'s tree): left loaded after the
+    checked steps, the two would stand in the window's peak."""
+    for program in _norm_programs():
+        program.clear_cache()
 
 
 # -- the compared numbers -------------------------------------------------------
 
 def leaf_norm_gaps(prog: dict, ref: dict, keep=None) -> dict:
-    """Per leaf ``| ||prog|| - ||ref|| | / max(||ref||, median ||ref||)``:
-    the gap between the two norms, not the norm of the difference."""
-    norms = {k: float(np.linalg.norm(v)) for k, v in ref.items()}
-    med = float(np.median(list(norms.values())))
+    """Per leaf ``| ||prog|| - ||ref|| | / max(||ref||, median ||ref||)``
+    from the two sides' norms by path (``leaf_norms``): the gap between
+    the two norms, not the norm of the difference."""
+    med = float(np.median(list(ref.values())))
     out = {}
-    for k, rn in norms.items():
+    for k, rn in ref.items():
         if keep is not None and not keep(k):
             continue
         gap = float("inf")
         if k in prog:
-            gap = abs(float(np.linalg.norm(prog[k])) - rn) / max(rn, med,
-                                                                 1e-30)
+            gap = abs(prog[k] - rn) / max(rn, med, 1e-30)
         out["/".join(k)] = gap if math.isfinite(gap) else float("inf")
     return out
 
@@ -166,16 +202,15 @@ def readings(prog: dict, ref: dict) -> dict:
     q = [g if math.isfinite(g) else float("inf") for g in q]
     out["q_gap"] = (max(q), f"step {int(np.argmax(q)) + 1}")
     out["q1_gap"] = (q[0], "step 1")
-    grad = leaf_norm_gaps(prog["first_grad"], ref["first_grad"])
+    grad = leaf_norm_gaps(prog["first_grad_norm"], ref["first_grad_norm"])
     out["grad_gap"] = _worst(grad)
     out["grad_median_gap"] = (float(np.median(list(grad.values()))),
                               "median leaf")
     # leaves whose reference gradient is nought to rounding move under the
     # optimizer by round-off alone: left out by a rule on the gradient
-    gnorm = {k: float(np.linalg.norm(v))
-             for k, v in ref["first_grad"].items()}
+    gnorm = ref["first_grad_norm"]
     gmed = float(np.median(list(gnorm.values())))
-    dpar = leaf_norm_gaps(prog["dparam"], ref["dparam"],
+    dpar = leaf_norm_gaps(prog["dparam_norm"], ref["dparam_norm"],
                           keep=lambda k: gnorm[k] >= 1e-3 * gmed)
     out["dparam_gap"] = _worst(dpar)
     out["dparam_median_gap"] = (float(np.median(list(dpar.values()))),
@@ -279,18 +314,34 @@ class Run:
         self.cfg = config_from_args(self.args)
         self.trainer, self.train_kw = build_trainer(self.args, self.cfg)
         tr = self.trainer
-        params = feed.make_weights(tr.train_state.params, self.seed)
-        self.weights0 = jax.device_get(params)          # the reference's copy
-        tr.train_state = tr.train_state.replace(
-            params=params,
-            target_params=jax.tree.map(lambda x: x.copy(), params),
-            opt_state=tr.core.optimizer.init(params))
+        self.seed_weights()
         jax.block_until_ready((tr.train_state, tr.replay_state))
         # the window's edges: a one-op program queued behind every step
         # dispatched so far, so its result marks "all of them completed"
         self._fence = jax.jit(lambda x: x + 1)
         self._fence(jax.numpy.int32(0)).block_until_ready()
         self.say("trainer built, the seed's weights in place")
+
+    def seed_weights(self) -> None:
+        """The seed's weights in the trainer's place, one learner state at
+        a time: the parameters, target and moments that are there are let
+        go before their replacements are made (their shapes are all the
+        draw needs), so set-up never holds more than the 16 bytes a
+        parameter the run itself holds.  ``weights0`` is the host copy the
+        reference starts from once the program's state is freed."""
+        import jax
+        tr = self.trainer
+        shapes = jax.eval_shape(lambda p: p, tr.train_state.params)
+        tr.train_state = tr.train_state.replace(
+            params=None, target_params=None, opt_state=None)
+        params = feed.make_weights(shapes, self.seed,
+                                   getattr(self.family, "init_rule", None))
+        self.weights0 = jax.device_get(params)
+        tr.train_state = tr.train_state.replace(
+            params=params,
+            target_params=jax.tree.map(lambda x: x.copy(), params),
+            opt_state=tr.core.optimizer.init(params),
+            step=jax.numpy.int32(0))
 
     def checked_steps(self) -> None:
         """Drive the programs the window drives (the mix's
@@ -378,16 +429,16 @@ class Run:
             self.steps.append(dict(idx=idx, key=k_update, beta=beta,
                                    size=size))
             if i == 0:
-                mu = moment_by_path(jax.device_get(tr.train_state.opt_state),
-                                    chk["first_moment"])
-                first_grad = {p: v * chk["first_moment_scale"]
-                              for p, v in mu.items()}
-        after = leaves_by_path(jax.device_get(tr.train_state.params))
-        before = leaves_by_path(self.weights0)
+                mu = leaf_norms(moment_by_path(tr.train_state.opt_state,
+                                               chk["first_moment"]))
+                first_grad_norm = {p: n * chk["first_moment_scale"]
+                                   for p, n in mu.items()}
         self.program = dict(
             losses=losses, q_means=q_means, written=written,
-            first_grad=first_grad,
-            dparam={p: after[p] - before[p] for p in before})
+            first_grad_norm=first_grad_norm,
+            dparam_norm=leaf_norms(by_path(tr.train_state.params),
+                                   minus=by_path(self.weights0)))
+        drop_norm_programs()
         self.say(f"checked steps {programs}: losses {losses}")
 
     # -- the window -----------------------------------------------------------------
@@ -523,8 +574,9 @@ class Run:
         self.failed_updates = len(bad)
         # the window drove the very programs the checked steps drove: one
         # compiled program each, not a second one for other operand types
-        sizes = {name: getattr(tr, name)._cache_size()
-                 for name in ("_fused", "_train")}
+        sizes = {"_" + program: getattr(tr, "_" + program)._cache_size()
+                 for program in dict.fromkeys(
+                     self.traffic["checked_programs"])}
         self.say(f"programs held by the jitted steps: {sizes}")
         checks["one_program_each"] = set(sizes.values()) == {1}
         pool = getattr(tr.pool, "pool", tr.pool)
@@ -554,19 +606,12 @@ class Run:
     def reset_state(self, seed: int) -> None:
         """A fresh replay and the weights of another seed in the trainer
         that is there (the readings read many seeds in one process)."""
-        import jax
         tr = self.trainer
         self.seed = int(seed)
         tr.replay_state = None
         gc.collect()
         tr.replay_state = tr.replay.init()
-        params = feed.make_weights(tr.train_state.params, self.seed)
-        self.weights0 = jax.device_get(params)
-        tr.train_state = tr.train_state.replace(
-            params=params,
-            target_params=jax.tree.map(lambda x: x.copy(), params),
-            opt_state=tr.core.optimizer.init(params),
-            step=jax.numpy.int32(0))
+        self.seed_weights()
 
     def reference(self, mode: str = "f32", fault: str | None = None) -> dict:
         """The plain reference over the same three steps: its own weights
@@ -583,9 +628,14 @@ class Run:
         leaves = np.maximum(self.rows["priority"].astype(np.float64),
                             eps) ** alpha
         params = jax.tree.map(jnp.asarray, self.weights0)
-        state = dict(params=params, target_params=params,
+        # target buffers of their own and a state that is never read once
+        # it is handed over: a family's step may donate what it is given
+        state = dict(params=params,
+                     target_params=jax.tree.map(jnp.copy, params),
                      opt=fam.init_opt(params, hp), step=0)
-        losses, q_means, q_abs, written, first_grad = [], [], [], [], None
+        del params
+        losses, q_means, q_abs, written = [], [], [], []
+        frozen = fault == "frozen"          # a step that returns its state
         with jax.default_matmul_precision("highest"):
             for i, st in enumerate(self.steps):
                 idx = st["idx"]
@@ -595,22 +645,30 @@ class Run:
                          for k, v in view.batch(idx).items()}
                 w = jnp.asarray(is_weights(leaves, st["size"], idx,
                                            st["beta"]))
-                new, out = fam.step(state, batch, w, st["key"], hp, mode)
-                if fault != "frozen":       # a step that returns its state
-                    state = new
+                if frozen:                  # the state stays: a copy to eat
+                    given = jax.tree.map(
+                        lambda x: jnp.copy(x) if hasattr(x, "shape") else x,
+                        state)
+                else:
+                    given, state = state, None
+                new, out = fam.step(given, batch, w, st["key"], hp, mode)
+                state = state if frozen else new
+                del given, new
                 losses.append(float(out["loss"]))
                 q_means.append(float(out["q_mean"]))
                 q_abs.append(float(out["q_abs"]))
                 if i == 0:
-                    first_grad = leaves_by_path(jax.device_get(out["grads"]))
+                    first_grad_norm = leaf_norms(by_path(out["grads"]))
                 pr = np.asarray(out["priorities"], np.float64)
+                del out                     # the gradient goes with it
                 leaves[idx] = np.maximum(pr, eps) ** alpha
                 written.append(np.unique(idx))
-        after = leaves_by_path(jax.device_get(state["params"]))
-        before = leaves_by_path(self.weights0)
+        dparam_norm = leaf_norms(by_path(state["params"]),
+                                 minus=by_path(self.weights0))
+        drop_norm_programs()
         return dict(losses=losses, q_means=q_means, q_abs=q_abs,
-                    written=written, first_grad=first_grad,
-                    dparam={p: after[p] - before[p] for p in before})
+                    written=written, first_grad_norm=first_grad_norm,
+                    dparam_norm=dparam_norm)
 
     def judge(self, side: dict | None = None, ref: dict | None = None) -> dict:
         """The numbers compared, each beside its limit: ``side`` (the
