@@ -113,7 +113,8 @@ class DQNWorkerFamily:
         import jax
 
         from apex_tpu.envs.registry import make_env, unstacked_env_spec
-        from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+        from apex_tpu.models import make_q_network
+        from apex_tpu.models.dueling import make_policy_fn
         from apex_tpu.replay.frame_chunks import FrameChunkBuilder
 
         self.seed = seed
@@ -122,7 +123,7 @@ class DQNWorkerFamily:
                             stack_frames=False)
         frame_shape, frame_dtype, frame_stack = unstacked_env_spec(
             self.env, cfg.env)
-        self.policy = jax.jit(make_policy_fn(DuelingDQN(**model_spec)))
+        self.policy = jax.jit(make_policy_fn(make_q_network(model_spec)))
         self.builder = FrameChunkBuilder(
             cfg.learner.n_steps, cfg.learner.gamma, frame_stack, frame_shape,
             chunk_transitions=chunk_transitions, frame_dtype=frame_dtype)

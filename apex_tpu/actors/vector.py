@@ -313,14 +313,15 @@ class VectorDQNWorkerFamily(VectorChunkFamilyBase):
     def __init__(self, cfg: ApexConfig, model_spec: dict, seeds,
                  slot_ids, epsilons, chunk_transitions: int):
         from apex_tpu.envs.registry import unstacked_env_spec
-        from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+        from apex_tpu.models import make_q_network
+        from apex_tpu.models.dueling import make_policy_fn
         from apex_tpu.replay.frame_chunks import FrameChunkBuilder
 
         super().__init__(cfg, seeds, slot_ids, epsilons)
         frame_shape, frame_dtype, frame_stack = unstacked_env_spec(
             self.envs[0], cfg.env)
         self.policy = self._grouped_policy(
-            make_policy_fn(DuelingDQN(**model_spec)))
+            make_policy_fn(make_q_network(model_spec)))
         self.builders = [
             FrameChunkBuilder(
                 cfg.learner.n_steps, cfg.learner.gamma, frame_stack,

@@ -73,6 +73,10 @@ class LearnerConfig:
     save_interval: int = 5000
     # TPU knobs
     compute_dtype: str = "bfloat16"  # MXU-native matmul dtype; params stay f32
+    # The Q-network's torso (--torso): "dueling" is the reference's
+    # Nature-CNN / MLP dueling net; any other name is a preset of
+    # apex_tpu/models/glm4_moe_lite.py (apex_tpu.models.make_q_network)
+    torso: str = "dueling"
     ingest_chunk: int = 512          # transitions folded into each fused step
     mesh_shape: tuple[int, ...] = (1,)
     mesh_axes: tuple[str, ...] = ("dp",)
@@ -178,6 +182,11 @@ class EnvConfig:
     episodic_life: bool = True
     clip_rewards: bool = True
     seed: int = 1122                 # reference default seed (arguments.py:14)
+    # ApexTokens-v0: ids a context holds and the vocabulary they are drawn
+    # from (a frame is u8[2 * token_context]); the CLI sets both from the
+    # --torso preset, whose model reads such frames
+    token_context: int = 16
+    token_vocab: int = 64
 
 
 @dataclass(frozen=True)

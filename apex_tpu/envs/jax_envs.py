@@ -343,3 +343,51 @@ def make_rally(grid: int = 21, pixels: int = 84, points: int = 3,
     return JaxEnv(reset=reset, step=step,
                   frame_shape=(pixels, pixels, 1), num_actions=3,
                   env_id=env_id)
+
+
+# -- Tokens ------------------------------------------------------------------
+
+
+class TokensState(NamedTuple):
+    ids: jax.Array          # i32[T] the context
+    steps: jax.Array        # i32
+
+
+def make_tokens(context: int = 16, vocab: int = 64,
+                episode_steps: int = 64,
+                env_id: str = "ApexTokens-v0") -> JaxEnv:
+    """Bitwise port of :class:`apex_tpu.envs.toy.TokensEnv` (integers
+    only).  The prompt is ONE keyed draw of ``context`` ids at the
+    reset-scope integer tag."""
+
+    def frame(ids):
+        return jnp.stack([ids % 256, ids // 256], 1).reshape(-1).astype(
+            jnp.uint8)
+
+    def fresh(key) -> TokensState:
+        ids = jax.random.randint(jax.random.fold_in(key, _T_RESET_INT),
+                                 (context,), 0, vocab)
+        return TokensState(ids=ids.astype(jnp.int32), steps=jnp.int32(0))
+
+    def reset(key) -> tuple[TokensState, jax.Array]:
+        state = fresh(key)
+        return state, frame(state.ids)
+
+    def step(state: TokensState, action, key):
+        c = state.ids
+        want = (31 * c[-1] + c[-2] + 7) % vocab
+        action = action.astype(jnp.int32)
+        reward = jnp.where(action == want, jnp.float32(1.0),
+                           jnp.float32(0.0))
+        mid = TokensState(ids=jnp.concatenate([c[1:], action[None]]),
+                          steps=state.steps + 1)
+        done = mid.steps >= episode_steps
+        final_frame = frame(mid.ids)
+        # auto-reset from the same key's reset-scope tag
+        new = fresh(key)
+        out = jax.tree.map(lambda a, b: jnp.where(done, a, b), new, mid)
+        obs = jnp.where(done, frame(new.ids), final_frame)
+        return out, obs, reward, done, final_frame
+
+    return JaxEnv(reset=reset, step=step, frame_shape=(2 * context,),
+                  num_actions=vocab, env_id=env_id)

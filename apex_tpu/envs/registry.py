@@ -82,6 +82,13 @@ def make_env(env_id: str | None = None, cfg: EnvConfig | None = None,
     elif env_id.startswith("ApexContinuousNav"):
         env = (toy.ContinuousNavEnv(max_episode_steps=max_episode_steps)
                if max_episode_steps is not None else toy.ContinuousNavEnv())
+    elif env_id.startswith("ApexTokens"):
+        # token contexts as byte frames (toy.TokensEnv): 1-D uint8, so no
+        # FrameStack and frame stack 1; sized by the config (the CLI sets
+        # both numbers from the --torso preset that reads such frames)
+        env = toy.TokensEnv(cfg.token_context, cfg.token_vocab)
+        if max_episode_steps is not None:
+            env = wrappers.TimeLimit(env, max_episode_steps)
     elif env_id.startswith(("ApexCatch", "ApexRally")):
         # Pixel toy envs.  Catch — Small: 7x7 grid rendered to 42x42
         # (smallest input the Nature conv geometry accepts), 3 balls (a
@@ -132,10 +139,10 @@ def jittable_env(env_id: str) -> bool:
     """Capability flag: True when :func:`make_jax_env` can build a pure-JAX
     port of ``env_id`` for on-device Anakin rollouts
     (:mod:`apex_tpu.training.anakin`).  Catch/Rally are integer/float32
-    grid worlds that run inside the accelerator; everything else (ALE,
-    CartPole-family float dynamics, continuous nav) stays on the host
-    pipeline."""
-    return env_id.startswith(("ApexCatch", "ApexRally"))
+    grid worlds and Tokens an integer context that run inside the
+    accelerator; everything else (ALE, CartPole-family float dynamics,
+    continuous nav) stays on the host pipeline."""
+    return env_id.startswith(("ApexCatch", "ApexRally", "ApexTokens"))
 
 
 def make_jax_env(env_id: str | None = None, cfg: EnvConfig | None = None):
@@ -153,8 +160,11 @@ def make_jax_env(env_id: str | None = None, cfg: EnvConfig | None = None):
         raise ValueError(
             f"env {env_id!r} has no jittable port — on-device rollouts "
             f"(--rollout ondevice / --role loadgen) serve the "
-            f"ApexCatch*/ApexRally* families only; use the host actor "
-            f"pipeline for this env")
+            f"ApexCatch*/ApexRally*/ApexTokens* families only; use the "
+            f"host actor pipeline for this env")
+    if env_id.startswith("ApexTokens"):
+        return jax_envs.make_tokens(cfg.token_context, cfg.token_vocab,
+                                    env_id=env_id)
     if env_id.startswith("ApexCatch"):
         if "Small" in env_id:
             return jax_envs.make_catch(grid=7, pixels=42, balls=3,

@@ -322,3 +322,54 @@ class CatchEnv(gym.Env):
         p1 = (min(self._paddle + 1, self.grid - 1) + 1) * s
         img[py:py + s, p0:p1] = 128
         return img
+
+
+class TokensEnv(gym.Env):
+    """``ApexTokens-v0``: next-token choice over a context of token ids,
+    the stand-in for a text environment a language-model torso acts in.
+
+    Integers only, so the jittable port (``envs/jax_envs.py``) is bitwise.
+    Reset draws a prompt of ``context`` ids below ``vocab``; the action is
+    the next id; the reward is 1.0 when it equals ``(31 c[T-1] + c[T-2] +
+    7) mod vocab`` of the context it was chosen in, else 0; the context
+    shifts by one and takes the action; an episode is ``EPISODE_STEPS``
+    steps.  The frame is the context as ``u8[2 * context]``, two bytes an
+    id, low byte first (frame stack 1)."""
+
+    metadata: dict = {}
+
+    EPISODE_STEPS = 64
+
+    def __init__(self, context: int = 16, vocab: int = 64):
+        if not 2 <= vocab <= 65536 or context < 2:
+            raise ValueError(f"ApexTokens needs 2 <= vocab <= 65536 and "
+                             f"context >= 2, got {vocab}, {context}")
+        self.context, self.vocab = context, vocab
+        self.observation_space = gym.spaces.Box(0, 255, (2 * context,),
+                                                np.uint8)
+        self.action_space = gym.spaces.Discrete(vocab)
+        self._ids = np.zeros(context, np.int32)
+        self._steps = 0
+
+    def _frame(self) -> np.ndarray:
+        ids = self._ids.astype(np.int64)
+        return np.stack([ids % 256, ids // 256], 1).reshape(-1).astype(
+            np.uint8)
+
+    def reset(self, *, seed=None, options=None):
+        super().reset(seed=seed)
+        self._ids = np.asarray(
+            self.np_random.integers(0, self.vocab, size=self.context),
+            np.int32)
+        self._steps = 0
+        return self._frame(), {}
+
+    def step(self, action):
+        c = self._ids.astype(np.int64)
+        want = (31 * c[-1] + c[-2] + 7) % self.vocab
+        reward = 1.0 if int(action) == int(want) else 0.0
+        self._ids = np.concatenate([self._ids[1:],
+                                    np.asarray([action], np.int32)])
+        self._steps += 1
+        return (self._frame(), reward, False,
+                self._steps >= self.EPISODE_STEPS, {})

@@ -559,9 +559,10 @@ def dqn_policy_fn(cfg: ApexConfig):
     """The policy program the server serves — the SAME builder the actor
     families jit locally (one function, two call sites: that identity is
     the whole bit-parity argument)."""
-    from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+    from apex_tpu.models import make_q_network
+    from apex_tpu.models.dueling import make_policy_fn
     from apex_tpu.training.apex import dqn_model_spec
-    return make_policy_fn(DuelingDQN(**dqn_model_spec(cfg)))
+    return make_policy_fn(make_q_network(dqn_model_spec(cfg)))
 
 
 def run_infer_server(cfg: ApexConfig, family: str = "dqn",

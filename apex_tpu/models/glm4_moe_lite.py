@@ -1,0 +1,426 @@
+"""GLM-4.7-Flash (``glm4_moe_lite``) as a Q-network over token contexts.
+
+The published block (https://huggingface.co/zai-org/GLM-4.7-Flash, config
+``model_type: glm4_moe_lite``): pre-norm residual layers of multi-head
+latent attention (MLA: low-rank query and key/value projections, a rope
+part shared by all heads) followed by a SwiGLU feed-forward in the leading
+dense layer and, in every later layer, one shared expert plus 4 of 64
+routed experts picked by a sigmoid router with a selection-only bias
+(``noaux_tc``).  Here it is the torso of Ape-X DQN's Q-network: a frame is
+a whole context of ``T`` token ids, two bytes each, and ``Q(s, .)`` is the
+output head at the last position, one value per id of the vocabulary held.
+
+**One chip's share of an expert-parallel deployment.**  The layer is told
+which routed experts it holds (``held = [rank * n_held, (rank + 1) *
+n_held)``), routes over all ``n_routed_experts`` and adds nothing for the
+absent ones: no stand-in for other chips, no exchange, no dropped pair and
+no capacity factor.  The (token, expert) pairs that land on held experts are
+sorted by expert and go through a grouped matrix product
+(:func:`jax.lax.ragged_dot`) in rounds of ``expert_rows`` rows; a round
+past the first runs only when routing filled the rounds before it
+(``lax.cond``), so uneven routing costs time, never a pair.  The vocabulary
+is a slice too: ids are taken ``mod vocab_held``.
+
+The repo's idiom: float32 parameters, ``compute_dtype`` operands on the
+MXU with float32 accumulation; norms, the router, softmax and the Q output
+in float32.  Each layer is rematerialised (``nn.remat``): an update keeps
+the residual stream between layers and one layer's internals.
+
+Not held: the multi-token-prediction module (it serves an auxiliary loss
+this learner does not have) and a key/value cache (training and acting
+both run whole contexts).
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+#: ``--torso`` presets: the published widths at one of 8 chips' share of
+#: each layer, and the toy the CPU tests run.  ``context`` is the number of
+#: ids a frame carries (``ApexTokens-v0`` emits ``u8[2 * context]``);
+#: ``vocab_held`` is the vocabulary the CLI sizes the env to unless
+#: ``--token-vocab`` says otherwise (the model holds ``num_actions`` ids).
+PRESETS: dict[str, dict[str, Any]] = {
+    "glm47_flash_ep8": dict(
+        hidden_size=2048, num_heads=20, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        intermediate_size=10240, moe_intermediate_size=1536,
+        n_routed_experts=64, n_held_experts=8, num_experts_per_tok=4,
+        routed_scaling_factor=1.8, n_dense_layers=1, n_expert_layers=4,
+        vocab_held=19360, rope_theta=1e6, rms_norm_eps=1e-5, context=1024,
+        attn_block=2, ffn_block=4096),
+    "glm47_flash_tiny": dict(
+        hidden_size=64, num_heads=2, q_lora_rank=32, kv_lora_rank=16,
+        qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+        intermediate_size=128, moe_intermediate_size=32,
+        n_routed_experts=8, n_held_experts=2, num_experts_per_tok=2,
+        routed_scaling_factor=1.8, n_dense_layers=1, n_expert_layers=2,
+        vocab_held=64, rope_theta=1e6, rms_norm_eps=1e-5, context=16,
+        attn_block=0),
+}
+
+
+def token_ids(obs_u8: jax.Array, vocab: int) -> jax.Array:
+    """``u8[B, 2T]`` -> ``i32[B, T]``: two bytes an id, low byte first,
+    ``mod vocab`` (the env emits ids below it; any other bytes fold in)."""
+    b = obs_u8.reshape(obs_u8.shape[0], -1, 2).astype(jnp.int32)
+    return (b[..., 0] + 256 * b[..., 1]) % vocab
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+def rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over the last axis of ``[B, T, ..., d]``, positions
+    ``0..T-1``, pairing dimension ``i`` with ``i + d/2`` (rotate-half)."""
+    d, t = x.shape[-1], x.shape[1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    shape = (1, t) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    x = x.astype(jnp.float32)
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def _normal(std: float = 0.02):
+    return nn.initializers.normal(std)
+
+
+class _Base(nn.Module):
+    """Shared helpers: a float32 kernel multiplied in the compute dtype."""
+
+    compute_dtype: Any = jnp.bfloat16
+
+    def kernel(self, name: str, shape: tuple[int, ...]) -> jax.Array:
+        return self.param(name, _normal(), shape)
+
+    def dot(self, x: jax.Array, w: jax.Array, out=None) -> jax.Array:
+        dt = self.compute_dtype
+        y = jnp.dot(x.astype(dt), w.astype(dt),
+                    preferred_element_type=jnp.float32)
+        return y.astype(out or dt)
+
+
+class Linear(_Base):
+    features: int = 0
+
+    @nn.compact
+    def __call__(self, x, out=None):
+        return self.dot(x, self.kernel("kernel", (x.shape[-1],
+                                                  self.features)), out)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return rms_norm(x, scale, self.eps)
+
+
+class SwiGLU(_Base):
+    """``(silu(h W_g) * (h W_u)) W_d`` -> float32, ``token_block`` tokens
+    at a time where that is set and the input is larger: the two wide
+    products of a block are made again in the backward pass, so a layer of
+    width 10,240 keeps 4 k tokens' worth of them, not 16 k (2 GB less at
+    the peak of the update, where every gradient is already live)."""
+
+    width: int = 0
+    token_block: int = 0
+
+    @nn.compact
+    def __call__(self, h):
+        d, f = h.shape[-1], self.width
+        w_gate, w_up = self.kernel("gate", (d, f)), self.kernel("up", (d, f))
+        w_down = self.kernel("down", (f, d))
+
+        def ffn(x):
+            g = self.dot(x, w_gate, jnp.float32)
+            u = self.dot(x, w_up, jnp.float32)
+            return self.dot(jax.nn.silu(g) * u, w_down, jnp.float32)
+
+        x = h.reshape(-1, d)
+        blk = self.token_block
+        if blk and x.shape[0] > blk and x.shape[0] % blk == 0:
+            y = jax.lax.map(jax.checkpoint(ffn), x.reshape(-1, blk, d))
+        else:
+            y = ffn(x)
+        return y.reshape(h.shape)
+
+
+class MLA(_Base):
+    """Multi-head latent attention over whole contexts, causal, no cache."""
+
+    num_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    rope_theta: float = 1e6
+    eps: float = 1e-5
+    attn_block: int = 0
+
+    def attend(self, q, k, v):
+        """``softmax_causal(q k^T / sqrt(d)) v`` for ``[b, T, H, d]``
+        operands: scores and softmax in float32."""
+        dt, t = self.compute_dtype, q.shape[1]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(dt), k.astype(dt),
+                       preferred_element_type=jnp.float32)
+        s = s * (q.shape[-1] ** -0.5)
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), v.astype(dt),
+                          preferred_element_type=jnp.float32).astype(dt)
+
+    @nn.compact
+    def __call__(self, h):
+        dt = self.compute_dtype
+        b, t, d = h.shape
+        nh, nope, rp, vd = (self.num_heads, self.qk_nope_head_dim,
+                            self.qk_rope_head_dim, self.v_head_dim)
+        c_q = RMSNorm(self.eps, name="q_a_norm")(
+            Linear(dt, self.q_lora_rank, name="q_a")(h, jnp.float32))
+        q = Linear(dt, nh * (nope + rp), name="q_b")(c_q).reshape(
+            b, t, nh, nope + rp)
+        kv = Linear(dt, self.kv_lora_rank + rp, name="kv_a")(h, jnp.float32)
+        c_kv, k_r = kv[..., :self.kv_lora_rank], kv[..., self.kv_lora_rank:]
+        kv = Linear(dt, nh * (nope + vd), name="kv_b")(
+            RMSNorm(self.eps, name="kv_a_norm")(c_kv)).reshape(
+                b, t, nh, nope + vd)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], self.rope_theta).astype(dt)],
+            -1)
+        k_r = rope(k_r, self.rope_theta).astype(dt)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (b, t, nh, rp))], -1)
+        blk = self.attn_block
+        if blk and b > blk and b % blk == 0:
+            # a block of contexts at a time, its scores made again in the
+            # backward pass: [blk, H, T, T] float32 live, not [B, H, T, T]
+            split = lambda x: x.reshape(b // blk, blk, *x.shape[1:])
+            o = jax.lax.map(
+                lambda qkv: jax.checkpoint(self.attend)(*qkv),
+                (split(q), split(k), split(v)))
+            o = o.reshape(b, t, nh, vd)
+        else:
+            o = self.attend(q, k, v)
+        return Linear(dt, d, name="o")(o.reshape(b, t, nh * vd), jnp.float32)
+
+
+class MoE(_Base):
+    """One shared expert and this chip's share of the routed experts."""
+
+    width: int = 1536
+    n_routed_experts: int = 64
+    n_held_experts: int = 8
+    expert_rank: int = 0
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.8
+    expert_rows: int = 0        # rows a round; 0 = a quarter of the pairs
+
+    def grouped(self, xs, w, group_sizes, live):
+        """``xs[i] @ w[g(i)]`` -> float32 for the rows the groups cover.
+        ``ragged_dot`` leaves the rows past the last group unwritten, in
+        its output and in the cotangent it hands back: both are masked
+        here, so no stray bits reach a live row."""
+        dt = self.compute_dtype
+        xs = jnp.where(live, xs, 0).astype(dt)
+        y = jax.lax.ragged_dot(xs, w.astype(dt), group_sizes,
+                               preferred_element_type=jnp.float32)
+        return jnp.where(live, y, 0.0)
+
+    def route(self, h32):
+        """``(picks i32[N, k], weights f32[N, k])`` over ALL routed
+        experts: sigmoid scores, the ``k`` largest of score + bias, weights
+        ``scaling * s_i / sum_picked s_j``.  The bias only selects."""
+        e = self.n_routed_experts
+        w_r = self.param("router_kernel", _normal(), (h32.shape[-1], e))
+        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        s = jax.nn.sigmoid(jnp.dot(h32, w_r,
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, picks = jax.lax.top_k(s + jax.lax.stop_gradient(bias),
+                                 self.num_experts_per_tok)
+        picked = jnp.take_along_axis(s, picks, axis=-1)
+        weights = (self.routed_scaling_factor * picked
+                   / picked.sum(-1, keepdims=True))
+        return picks.astype(jnp.int32), weights
+
+    def routed(self, h, picks, weights, w_gate, w_up, w_down):
+        """The held experts' part of the layer output, ``f32[N, D]``, and
+        the pairs that landed on each of them, ``i32[n_held]``."""
+        n, k = picks.shape
+        held, lo = self.n_held_experts, self.expert_rank * self.n_held_experts
+        local = (picks >= lo) & (picks < lo + held)
+        key = jnp.where(local, picks - lo, held).reshape(-1)
+        order = jnp.argsort(key, stable=True)       # held pairs first, by expert
+        counts = (key[:, None] == jnp.arange(held)[None, :]).sum(
+            0, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        starts, n_local = ends - counts, ends[-1]
+        flat_w = weights.reshape(-1)
+        rows = self.expert_rows or max(n * k // 4, 1)
+
+        def one_round(r):
+            @jax.checkpoint
+            def run(out, h, w_gate, w_up, w_down):
+                a = r * rows
+                idx = jax.lax.dynamic_slice_in_dim(order, a, rows)
+                tok = idx // k
+                sizes = jnp.clip(jnp.minimum(ends, a + rows)
+                                 - jnp.maximum(starts, a), 0, rows)
+                live = ((a + jnp.arange(rows)) < n_local)[:, None]
+                xs = h[tok]
+                with jax.named_scope("experts"):
+                    g = self.grouped(xs, w_gate, sizes, live)
+                    u = self.grouped(xs, w_up, sizes, live)
+                    y = self.grouped(jax.nn.silu(g) * u, w_down, sizes, live)
+                y = y * flat_w[idx][:, None]
+                return out.at[tok].add(y)
+            return run
+
+        out = jnp.zeros((n, h.shape[-1]), jnp.float32)
+        for r in range(-(-n * k // rows)):
+            args = (out, h, w_gate, w_up, w_down)
+            out = one_round(r)(*args) if r == 0 else jax.lax.cond(
+                n_local > r * rows, one_round(r), lambda out, *_: out, *args)
+        return out, counts
+
+    @nn.compact
+    def __call__(self, h32):
+        b, t, d = h32.shape
+        dt = self.compute_dtype
+        with jax.named_scope("shared_expert"):
+            y = SwiGLU(dt, self.width, name="shared")(h32)
+        # router: scores, picks, and the sort / gather / scatter that take
+        # pairs to their experts and back (``experts``: the products alone)
+        with jax.named_scope("router"):
+            picks, weights = self.route(h32.reshape(b * t, d))
+        e, f = self.n_held_experts, self.width
+        w_gate = self.param("experts_gate", _normal(), (e, d, f))
+        w_up = self.param("experts_up", _normal(), (e, d, f))
+        w_down = self.param("experts_down", _normal(), (e, f, d))
+        with jax.named_scope("router"):
+            part, counts = self.routed(
+                h32.reshape(b * t, d).astype(dt), picks, weights,
+                w_gate, w_up, w_down)
+        return y + part.reshape(b, t, d), counts
+
+
+class Block(_Base):
+    """``x += MLA(norm(x))``; ``x += FFN(norm(x))``, dense or experts."""
+
+    cfg: Any = None             # the preset's dict, frozen
+    dense: bool = True
+    expert_rank: int = 0
+
+    @nn.compact
+    def __call__(self, x):
+        c, dt = dict(self.cfg), self.compute_dtype
+        eps = c["rms_norm_eps"]
+        with jax.named_scope("mla"):
+            x = x + MLA(dt, c["num_heads"], c["q_lora_rank"],
+                        c["kv_lora_rank"], c["qk_nope_head_dim"],
+                        c["qk_rope_head_dim"], c["v_head_dim"],
+                        c["rope_theta"], eps, c.get("attn_block", 0),
+                        name="mla")(RMSNorm(eps, name="attn_norm")(x))
+        h = RMSNorm(eps, name="ffn_norm")(x)
+        if self.dense:
+            with jax.named_scope("dense_ffn"):
+                y = SwiGLU(dt, c["intermediate_size"],
+                           c.get("ffn_block", 0), name="mlp")(h)
+            counts = jnp.zeros(c["n_held_experts"], jnp.int32)
+        else:
+            y, counts = MoE(dt, c["moe_intermediate_size"],
+                            c["n_routed_experts"], c["n_held_experts"],
+                            self.expert_rank, c["num_experts_per_tok"],
+                            c["routed_scaling_factor"],
+                            c.get("expert_rows", 0), name="moe")(h)
+        return x + y, counts
+
+
+class Glm4MoeLiteQ(nn.Module):
+    """``Q(s, .) = RMSNorm(x_T) W_head`` over the ids held, float32."""
+
+    num_actions: int
+    preset: str = "glm47_flash_tiny"
+    compute_dtype: Any = jnp.bfloat16
+    expert_rank: int = 0
+    n_held_experts: int | None = None   # None = the preset's share
+    remat: bool = True
+
+    #: ``__call__(obs, with_stats=True)`` also returns routing scalars
+    #: (``models.learner_apply_fn``)
+    COUNTS_STATS = True
+    #: leaves the forward pass reads in float32 (``models.acting_params``)
+    ACTING_KEEPS_FLOAT32 = ("scale", "router_kernel", "router_bias",
+                            "embedding")
+
+    @property
+    def cfg(self) -> dict[str, Any]:
+        c = dict(PRESETS[self.preset], vocab_held=self.num_actions)
+        if self.n_held_experts is not None:
+            c["n_held_experts"] = self.n_held_experts
+        return c
+
+    @nn.compact
+    def __call__(self, obs, with_stats: bool = False):
+        c, dt = self.cfg, self.compute_dtype
+        with jax.named_scope("embed"):
+            emb = self.param("embedding", _normal(),
+                             (c["vocab_held"], c["hidden_size"]))
+            x = emb[token_ids(obs, c["vocab_held"])]
+        block = nn.remat(Block) if self.remat else Block
+        frozen = tuple(sorted(c.items()))
+        loads = []
+        for i in range(c["n_dense_layers"] + c["n_expert_layers"]):
+            x, counts = block(dt, frozen, i < c["n_dense_layers"],
+                              self.expert_rank, name=f"layers_{i}")(x)
+            loads.append(counts)
+        with jax.named_scope("q_head"):
+            last = RMSNorm(c["rms_norm_eps"], name="final_norm")(x[:, -1])
+            q = Linear(dt, c["vocab_held"], name="head")(last, jnp.float32)
+        if not with_stats:
+            return q
+        # routing of this pass over its expert layers: the (token, expert)
+        # pairs that landed on held experts, their share of all pairs (an
+        # even router gives held / routed), the busiest held expert's load
+        # over the mean
+        load = jnp.sum(jnp.stack(loads), 0).astype(jnp.float32)
+        pairs = (obs.shape[0] * (obs.shape[1] // 2) * c["n_expert_layers"]
+                 * c["num_experts_per_tok"])
+        return q, {"moe_local_pairs": load.sum(),
+                   "moe_local_share": load.sum() / pairs,
+                   "moe_load_max_over_mean":
+                       load.max() / jnp.maximum(load.mean(), 1.0)}
+
+
+def param_count(preset: str) -> int:
+    """Parameters of a preset, from its widths (norm gains and the
+    router's bias counted)."""
+    c = PRESETS[preset]
+    d, nh = c["hidden_size"], c["num_heads"]
+    mla = (d * c["q_lora_rank"] + c["q_lora_rank"]
+           + c["q_lora_rank"] * nh * (c["qk_nope_head_dim"]
+                                      + c["qk_rope_head_dim"])
+           + d * (c["kv_lora_rank"] + c["qk_rope_head_dim"])
+           + c["kv_lora_rank"]
+           + c["kv_lora_rank"] * nh * (c["qk_nope_head_dim"]
+                                       + c["v_head_dim"])
+           + nh * c["v_head_dim"] * d + 2 * d)
+    dense = mla + 3 * d * c["intermediate_size"]
+    f = c["moe_intermediate_size"]
+    moe = (mla + d * c["n_routed_experts"] + c["n_routed_experts"]
+           + 3 * d * f * (1 + c["n_held_experts"]))
+    return (c["n_dense_layers"] * dense + c["n_expert_layers"] * moe
+            + 2 * c["vocab_held"] * d + d)
+

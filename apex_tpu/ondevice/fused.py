@@ -87,10 +87,8 @@ def acting_priorities(out):
     host-computed priorities for the same transition)."""
     import jax.numpy as jnp
 
-    q_taken = jnp.take_along_axis(
-        out["q0"], out["action"][..., None], -1)[..., 0]
-    target = out["reward"] + out["discount"] * out["qn"].max(-1)
-    return jnp.abs(target - q_taken) + jnp.float32(1e-6)
+    target = out["reward"] + out["discount"] * out["qn_max"]
+    return jnp.abs(target - out["q_taken"]) + jnp.float32(1e-6)
 
 
 class FusedStep:

@@ -13,9 +13,12 @@ Parity with the reference's ``utils.compute_loss`` (``utils.py:64-81``) and
   centered RMSprop (``ApeX.py:37``) — composed as one optax chain so the whole
   update fuses into the learner's XLA step.
 
-Unlike the reference, which runs THREE forward passes (online(s), online(s'),
-target(s') — ``utils.py:67-69``), we fold online(s) and online(s') into one
-batched pass over concatenated states: fewer, larger MXU matmuls.
+Like the reference this runs THREE forward passes (online(s), online(s'),
+target(s') — ``utils.py:67-69``).  online(s') only picks the bootstrap
+action, so it runs on parameters cut out of the differentiated graph: it
+saves no residuals and is never back-propagated (folded into one batched
+pass over concatenated states, as it was before PR 29, its half of the
+batch kept its activations and took a backward pass of zeros).
 """
 
 from __future__ import annotations
@@ -32,6 +35,8 @@ class TDOutput(NamedTuple):
     td_abs: jax.Array        # (B,) |TD error|
     priorities: jax.Array    # (B,) mixed-max heuristic priorities
     q_taken: jax.Array       # (B,) Q(s0, a0) — mean logged as learner/q
+    stats: dict | None = None  # scalars a model counted inside its forward
+                               # passes (expert routing), by pass
 
 
 class AQLOutput(NamedTuple):
@@ -63,6 +68,14 @@ def mixed_max_priorities(td_abs: jax.Array, eps: float = 1e-6) -> jax.Array:
             + (1.0 - PRIORITY_ETA) * td_abs + eps)
 
 
+def _q_and_stats(out, suffix: str) -> tuple[jax.Array, dict]:
+    """``apply_fn`` gives ``q`` or ``(q, stats)``: the stats of one pass
+    under that pass's suffix."""
+    if isinstance(out, tuple):
+        return out[0], {k + suffix: v for k, v in out[1].items()}
+    return out, {}
+
+
 def double_dqn_loss(
     apply_fn: Callable[..., jax.Array],
     params: Any,
@@ -81,10 +94,13 @@ def double_dqn_loss(
     with truncation-correct bootstrapping.
     """
     obs, next_obs = batch["obs"], batch["next_obs"]
-    both = jnp.concatenate([obs, next_obs], axis=0)
-    q_both = apply_fn(params, both)
-    q_values, next_q_values = jnp.split(q_both, 2, axis=0)
-    tgt_next_q_values = apply_fn(target_params, next_obs)
+    q_values, stats = _q_and_stats(apply_fn(params, obs), "")
+    next_q_values, s = _q_and_stats(
+        apply_fn(jax.lax.stop_gradient(params), next_obs), "_next")
+    stats |= s
+    tgt_next_q_values, s = _q_and_stats(apply_fn(target_params, next_obs),
+                                        "_target")
+    stats |= s
 
     actions = batch["action"].astype(jnp.int32)
     q_taken = jnp.take_along_axis(q_values, actions[:, None], axis=1)[:, 0]
@@ -99,7 +115,7 @@ def double_dqn_loss(
     loss = (huber(td) * weights).mean()
     return loss, TDOutput(loss=loss, td_abs=td_abs,
                           priorities=mixed_max_priorities(td_abs),
-                          q_taken=q_taken)
+                          q_taken=q_taken, stats=stats or None)
 
 
 def r2d2_loss(

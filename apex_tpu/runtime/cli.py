@@ -71,6 +71,20 @@ def build_parser() -> argparse.ArgumentParser:
                         "enjoy: eval a checkpoint")
     p.add_argument("--family", default=e.get("APEX_FAMILY", "dqn"),
                    choices=["dqn", "aql", "r2d2"])
+    p.add_argument("--torso", default="dueling",
+                   help="dqn family: the Q-network's torso.  'dueling' "
+                        "(default) is the reference's Nature-CNN / MLP "
+                        "dueling net; 'glm47_flash_ep8' is GLM-4.7-Flash's "
+                        "block (latent attention, a shared and routed "
+                        "experts) at published widths, one of 8 chips' "
+                        "share of each layer, over token contexts "
+                        "(--env-id ApexTokens-v0, sized by the preset); "
+                        "'glm47_flash_tiny' its toy "
+                        "(apex_tpu/models/glm4_moe_lite.py)")
+    p.add_argument("--token-vocab", type=int, default=0,
+                   help="ApexTokens-v0 under a token torso: the ids the "
+                        "env draws from, which are the actions and the "
+                        "vocabulary the torso holds (0 = the preset's)")
     p.add_argument("--rollout", default=e.get("APEX_ROLLOUT", "host"),
                    choices=["host", "ondevice", "fused"],
                    help="learner/apex roles: 'ondevice' co-locates an "
@@ -131,6 +145,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "256-actor spectrum in 8 processes)")
     p.add_argument("--n-evaluators", type=int,
                    default=int(e.get("N_EVALUATORS", 1)))
+    p.add_argument("--send-interval", type=int,
+                   default=50,
+                   help="transitions per shipped chunk (the reference's "
+                        "send interval); also the on-device rollout's "
+                        "chunk size")
     p.add_argument("--learner-ip", default=ident.learner_ip)
     # comms ports (env twins let topology tests / multi-fleet hosts remap
     # the whole plane without code changes)
@@ -407,11 +426,23 @@ def _mesh_shape(args: argparse.Namespace) -> tuple[int, ...]:
 
 
 def config_from_args(args: argparse.Namespace) -> ApexConfig:
+    from apex_tpu.models import DEFAULT_TORSO, torso_names
+    if args.torso not in torso_names():
+        raise SystemExit(f"--torso {args.torso!r}: known are "
+                         f"{torso_names()}")
+    tokens = {}
+    if args.torso != DEFAULT_TORSO:
+        # the preset's model reads frames of `context` ids: ApexTokens-v0
+        # is sized to it, and the model holds the ids the env draws from
+        from apex_tpu.models.glm4_moe_lite import PRESETS
+        preset = PRESETS[args.torso]
+        tokens = dict(token_context=preset["context"],
+                      token_vocab=args.token_vocab or preset["vocab_held"])
     return ApexConfig(
         env=EnvConfig(env_id=args.env_id, seed=args.seed,
                       frame_stack=args.frame_stack,
                       clip_rewards=not args.no_clip_rewards,
-                      episodic_life=not args.no_episodic_life),
+                      episodic_life=not args.no_episodic_life, **tokens),
         replay=ReplayConfig(capacity=args.capacity, warmup=args.warmup,
                             alpha=args.alpha, beta=args.beta),
         learner=LearnerConfig(batch_size=args.batch_size, lr=args.lr,
@@ -421,9 +452,11 @@ def config_from_args(args: argparse.Namespace) -> ApexConfig:
                               target_update_interval=
                               args.target_update_interval,
                               save_interval=args.save_interval,
-                              mesh_shape=_mesh_shape(args)),
+                              mesh_shape=_mesh_shape(args),
+                              torso=args.torso),
         actor=ActorConfig(n_actors=args.n_actors,
                           n_envs_per_actor=args.n_envs_per_actor,
+                          send_interval=args.send_interval,
                           remote_policy=args.remote_policy),
         aql=AQLConfig(),
         comms=CommsConfig(batch_port=args.batch_port,

@@ -560,9 +560,10 @@ def _evaluator_body(cfg, identity, family, stop_event, episodes, max_steps,
 
     reset_act = None            # recurrent families override per episode
     if family == "dqn":
-        from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+        from apex_tpu.models import make_q_network
+        from apex_tpu.models.dueling import make_policy_fn
         from apex_tpu.training.apex import dqn_model_spec
-        model = DuelingDQN(**dqn_model_spec(cfg))
+        model = make_q_network(dqn_model_spec(cfg))
         policy = jax.jit(make_policy_fn(model))
 
         def act(params, obs, key):

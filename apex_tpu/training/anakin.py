@@ -42,6 +42,8 @@ Two consumers:
 
 from __future__ import annotations
 
+import functools
+import threading
 import time
 from collections import deque
 from typing import Any, NamedTuple
@@ -60,7 +62,10 @@ class RolloutCarry(NamedTuple):
     """Vectorized builder + env state between scan steps (leading axis B).
 
     Deliberately SMALL: the scan carry holds only bookkeeping (int32 row
-    maps, the n-step window, the S-frame acting stack) — frame BYTES leave
+    maps, the n-step window, the S-frame acting stack; of each Q row the
+    two numbers the acting-time priority reads, ``q[a]`` and ``max q``,
+    both picked without arithmetic and so the bits the host builder reads
+    off whole rows) — frame BYTES leave
     the scan as per-step outputs, land in an append-only per-dispatch ring,
     and materialize into chunk layout once per dispatch (``fmap`` maps each
     chunk row to its ring row).  A first cut kept ``[B, M, Kf, D]`` frame
@@ -78,7 +83,7 @@ class RolloutCarry(NamedTuple):
     action: Any             # i32[B, M, K]
     rd: Any                 # f32[B, M, K, 2] (reward, discount) pairs
     refs: Any               # i32[B, M, K, 2, S] (obs_ref, next_ref) pairs
-    q: Any                  # f32[B, M, K, 2, A] (q0, qn) pairs
+    q: Any                  # f32[B, M, K, 2] (q0[a], max qn) pairs
     counts: Any             # i32[B, M, 2] (n_frames, n_trans) at seal
     sealed: Any             # i32[B] chunks sealed this dispatch (= cur slot)
     cur_nf: Any             # i32[B] in-progress frame count
@@ -88,7 +93,7 @@ class RolloutCarry(NamedTuple):
     w_obs: Any              # i32[B, n+1]
     w_act: Any              # i32[B, n+1]
     w_rew: Any              # f32[B, n+1]
-    w_q: Any                # f32[B, n+1, A]
+    w_q: Any                # f32[B, n+1, 2] (q[a], max q) of each step
     w_len: Any              # i32[B]
     ep_ret: Any             # f32[B]
     ep_len: Any             # i32[B]
@@ -110,7 +115,8 @@ class AnakinRollout:
                  slot_ids=None, n_steps: int = 3, gamma: float = 0.99,
                  frame_stack: int = 4, chunk_transitions: int = 64,
                  rollout_len: int | None = None,
-                 frame_margin: int = FRAME_MARGIN, seed: int = 0):
+                 frame_margin: int = FRAME_MARGIN, seed: int = 0,
+                 acting_params=None):
         import jax
 
         self.env = env
@@ -127,7 +133,6 @@ class AnakinRollout:
         # for frame-overflow partial seals (overflow past M is detected
         # loudly in rollout(), never silent corruption)
         self.M = (self.T + self.n + self.K - 1) // self.K + 3
-        self.A = int(env.num_actions)
         self.D = int(np.prod(env.frame_shape))
         self.frame_shape = tuple(env.frame_shape)
         self.slot_ids = list(slot_ids if slot_ids is not None
@@ -143,7 +148,15 @@ class AnakinRollout:
         self.key = jax.random.key(seed)
         self.key, init_key = jax.random.split(self.key)
         self.carry, self.carry_frames = self._init_carry(init_key)
-        self._jit = jax.jit(self._dispatch)
+
+        def anakin_rollout(*args):      # the program's name in a trace
+            return self._dispatch(*args)
+
+        self._jit = jax.jit(anakin_rollout)
+        # how a publish snapshots the learner's parameters for this policy
+        # (AnakinPool): the model's cast to what it multiplies with; None
+        # copies
+        self.acting_params = acting_params
         # counters (host-side observability)
         self.dispatches = 0
         self.chunks = 0
@@ -164,8 +177,7 @@ class AnakinRollout:
         import jax.numpy as jnp
 
         states, obs = jax.vmap(self.env.reset)(self.reset_keys(key))
-        B, M, K, Kf, S, A = (self.B, self.M, self.K, self.Kf, self.S,
-                             self.A)
+        B, M, K, Kf, S = self.B, self.M, self.K, self.Kf, self.S
         flat = obs.reshape(B, self.D)
         # begin_episode: reset frame is episode frame 0 = chunk row 0;
         # the acting stack starts as S copies of it (host FrameStack.reset)
@@ -178,7 +190,7 @@ class AnakinRollout:
             action=jnp.zeros((B, M, K), jnp.int32),
             rd=jnp.zeros((B, M, K, 2), jnp.float32),
             refs=jnp.zeros((B, M, K, 2, S), jnp.int32),
-            q=jnp.zeros((B, M, K, 2, A), jnp.float32),
+            q=jnp.zeros((B, M, K, 2), jnp.float32),
             counts=jnp.zeros((B, M, 2), jnp.int32),
             sealed=jnp.zeros(B, jnp.int32),
             cur_nf=jnp.ones(B, jnp.int32),
@@ -188,7 +200,7 @@ class AnakinRollout:
             w_obs=jnp.zeros((B, self.n + 1), jnp.int32),
             w_act=jnp.zeros((B, self.n + 1), jnp.int32),
             w_rew=jnp.zeros((B, self.n + 1), jnp.float32),
-            w_q=jnp.zeros((B, self.n + 1, A), jnp.float32),
+            w_q=jnp.zeros((B, self.n + 1, 2), jnp.float32),
             w_len=jnp.zeros(B, jnp.int32),
             ep_ret=jnp.zeros(B, jnp.float32),
             ep_len=jnp.zeros(B, jnp.int32))
@@ -276,7 +288,7 @@ class AnakinRollout:
         offs = jnp.arange(self.S - 1, -1, -1)[None, :]
         return self._rows_of(c, end[:, None] - offs)
 
-    def _push(self, c: RolloutCarry, ret, next_end, disc, qn_row, do):
+    def _push(self, c: RolloutCarry, ret, next_end, disc, qn_max, do):
         """Emit one transition from the window head, then flush at K."""
         import jax.numpy as jnp
         ar = jnp.arange(self.B)
@@ -293,7 +305,7 @@ class AnakinRollout:
             refs=c.refs.at[ar, sl, pos].set(
                 jnp.stack([obs_ref, next_ref], 1), mode="drop"),
             q=c.q.at[ar, sl, pos].set(
-                jnp.stack([c.w_q[:, 0], qn_row], 1), mode="drop"),
+                jnp.stack([c.w_q[:, 0, 0], qn_max], 1), mode="drop"),
             cur_nt=c.cur_nt + do.astype(jnp.int32))
         return self._flush(c, do & (c.cur_nt == self.K))
 
@@ -338,6 +350,14 @@ class AnakinRollout:
         step_key, t = xs
         actions, q = self.policy_fn(params, self._policy_obs(c), eps,
                                     jax.random.fold_in(step_key, T_POLICY))
+        # both numbers as max-reductions of the row, the taken one over a
+        # one-hot mask: a one-element gather fused into the arithmetic
+        # that made the row rounded otherwise than the row the host
+        # builder reads (XLA:CPU summed the dueling mean in another order)
+        q = q.astype(jnp.float32)
+        taken = jnp.arange(q.shape[1])[None, :] == actions[:, None]
+        q_pair = jnp.stack([jnp.max(jnp.where(taken, q, -jnp.inf), axis=1),
+                            q.max(axis=1)], 1)
         env_key = jax.random.fold_in(step_key, T_ENV)
         env_state, obs, reward, done, final_frame = jax.vmap(
             lambda s, a, i: self.env.step(s, a,
@@ -363,22 +383,22 @@ class AnakinRollout:
             w_obs=c.w_obs.at[ar, pos].set(obs_idx),
             w_act=c.w_act.at[ar, pos].set(actions.astype(jnp.int32)),
             w_rew=c.w_rew.at[ar, pos].set(reward),
-            w_q=c.w_q.at[ar, pos].set(q.astype(jnp.float32)),
+            w_q=c.w_q.at[ar, pos].set(q_pair),
             w_len=c.w_len + 1)
         # full-window emission (gamma**n bootstrap)
         full = c.w_len == self.n + 1
         c = self._push(c, self._nstep_return(c, jnp.int32(self.n)),
                        c.w_obs[:, 0] + self.n,
                        jnp.full(self.B, self.gpow[self.n]),
-                       c.w_q[:, self.n], full)
+                       c.w_q[:, self.n, 1], full)
         c = self._popleft(c, full)
         # terminal tails (discount 0, next stack = masked obs stack)
         for _ in range(self.n):
             m = done & (c.w_len > 0)
             k = c.w_len
-            qn_row = c.w_q[ar, jnp.clip(k - 1, 0, self.n)]
+            qn_max = c.w_q[ar, jnp.clip(k - 1, 0, self.n), 1]
             c = self._push(c, self._nstep_return(c, k), c.w_obs[:, 0],
-                           jnp.zeros(self.B, jnp.float32), qn_row, m)
+                           jnp.zeros(self.B, jnp.float32), qn_max, m)
             c = self._popleft(c, m)
         c = c._replace(ep_step=jnp.where(done, -1, c.ep_step))
         # auto-reset: begin_episode(obs) for done slots
@@ -426,9 +446,10 @@ class AnakinRollout:
 
         c = self._rebase(c)
         keys = jax.random.split(key, self.T)
-        c, ys = jax.lax.scan(
-            lambda cc, xs: self._step(params, eps, cc, xs), c,
-            (keys, jnp.arange(self.T)))
+        with jax.named_scope("rollout"):
+            c, ys = jax.lax.scan(
+                lambda cc, xs: self._step(params, eps, cc, xs), c,
+                (keys, jnp.arange(self.T)))
         final_flat, obs_flat, done, ep_ret, ep_len = ys
         # the dispatch ring: carry region + this dispatch's frame pairs
         pairs = jnp.stack([jnp.moveaxis(final_flat, 0, 1),
@@ -459,7 +480,7 @@ class AnakinRollout:
         out = dict(frames=frames, action=pad(c.action, nt, self.K),
                    reward=rd[..., 0], discount=rd[..., 1],
                    obs_ref=refs[..., 0, :], next_ref=refs[..., 1, :],
-                   q0=q[..., 0, :], qn=q[..., 1, :],
+                   q_taken=q[..., 0], qn_max=q[..., 1],
                    nf=nf, nt=nt, sealed=c.sealed,
                    stepped=(done, ep_ret, ep_len))
         return c, carry_next, out
@@ -468,14 +489,31 @@ class AnakinRollout:
 
     def rollout(self, params):
         """One dispatch; returns ``(messages, stats)``."""
+        return self.collect(self.launch(params))
+
+    def launch(self, params):
+        """Enqueue one dispatch (span ``rollout_dispatch`` on the calling
+        thread); :meth:`collect` waits for what it returns.  ``params`` is
+        read by the program enqueued here and may be donated the moment
+        this returns."""
+        import jax
+
+        from apex_tpu.obs.trace import get_ring
+
+        self.key, k = jax.random.split(self.key)
+        with get_ring().span("rollout_dispatch", "rollout",
+                             {"lanes": self.B, "steps": self.T}):
+            self.carry, self.carry_frames, out = self._jit(
+                params, self.epsilons, self.carry, self.carry_frames, k)
+        return out
+
+    def collect(self, out):
+        """The launched dispatch's ``(messages, stats)``."""
         import jax
 
         from apex_tpu.actors.pool import EpisodeStat
         from apex_tpu.obs import spans as obs_spans
 
-        self.key, k = jax.random.split(self.key)
-        self.carry, self.carry_frames, out = self._jit(
-            params, self.epsilons, self.carry, self.carry_frames, k)
         got = jax.device_get(out)
         sealed = got["sealed"]
         if int(sealed.max(initial=0)) > self.M - 1:
@@ -487,10 +525,8 @@ class AnakinRollout:
         # fuses reward + discount*max into an FMA, which rounds once
         # where numpy rounds twice — a 1-ulp drift the bit-compat
         # contract forbids.  Vectorized host epilogue, not per-step work.
-        q_taken = np.take_along_axis(
-            got["q0"], got["action"][..., None], -1)[..., 0]
-        target = got["reward"] + got["discount"] * got["qn"].max(-1)
-        priorities = (np.abs(target - q_taken).astype(np.float32)
+        target = got["reward"] + got["discount"] * got["qn_max"]
+        priorities = (np.abs(target - got["q_taken"]).astype(np.float32)
                       + np.float32(1e-6))
         stamped = obs_spans.enabled()
         msgs = []
@@ -539,18 +575,20 @@ def make_anakin_engine(cfg: ApexConfig, rollout_len: int | None = None,
     (:func:`apex_tpu.actors.vector.worker_slots`)."""
     from apex_tpu.actors.pool import actor_epsilons
     from apex_tpu.envs.registry import make_jax_env
-    from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+    from apex_tpu.models import acting_params, make_q_network
+    from apex_tpu.models.dueling import make_policy_fn
     from apex_tpu.training.apex import dqn_env_specs
 
     env = make_jax_env(cfg.env.env_id, cfg.env)
     model_spec, _shape, _dtype, frame_stack = dqn_env_specs(cfg)
+    model = make_q_network(model_spec)
     b = n_envs or max(cfg.actor.n_actors, 1) * max(
         1, cfg.actor.n_envs_per_actor)
     total = max(total_slots or 0, (slot_band + 1) * b)
     ladder = actor_epsilons(total, cfg.actor.eps_base, cfg.actor.eps_alpha)
     slot_ids = list(range(slot_band * b, (slot_band + 1) * b))
     return AnakinRollout(
-        env, make_policy_fn(DuelingDQN(**model_spec)),
+        env, make_policy_fn(model),
         n_envs=b, epsilons=ladder[slot_ids], slot_ids=slot_ids,
         n_steps=cfg.learner.n_steps, gamma=cfg.learner.gamma,
         frame_stack=frame_stack,
@@ -559,7 +597,8 @@ def make_anakin_engine(cfg: ApexConfig, rollout_len: int | None = None,
         # distinct key chains per ladder band so N loadgen processes
         # explore different trajectories (the host fleet's per-slot seed
         # discipline, lifted to the band level)
-        seed=cfg.env.seed + 1000 * (slot_band + 1))
+        seed=cfg.env.seed + 1000 * (slot_band + 1),
+        acting_params=functools.partial(acting_params, model))
 
 
 class AnakinPool:
@@ -567,13 +606,20 @@ class AnakinPool:
     co-located training mode (``--rollout ondevice``).
 
     Params hand over as ON-DEVICE arrays (``accepts_device_params`` — the
-    trainer and ingest pipeline skip their ``device_get``), rollout
-    dispatches run lazily inside ``poll_chunks`` (so the trainer's
+    trainer passes its live tree on the loop thread, no ``device_get``):
+    a publish takes a SNAPSHOT of them as the policy multiplies them (the
+    engine's ``acting_params``: the compute dtype, so half the bytes and
+    the same Q bits), written into the buffers of the snapshot it replaces
+    (donated), so exactly one snapshot lives however large the network.
+    The lock orders a publish against the launch of a rollout that reads
+    the snapshot: device programs run in the order they were enqueued, so
+    a donated snapshot is never read after its buffers changed hands.
+    Rollout dispatches run lazily inside ``poll_chunks`` (so the trainer's
     replay-ratio backpressure gates collection for free), and heartbeats +
     episode stats surface through ``poll_stats`` like any worker fleet.
     ``inner`` (a socket RemotePool) keeps host actors/evaluators riding
     alongside: their chunks/stats merge in, and publishes fan out to them
-    as host params."""
+    as host params, from the thread that polls (the inner pool's owner)."""
 
     accepts_device_params = True
 
@@ -586,6 +632,9 @@ class AnakinPool:
         self.inner = inner
         self._params = None
         self._version = 0
+        self._lock = threading.Lock()
+        self._snap = self._snap_into = None
+        self._wire_due = False      # inner fleet owes a host publish
         self._pending: deque = deque()
         self._stats: deque = deque()
         self._beat = HeartbeatEmitter(
@@ -617,12 +666,38 @@ class AnakinPool:
     # -- param plane -------------------------------------------------------
 
     def publish_params(self, version: int, params) -> None:
-        """Keep the device reference for the engine; the host copy is made
-        only when an inner fleet needs wire params."""
-        self._version, self._params = version, params
-        if self.inner is not None:
-            import jax
-            self.inner.publish_params(version, jax.device_get(params))
+        """Snapshot ``params`` (the learner's live tree, or any tree) for
+        the engine; an inner fleet gets its host copy at the next poll."""
+        import jax
+
+        if self._snap is None:
+            cast = self.engine.acting_params or (
+                lambda p: jax.tree.map(lambda x: x, p))
+            # jit outputs never alias an input that is not donated: the
+            # snapshot owns its buffers, whatever the caller donates next
+            self._snap = jax.jit(cast)
+            self._snap_into = jax.jit(lambda p, old: cast(p),
+                                      donate_argnums=(1,), keep_unused=True)
+        with self._lock:
+            # the first publish runs both programs, so neither compiles
+            # later (a benchmark's window allows no compile)
+            old = self._params if self._params is not None \
+                else self._snap(params)
+            self._params = self._snap_into(params, old)
+            self._version = version
+            self._wire_due = self.inner is not None
+
+    def _publish_wire(self) -> None:
+        """The inner fleet's host copy of the snapshot, float32 on the
+        wire as before (the values the policy multiplies with)."""
+        import jax
+        with self._lock:
+            if not self._wire_due:
+                return
+            self._wire_due = False
+            version, host = self._version, jax.device_get(self._params)
+        self.inner.publish_params(version, jax.tree.map(
+            lambda x: np.asarray(x).astype(np.float32), host))
 
     @property
     def needs_warmup_republish(self) -> bool:
@@ -642,6 +717,7 @@ class AnakinPool:
     def poll_chunks(self, max_chunks: int, timeout: float = 0.0) -> list:
         out = []
         if self.inner is not None:
+            self._publish_wire()
             out = self.inner.poll_chunks(max_chunks, timeout=0)
         dry = 0
         while len(out) < max_chunks:
@@ -652,7 +728,9 @@ class AnakinPool:
                 # produce — the cap only guards a pathological config
                 if self._params is None or dry >= 4:
                     break
-                msgs, stats = self.engine.rollout(self._params)
+                with self._lock:
+                    launched = self.engine.launch(self._params)
+                msgs, stats = self.engine.collect(launched)
                 self._pending.extend(msgs)
                 self._stats.extend(stats)
                 dry = 0 if msgs else dry + 1

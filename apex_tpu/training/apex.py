@@ -25,6 +25,7 @@ independent processes, and the learner thread blocks only on device results.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 
@@ -40,7 +41,8 @@ from apex_tpu.obs import spans as obs_spans
 from apex_tpu.parallel.aggregate import stack_chunk_messages
 from apex_tpu.envs.registry import (make_env, make_eval_env, num_actions,
                                     unstacked_env_spec)
-from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+from apex_tpu.models import learner_apply_fn, make_q_network, q_model_spec
+from apex_tpu.models.dueling import make_policy_fn
 from apex_tpu.ops.losses import make_optimizer
 from apex_tpu.replay.base import check_hbm_budget
 from apex_tpu.replay.frame_pool import FramePoolReplay
@@ -63,7 +65,8 @@ def dqn_env_specs(cfg: ApexConfig):
     probe = make_env(cfg.env.env_id, cfg.env, seed=cfg.env.seed,
                      stack_frames=False)
     frame_shape, frame_dtype, frame_stack = unstacked_env_spec(probe, cfg.env)
-    model_spec = dict(
+    model_spec = q_model_spec(
+        cfg.learner.torso,
         num_actions=num_actions(probe),
         obs_is_image=len(frame_shape) == 3,
         compute_dtype=jnp.dtype(cfg.learner.compute_dtype),
@@ -110,6 +113,14 @@ class ConcurrentTrainer(CheckpointableTrainer):
     # ring, the track the loop's events go to, and the pass under way
     _ring = None
     _loop_track = "learner-hot-loop"
+    # the newest update's metrics the loop has looked at (None before the
+    # first), the losses of the updates still enqueued (_bound_in_flight)
+    # and the model counters on their way to the ring (_note_model_stats:
+    # (step, scalars) whose values the device has not produced yet)
+    _stats_last = None
+    _stats_pending = None
+    _in_flight = None
+    max_steps_in_flight = 2
     _pass = 0
     _pass_kind = "idle"
     # the per-pass operands no pass launches a program for: the key
@@ -219,6 +230,16 @@ class ConcurrentTrainer(CheckpointableTrainer):
             # learner step, THIS version left
             self._obs.note_publish(self.param_version,
                                    self.steps_rate.total)
+        if getattr(self.pool, "accepts_device_params", False):
+            # co-located on-device rollouts (training/anakin.py): the pool
+            # takes its own snapshot of the live parameters, here on the
+            # loop thread before the next fused step donates them, in the
+            # compute dtype and into the buffers of the snapshot it
+            # replaces — one snapshot lives, and params never leave the
+            # device on this path
+            self.pool.publish_params(self.param_version,
+                                     self.train_state.params)
+            return
         if self._pipeline is not None:
             # hand the staging thread an on-device COPY: the hot loop's
             # next fused step donates train_state, which would invalidate
@@ -227,13 +248,6 @@ class ConcurrentTrainer(CheckpointableTrainer):
             # path below drains the whole device pipeline per publish).
             params = jax.tree.map(jnp.copy, self.train_state.params)
             self._pipeline.publish(self.param_version, params)
-            return
-        if getattr(self.pool, "accepts_device_params", False):
-            # co-located on-device rollouts (training/anakin.py): hand the
-            # engine an on-device COPY (the next fused step donates
-            # train_state) — params never leave the device on this path
-            params = jax.tree.map(jnp.copy, self.train_state.params)
-            self.pool.publish_params(self.param_version, params)
             return
         host_params = jax.device_get(self.train_state.params)
         self.pool.publish_params(self.param_version, host_params)
@@ -332,6 +346,8 @@ class ConcurrentTrainer(CheckpointableTrainer):
             self._obs = obs_spans.LearnerObs(ring=ring)
         gap = self._dispatch_gap = DispatchGapTimer(ring=ring,
                                                     track=self._loop_track)
+        self._stats_pending = collections.deque(maxlen=64)
+        self._in_flight = collections.deque()
         client = self.replay_client
         if client is not None and self._train_batch is None:
             # dp>1 included: _make_batch_train shards the service batch
@@ -494,6 +510,12 @@ class ConcurrentTrainer(CheckpointableTrainer):
                             time.sleep(0.002)   # replay-ratio cap reached
 
                     steps = self.steps_rate.total
+                    if metrics is not None \
+                            and metrics is not self._stats_last:
+                        self._bound_in_flight(metrics)
+                    if ring.enabled:
+                        self._note_model_stats(metrics, steps)
+                    self._stats_last = metrics
                     if (self.checkpointer is not None
                             and steps - self._last_save
                             >= cfg.learner.save_interval):
@@ -1275,6 +1297,42 @@ class ConcurrentTrainer(CheckpointableTrainer):
             return ring.span(name)
         return ring.span(name, self._loop_track, {"it": self._pass, **args})
 
+    def _bound_in_flight(self, metrics) -> None:
+        """Keep at most ``max_steps_in_flight`` updates enqueued on the
+        device: wait for the oldest one's loss before going on.  A loop
+        the host paces never waits here (the step it asks about finished
+        passes ago).  A loop the DEVICE paces would otherwise enqueue
+        every update the replay-ratio cap allows at once: its counters
+        (and the ratio control that reads them) would run tens of seconds
+        ahead of the work done, and a rollout dispatched by the staging
+        thread would queue behind all of them."""
+        self._in_flight.append(metrics["loss"])
+        if len(self._in_flight) > self.max_steps_in_flight:
+            oldest = self._in_flight.popleft()
+            if not oldest.is_ready():
+                # the loop waits for the device, not the device for the
+                # loop: a span of its own, so the ring tells this wait
+                # from host work inside ``host_gap``
+                with self._span("in_flight_wait"):
+                    oldest.block_until_ready()
+
+    def _note_model_stats(self, metrics, steps: int) -> None:
+        """What the model counted inside the step (the expert layers'
+        ``moe_*`` scalars among the step's metrics) as one ring instant
+        ``moe_stats`` an update, written once the device has the values:
+        the loop never waits for them, and no program is launched.
+        ``log_scalars`` carries the same numbers at its own cadence."""
+        pending = self._stats_pending
+        if metrics is not None and metrics is not self._stats_last:
+            stats = {k: v for k, v in metrics.items()
+                     if k.startswith("moe_") and getattr(v, "ndim", 1) == 0}
+            if stats:
+                pending.append((steps, stats))
+        while pending and all(v.is_ready() for v in pending[0][1].values()):
+            at, stats = pending.popleft()
+            self._ring.instant("moe_stats", self._loop_track, {
+                "step": at, **{k: float(v) for k, v in stats.items()}})
+
     def _pre_consume(self, spans) -> None:
         """Chunk-lineage join, first half (stamps ``consume``)."""
         if spans:
@@ -1673,7 +1731,7 @@ class ApexTrainer(ConcurrentTrainer):
         self.model_spec, frame_shape, frame_dtype, frame_stack = \
             dqn_env_specs(cfg)
 
-        self.model = DuelingDQN(**self.model_spec)
+        self.model = make_q_network(self.model_spec)
         self.replay = FramePoolReplay(
             capacity=cfg.replay.capacity, frame_shape=frame_shape,
             frame_stack=frame_stack, frame_dtype=np.dtype(frame_dtype).name,
@@ -1691,7 +1749,8 @@ class ApexTrainer(ConcurrentTrainer):
             self.model, optimizer, init_key,
             jnp.zeros((1,) + stacked, frame_dtype))
         self.core = LearnerCore(
-            apply_fn=self.model.apply, replay=self.replay, optimizer=optimizer,
+            apply_fn=learner_apply_fn(self.model), replay=self.replay,
+            optimizer=optimizer,
             batch_size=lc.batch_size,
             target_update_interval=lc.target_update_interval)
         self._policy = jax.jit(make_policy_fn(self.model))

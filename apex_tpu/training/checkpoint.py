@@ -280,8 +280,9 @@ def evaluate_checkpoint(path: str, episodes: int = 10, epsilon: float = 0.0,
         def reset_policy():       # fresh carry each episode
             carry_box[0] = model.initial_state(1)
     else:
-        from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
-        model = DuelingDQN(**spec)
+        from apex_tpu.models import make_q_network
+        from apex_tpu.models.dueling import make_policy_fn
+        model = make_q_network(spec)
         dqn_policy = jax.jit(make_policy_fn(model))
 
         def policy(params, obs, eps, key):

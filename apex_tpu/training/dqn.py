@@ -24,7 +24,8 @@ import numpy as np
 
 from apex_tpu.config import ApexConfig
 from apex_tpu.envs.registry import make_env, make_eval_env, num_actions
-from apex_tpu.models.dueling import DuelingDQN, make_policy_fn
+from apex_tpu.models import make_q_network, q_model_spec
+from apex_tpu.models.dueling import make_policy_fn
 from apex_tpu.replay.nstep import NStepAccumulator
 from apex_tpu.training import learner as learner_lib
 from apex_tpu.training.checkpoint import (CheckpointableTrainer,
@@ -69,12 +70,13 @@ class DQNTrainer(CheckpointableTrainer):
                             seed=self.cfg.env.seed,
                             max_episode_steps=self.cfg.actor.max_episode_length)
         obs_shape = self.env.observation_space.shape
-        self.model_spec = dict(
+        self.model_spec = q_model_spec(
+            self.cfg.learner.torso,
             num_actions=num_actions(self.env),
             obs_is_image=len(obs_shape) == 3,
             compute_dtype=jnp.dtype(self.cfg.learner.compute_dtype),
             scale_uint8=self.env.observation_space.dtype == np.uint8)
-        self.model = DuelingDQN(**self.model_spec)
+        self.model = make_q_network(self.model_spec)
 
         lc = self.cfg.learner
         example_obs = jnp.zeros((1,) + obs_shape,

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from apex_tpu.models import learner_apply_fn
 from apex_tpu.ops.losses import double_dqn_loss, make_optimizer
 from apex_tpu.replay.device import DeviceReplay, ReplayState
 from apex_tpu.training.state import TrainState, create_train_state
@@ -47,7 +48,10 @@ class ReplayLike(Protocol):
 class LearnerCore:
     """Static wiring of model/replay/optimizer into jitted step functions.
 
-    ``apply_fn`` must be a plain callable ``(params, obs) -> q_values``.
+    ``apply_fn`` must be a plain callable ``(params, obs) -> q_values``, or
+    ``-> (q_values, stats)`` with a dict of scalars the forward pass
+    counted (:func:`apex_tpu.models.learner_apply_fn`); they leave the step
+    among its metrics.
     """
 
     apply_fn: Callable[..., jax.Array]
@@ -166,6 +170,12 @@ def td_update(optimizer, target_update_interval: int,
         "q_mean": q_mean,
         "td_mean": td_mean,
     }
+    if aux.stats:
+        # what the model counted in its passes, out with the step's other
+        # scalars: no second program reads them
+        stats = jax.lax.stop_gradient(aux.stats)
+        metrics |= (stats if axis_name is None
+                    else jax.lax.pmean(stats, axis_name))
     train_state = TrainState(params=params, target_params=target_params,
                              opt_state=opt_state, step=step)
     return train_state, aux.priorities, metrics
@@ -264,7 +274,7 @@ def build_learner(model, replay_capacity: int, example_obs, key: jax.Array,
         check_hbm_budget(replay.hbm_bytes(example_item), hbm_budget_gb,
                          "replay (stacked obs storage)", replay_capacity)
     replay_state = replay.init(example_item)
-    core = LearnerCore(apply_fn=model.apply, replay=replay,
+    core = LearnerCore(apply_fn=learner_apply_fn(model), replay=replay,
                        optimizer=optimizer, batch_size=batch_size,
                        target_update_interval=target_update_interval)
     return core, train_state, replay_state

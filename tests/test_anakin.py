@@ -24,8 +24,8 @@ from apex_tpu.actors.pool import (EpisodeStat,  # noqa: E402
 from apex_tpu.config import (ActorConfig, ApexConfig,  # noqa: E402
                              EnvConfig, LearnerConfig, ReplayConfig)
 from apex_tpu.envs.registry import make_jax_env  # noqa: E402
-from apex_tpu.models.dueling import (DuelingDQN,  # noqa: E402
-                                     make_policy_fn)
+from apex_tpu.models import make_q_network  # noqa: E402
+from apex_tpu.models.dueling import make_policy_fn  # noqa: E402
 from apex_tpu.ops.losses import make_optimizer  # noqa: E402
 from apex_tpu.replay.frame_chunks import FrameChunkBuilder  # noqa: E402
 from apex_tpu.training import anakin  # noqa: E402
@@ -38,21 +38,22 @@ CHUNK_KEYS = ("frames", "n_frames", "n_trans", "action", "reward",
               "discount", "obs_ref", "next_ref")
 
 
-def _cfg(env_id="ApexCatchSmall-v0", stack=2, n_envs=3, send=16):
+def _cfg(env_id="ApexCatchSmall-v0", stack=2, n_envs=3, send=16,
+         torso="dueling"):
     return ApexConfig(
         env=EnvConfig(env_id=env_id, frame_stack=stack,
                       clip_rewards=False, episodic_life=False),
         replay=ReplayConfig(capacity=1024, warmup=128),
         learner=LearnerConfig(batch_size=32, ingest_chunk=32,
                               compute_dtype="float32",
-                              target_update_interval=100),
+                              target_update_interval=100, torso=torso),
         actor=ActorConfig(n_actors=1, n_envs_per_actor=n_envs,
                           send_interval=send))
 
 
 def _params(cfg):
     model_spec, frame_shape, frame_dtype, frame_stack = dqn_env_specs(cfg)
-    model = DuelingDQN(**model_spec)
+    model = make_q_network(model_spec)
     stacked = frame_shape[:-1] + (frame_stack * frame_shape[-1],)
     ts = create_train_state(model, make_optimizer(), jax.random.key(0),
                             np.zeros((1,) + stacked, frame_dtype))
@@ -131,6 +132,84 @@ def test_chunk_bit_compat_with_host_builder():
                     np.asarray(e["payload"][k]), err_msg=k)
             compared += 1
     assert compared >= 8       # several chunks incl. cross-dispatch carry
+
+
+def test_chunk_bit_compat_on_token_contexts():
+    """The same pin on ``ApexTokens-v0`` under the toy GLM torso: 1-D byte
+    frames, frame stack 1, a 64-wide Q row of which the carry keeps two
+    numbers, episodes that end by truncation."""
+    cfg = _cfg(env_id="ApexTokens-v0", stack=1, n_envs=2, send=16,
+               torso="glm47_flash_tiny")
+    model, spec, frame_shape, _dtype, params = _params(cfg)
+    assert spec["torso"] == "glm47_flash_tiny" and frame_shape == (32,)
+    engine = make_anakin_engine(cfg, rollout_len=40)
+    assert engine.carry.q.shape == (2, engine.M, 16, 2)
+    assert engine.carry.w_q.shape == (2, engine.n + 1, 2)
+    host_stream, stats = _host_replay(cfg, engine, params, model,
+                                      dispatches=2)
+    compared = 0
+    for host in host_stream:
+        msgs, _stats = engine.rollout(params)
+        assert len(host) == len(msgs)
+        for h, e in zip(host, msgs):
+            np.testing.assert_array_equal(h["priorities"],
+                                          e["priorities"])
+            for k in CHUNK_KEYS:
+                np.testing.assert_array_equal(
+                    np.asarray(h["payload"][k]),
+                    np.asarray(e["payload"][k]), err_msg=k)
+            compared += 1
+    assert compared >= 6 and stats          # an episode ended (64 steps)
+
+
+def _live_bytes() -> int:
+    """Live device bytes, each buffer once (benchmark/tests/
+    test_harness.py counts them the same way)."""
+    return sum({x.unsafe_buffer_pointer(): x.nbytes
+                for x in jax.live_arrays()}.values())
+
+
+def test_a_publish_holds_one_snapshot():
+    """``AnakinPool.publish_params`` snapshots the learner's tree as the
+    policy multiplies it, into the buffers of the snapshot it replaces:
+    however many publishes, one snapshot's bytes are live, the learner's
+    own tree is untouched, and the engine acts on the newest."""
+    import gc
+
+    from apex_tpu.models import acting_params
+
+    import dataclasses
+
+    cfg = _cfg(env_id="ApexTokens-v0", stack=1, n_envs=2, send=16,
+               torso="glm47_flash_tiny")
+    cfg = dataclasses.replace(cfg, learner=dataclasses.replace(
+        cfg.learner, compute_dtype="bfloat16"))
+    model, _spec, _shape, _dtype, params = _params(cfg)
+    pool = AnakinPool(cfg, make_anakin_engine(cfg, rollout_len=8))
+    want = acting_params(model, params)
+    snap_bytes = sum(x.nbytes for x in jax.tree.leaves(want))
+    assert snap_bytes < 0.6 * sum(x.nbytes for x in jax.tree.leaves(params))
+    del want
+    gc.collect()
+    before = _live_bytes()
+    pool.publish_params(1, params)
+    jax.block_until_ready(pool._params)
+    assert _live_bytes() - before == snap_bytes
+    first = jax.tree.leaves(pool._params)
+    bumped = jax.tree.map(lambda x: x + 1.0, params)
+    held = _live_bytes()
+    for version in (2, 3, 4):
+        pool.publish_params(version, bumped)
+    jax.block_until_ready(pool._params)
+    assert _live_bytes() == held                # still one snapshot
+    assert all(x.is_deleted() for x in first)   # its buffers moved on
+    for got, live in zip(jax.tree.leaves(pool._params),
+                         jax.tree.leaves(bumped)):
+        assert not live.is_deleted()
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(live.astype(got.dtype), np.float32))
+    assert len(pool.poll_chunks(1)) == 1
 
 
 def test_chunk_ingest_parity_into_frame_pool():

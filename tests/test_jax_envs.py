@@ -44,11 +44,13 @@ class KeyedNpRandom:
     def _tag(self, step_tag: int, reset_tag: int) -> int:
         return reset_tag if self.mode == "reset" else step_tag
 
-    def integers(self, low, high=None):
+    def integers(self, low, high=None, size=None):
         lo, hi = (0, low) if high is None else (low, high)
         t = self._tag(jax_envs._T_INT, jax_envs._T_RESET_INT)
-        return int(jax.random.randint(jax.random.fold_in(self.key, t),
-                                      (), lo, hi))
+        k = jax.random.fold_in(self.key, t)
+        if size is not None:            # one keyed draw of a whole vector
+            return np.asarray(jax.random.randint(k, (size,), lo, hi))
+        return int(jax.random.randint(k, (), lo, hi))
 
     def random(self):
         t = self._tag(jax_envs._T_COIN, jax_envs._T_RESET_COIN)
@@ -61,7 +63,8 @@ class KeyedNpRandom:
         return arr[i]
 
 
-def assert_trajectory_parity(np_env, jenv, steps: int, seed: int) -> int:
+def assert_trajectory_parity(np_env, jenv, steps: int, seed: int,
+                             n_actions: int = 3) -> int:
     """Drive both envs ``steps`` steps under one key chain + action stream;
     assert renders/rewards/dones equal bitwise at every step.  Returns the
     number of episode terminations seen (callers assert coverage)."""
@@ -78,7 +81,7 @@ def assert_trajectory_parity(np_env, jenv, steps: int, seed: int) -> int:
     rng = np.random.default_rng(seed)
     dones = 0
     for t in range(steps):
-        a = int(rng.integers(0, 3))
+        a = int(rng.integers(0, n_actions))
         key, kt = jax.random.split(key)
         fake.key, fake.mode = kt, "step"
         obs_np, r_np, term, trunc, _ = np_env.step(a)
@@ -194,3 +197,33 @@ def test_scanned_batch_rollout_smoke():
     assert rewards.shape == (T, B) and dones.shape == (T, B)
     assert int(dones.sum()) >= B          # 18-step episodes: all lanes reset
     assert obs.shape == (B, 42, 42, 1) and obs.dtype == jnp.uint8
+
+
+def test_tokens_trajectory_parity_bitwise():
+    """``ApexTokens-v0``: integers only, so numpy env and port agree bit
+    for bit over three whole episodes on keyed draws (prompt, reward of the
+    arithmetic next id, context shift, truncation at 64 steps)."""
+    cfg = EnvConfig(env_id="ApexTokens-v0", token_context=12,
+                    token_vocab=300)
+    np_env = make_env("ApexTokens-v0", cfg, stack_frames=False)
+    jenv = make_jax_env("ApexTokens-v0", cfg)
+    assert jittable_env("ApexTokens-v0")
+    assert jenv.frame_shape == (24,) and jenv.num_actions == 300
+    assert np_env.observation_space.shape == (24,)
+    dones = assert_trajectory_parity(np_env, jenv, steps=3 * 64 + 5, seed=9,
+                                     n_actions=300)
+    assert dones == 3
+
+
+def test_tokens_reward_is_the_arithmetic_next_id():
+    env = toy.TokensEnv(context=4, vocab=50)
+    obs, _ = env.reset(seed=3)
+    ids = obs.reshape(-1, 2).astype(int) @ np.array([1, 256])
+    want = (31 * ids[-1] + ids[-2] + 7) % 50
+    obs, r, term, trunc, _ = env.step(int(want))
+    assert (r, term, trunc) == (1.0, False, False)
+    after = obs.reshape(-1, 2).astype(int) @ np.array([1, 256])
+    np.testing.assert_array_equal(after, list(ids[1:]) + [want])
+    _, r, _, _, _ = env.step(int((want + 1) % 50))
+    wrong_want = (31 * after[-1] + after[-2] + 7) % 50
+    assert r == (1.0 if (want + 1) % 50 == wrong_want else 0.0)

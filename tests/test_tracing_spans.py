@@ -107,6 +107,44 @@ def test_real_annotation_is_importable_and_nests():
 
 # -- the learner loop's phases -------------------------------------------------------
 
+def test_in_flight_bound_waits_under_a_span_of_its_own():
+    """At most two updates enqueued: the third pass asks for the oldest
+    loss.  Ready already (a loop the host paces): no wait and no span.
+    Not ready (a loop the device paces): one ``in_flight_wait`` span
+    around the wait, so the ring tells it from host work."""
+    import collections
+    import contextlib
+    import types
+
+    from apex_tpu.training.apex import ConcurrentTrainer
+
+    seen = []
+
+    class Loss:
+        def __init__(self, ready):
+            self.ready, self.waited = ready, False
+
+        def is_ready(self):
+            return self.ready
+
+        def block_until_ready(self):
+            self.waited = True
+
+    @contextlib.contextmanager
+    def span(name):
+        seen.append(name)
+        yield
+
+    loop = types.SimpleNamespace(_in_flight=collections.deque(),
+                                 max_steps_in_flight=2, _span=span)
+    losses = [Loss(True), Loss(False), Loss(True), Loss(True)]
+    for loss in losses:
+        ConcurrentTrainer._bound_in_flight(loop, {"loss": loss})
+    assert [x.waited for x in losses] == [False, True, False, False]
+    assert seen == ["in_flight_wait"]
+    assert len(loop._in_flight) == 2
+
+
 def _scripted_messages(n: int = 24) -> list[dict]:
     from apex_tpu.actors.pool import drain_builder_chunks
     from apex_tpu.obs import spans as obs_spans
@@ -440,12 +478,16 @@ def test_reader_returns_none_without_ring_or_trace(name, monkeypatch):
 
 
 def test_benchmark_json_lists_the_new_readers_last():
+    """PR 26's readers follow everything that was there before them, in
+    order, as one block (later PRs append after it)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    tail = bench["per_layer"][-len(NEW_METRICS):]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    tail = bench["per_layer"][at:at + len(NEW_METRICS)]
     assert [m["name"] for m in tail] == list(NEW_METRICS)
     assert all(m["workloads"] == ["dqn_hostfed"] for m in tail)
-    layers = {m["layer"] for m in bench["per_layer"][:-len(NEW_METRICS)]}
+    layers = {m["layer"] for m in bench["per_layer"][:at]}
     assert {m["layer"] for m in tail} <= layers
 
 
@@ -692,6 +734,73 @@ def test_device_scopes_account_for_the_step_programs(synthetic_trace):
     assert spans.device_scopes(small, ("jit_fused_step",
                                        "jit_train_step")) is None
     assert spans.host_attribution(small) is None
+
+
+def test_torso_scopes_place_the_grouped_kernels_by_name(tmp_path):
+    """The device plane of one update of the torso as XLA:TPU names it (the
+    ``tf_op`` of PR 29's AOT-compiled step): the grouped products are
+    kernels named ``%ragged-dot-none.<n>`` with NO scope path, the masks
+    and converts around them carry ``.../router/.../experts/...``, in the
+    backward pass under ``transpose(jvp(...))`` and
+    ``rematted_computation``.  ``experts`` is the kernels' time plus the
+    in-scope operations', ``router`` loses nothing to it, and an operation
+    of another program counts nowhere."""
+    from benchmark import torso_scopes
+
+    step = "jit(fused_step)/update/loss_grad/"
+    fwd = step + "jvp(Glm4MoeLiteQ)/layers_1/moe/router/moe.routed/"
+    bwd = (step + "transpose(jvp(Glm4MoeLiteQ))/update/loss_grad/"
+           "jvp(Glm4MoeLiteQ)/checkpoint/layers_1/moe/router/moe.routed/"
+           "checkpoint/rematted_computation/")
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(123)", []),
+        2: ("jit__threefry_split(9)", []),
+        10: ("%ragged-dot-none.1 = f32[16384,1536]{1,0} custom-call(",
+             [_stat("tf_op", "ragged-dot-none")]),
+        11: ("%select_convert_fusion = bf16[16384,1536] fusion(", [_stat(
+            "tf_op", fwd + "checkpoint/experts/moe.grouped/"
+            "convert_element_type:")]),
+        12: ("%select_multiply_fusion = f32[16384,2048] fusion(",
+             [_stat("tf_op", fwd + "checkpoint/mul:")]),
+        13: ("%ragged-dot-none.7 = f32[8,2048,1536]{2,1,0} custom-call(",
+             [_stat("tf_op", "ragged-dot-none")]),
+        14: ("%select_add_fusion = f32[16384,1536] fusion(", [_stat(
+            "tf_op", bwd + "experts/moe.grouped/add_any:")]),
+        15: ("%fusion.9 = f32[16384,2048] fusion(",
+             [_stat("tf_op", bwd + "scatter-add:")]),
+        16: ("%fusion.20 = f32[16,1024,5120] fusion(", [_stat(
+            "tf_op", step + "jvp(Glm4MoeLiteQ)/layers_1/mla/mla/q_b/"
+            "dot_general:")]),
+        17: ("%fusion.30 = f32[2048] fusion(",
+             [_stat("tf_op", "jit(fused_step)/update/optimizer/mul:")]),
+        18: ("%ragged-dot-none.2 = f32[8] custom-call(",
+             [_stat("tf_op", "ragged-dot-none")]),
+    }, {
+        "XLA Modules": (0, [(2, 0, 50, []), (1, 100, 900, [])]),
+        "XLA Ops": (0, [
+            (18, 10, 20, []),                  # in another program
+            (16, 100, 300, []), (10, 400, 70, []), (11, 470, 10, []),
+            (12, 480, 40, []), (13, 520, 60, []), (14, 580, 20, []),
+            (15, 600, 50, []), (17, 650, 100, [])]),
+    })
+    path = tmp_path / "torso.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, _plane("/host:metadata", {}, {}))))
+    red = torso_scopes.reduce_planes(spans.read_xspace(str(path)),
+                                     ("jit_fused_step",))
+    assert set(red) == {"jit_fused_step"}
+    got = red["jit_fused_step"]
+    us = 1e-6
+    assert got["calls"] == 1
+    assert {k: round(v / us, 2) for k, v in got["scopes"].items()
+            if v} == {"mla": 300.0, "router": 90.0, "experts": 160.0}
+    # the products alone: every ragged-dot operation of the program
+    assert got["kernels_s"] == pytest.approx(130 * us)
+    assert {k: round(v / us, 2) for k, v in got["rest"].items()} == {
+        "%fusion.30": 100.0}
+    # by path alone, as the reader went before, they fell under no scope
+    assert torso_scopes.scope_of("ragged-dot-none") is None
+    assert torso_scopes.scope_of(
+        step + "jvp(router)/transpose(jvp(experts))/mul:") == "experts"
 
 
 # -- the trace recorded on the chip ------------------------------------------------------
