@@ -9,6 +9,8 @@ network's keyword arguments, unchanged; with one it names a preset of
 
 from __future__ import annotations
 
+import sys
+
 import jax
 
 DEFAULT_TORSO = "dueling"
@@ -38,6 +40,26 @@ def make_q_network(model_spec: dict):
         return DuelingDQN(**spec)
     from apex_tpu.models.glm4_moe_lite import Glm4MoeLiteQ
     return Glm4MoeLiteQ(preset=torso, **spec)
+
+
+def note_attention_path(model, site: str) -> None:
+    """Which implementation a token torso's attention takes in this
+    process's programs (:func:`apex_tpu.ops.attention.attention_path`:
+    decided by platform and widths when a program is lowered, so once a
+    program's builder is enough): one ``attention_path`` instant in the
+    trace ring and one start-up line on stderr, from the ``site`` that
+    built the model (``trainer``, ``rollout``).  A model without attention
+    says nothing."""
+    path_of = getattr(model, "attention_path", None)
+    if path_of is None:
+        return
+    from apex_tpu.obs.trace import get_ring
+    args = {"site": site, "torso": model.preset,
+            **path_of(jax.default_backend())}
+    get_ring().instant("attention_path", None, args)
+    print("torso: attention_path "
+          + " ".join(f"{k}={v}" for k, v in args.items()),
+          file=sys.stderr, flush=True)
 
 
 def learner_apply_fn(model):

@@ -24,7 +24,13 @@ is a slice too: ids are taken ``mod vocab_held``.
 The repo's idiom: float32 parameters, ``compute_dtype`` operands on the
 MXU with float32 accumulation; norms, the router, softmax and the Q output
 in float32.  Each layer is rematerialised (``nn.remat``): an update keeps
-the residual stream between layers and one layer's internals.
+the residual stream between layers and one layer's internals, and that is
+the only rematerialisation attention sees.  Its softmax runs in
+:func:`apex_tpu.ops.attention.causal_attention`, once for the whole batch:
+at the published widths in a program compiled for a TPU that is a fused
+kernel whose scores never leave the chip's VMEM and whose backward pass
+makes them again from ``q``, ``k`` and the saved row statistics; at the
+toy's widths, and on a CPU, the plain path through float32 scores.
 
 Not held: the multi-token-prediction module (it serves an auxiliary loss
 this learner does not have) and a key/value cache (training and acting
@@ -33,11 +39,14 @@ both run whole contexts).
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+from apex_tpu.ops import attention
 
 #: ``--torso`` presets: the published widths at one of 8 chips' share of
 #: each layer, and the toy the CPU tests run.  ``context`` is the number of
@@ -52,15 +61,14 @@ PRESETS: dict[str, dict[str, Any]] = {
         n_routed_experts=64, n_held_experts=8, num_experts_per_tok=4,
         routed_scaling_factor=1.8, n_dense_layers=1, n_expert_layers=4,
         vocab_held=19360, rope_theta=1e6, rms_norm_eps=1e-5, context=1024,
-        attn_block=2, ffn_block=4096),
+        ffn_block=4096),
     "glm47_flash_tiny": dict(
         hidden_size=64, num_heads=2, q_lora_rank=32, kv_lora_rank=16,
         qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
         intermediate_size=128, moe_intermediate_size=32,
         n_routed_experts=8, n_held_experts=2, num_experts_per_tok=2,
         routed_scaling_factor=1.8, n_dense_layers=1, n_expert_layers=2,
-        vocab_held=64, rope_theta=1e6, rms_norm_eps=1e-5, context=16,
-        attn_block=0),
+        vocab_held=64, rope_theta=1e6, rms_norm_eps=1e-5, context=16),
 }
 
 
@@ -77,12 +85,12 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
 
 
 def rope(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over the last axis of ``[B, T, ..., d]``, positions
+    """Rotary embedding over the last axis of ``[..., T, d]``, positions
     ``0..T-1``, pairing dimension ``i`` with ``i + d/2`` (rotate-half)."""
-    d, t = x.shape[-1], x.shape[1]
+    d, t = x.shape[-1], x.shape[-2]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    shape = (1, t) + (1,) * (x.ndim - 3) + (d // 2,)
+    shape = (1,) * (x.ndim - 2) + (t, d // 2)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
     x = x.astype(jnp.float32)
     a, b = x[..., :d // 2], x[..., d // 2:]
@@ -115,6 +123,17 @@ class Linear(_Base):
     def __call__(self, x, out=None):
         return self.dot(x, self.kernel("kernel", (x.shape[-1],
                                                   self.features)), out)
+
+
+class Weight(_Base):
+    """A :class:`Linear`'s kernel alone, in the compute dtype, for a
+    caller that multiplies it head by head (same leaf, same path)."""
+
+    shape: tuple[int, ...] = ()
+
+    @nn.compact
+    def __call__(self):
+        return self.kernel("kernel", self.shape).astype(self.compute_dtype)
 
 
 class RMSNorm(nn.Module):
@@ -157,7 +176,15 @@ class SwiGLU(_Base):
 
 
 class MLA(_Base):
-    """Multi-head latent attention over whole contexts, causal, no cache."""
+    """Multi-head latent attention over whole contexts, causal, no cache.
+    The up-projections write ``q``, ``k`` and ``v`` as ``[b, H, T, d]`` in
+    the compute dtype (the rope part of ``k`` repeated for every head),
+    the layout attention works in, and ``o`` reads it: no copy of a 168 MB
+    activation stands between a product and the kernel (PERF.md, PR 30).
+    :func:`~apex_tpu.ops.attention.causal_attention` takes the whole batch
+    in one call: a fused kernel where platform and widths allow it
+    (``qk_nope + qk_rope == v_head_dim``, multiples of 128), else the plain
+    path.  No blocking over contexts and no checkpoint of its own."""
 
     num_heads: int = 20
     q_lora_rank: int = 768
@@ -167,19 +194,6 @@ class MLA(_Base):
     v_head_dim: int = 256
     rope_theta: float = 1e6
     eps: float = 1e-5
-    attn_block: int = 0
-
-    def attend(self, q, k, v):
-        """``softmax_causal(q k^T / sqrt(d)) v`` for ``[b, T, H, d]``
-        operands: scores and softmax in float32."""
-        dt, t = self.compute_dtype, q.shape[1]
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(dt), k.astype(dt),
-                       preferred_element_type=jnp.float32)
-        s = s * (q.shape[-1] ** -0.5)
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), v.astype(dt),
-                          preferred_element_type=jnp.float32).astype(dt)
 
     @nn.compact
     def __call__(self, h):
@@ -187,34 +201,34 @@ class MLA(_Base):
         b, t, d = h.shape
         nh, nope, rp, vd = (self.num_heads, self.qk_nope_head_dim,
                             self.qk_rope_head_dim, self.v_head_dim)
+        mm = functools.partial(jnp.einsum,
+                               preferred_element_type=jnp.float32)
+
+        def heads(x, w):
+            # an up-projection writes its heads apart, ``[b, H, t, d]``
+            return mm("btr,rhd->bhtd", x.astype(dt), w).astype(dt)
+
         c_q = RMSNorm(self.eps, name="q_a_norm")(
             Linear(dt, self.q_lora_rank, name="q_a")(h, jnp.float32))
-        q = Linear(dt, nh * (nope + rp), name="q_b")(c_q).reshape(
-            b, t, nh, nope + rp)
         kv = Linear(dt, self.kv_lora_rank + rp, name="kv_a")(h, jnp.float32)
         c_kv, k_r = kv[..., :self.kv_lora_rank], kv[..., self.kv_lora_rank:]
-        kv = Linear(dt, nh * (nope + vd), name="kv_b")(
-            RMSNorm(self.eps, name="kv_a_norm")(c_kv)).reshape(
-                b, t, nh, nope + vd)
-        k_nope, v = kv[..., :nope], kv[..., nope:]
+        c_kv = RMSNorm(self.eps, name="kv_a_norm")(c_kv)
+        w_q = Weight(dt, (self.q_lora_rank, nh * (nope + rp)), name="q_b")()
+        w_kv = Weight(dt, (self.kv_lora_rank, nh * (nope + vd)),
+                      name="kv_b")().reshape(-1, nh, nope + vd)
+        q = heads(c_q, w_q.reshape(-1, nh, nope + rp))
+        # ``k``'s content part and ``v`` are two products of one kernel's
+        # columns: a 448-wide head would be sliced, and copied, afterwards
+        k_nope, v = heads(c_kv, w_kv[..., :nope]), heads(c_kv, w_kv[..., nope:])
         q = jnp.concatenate(
             [q[..., :nope], rope(q[..., nope:], self.rope_theta).astype(dt)],
             -1)
         k_r = rope(k_r, self.rope_theta).astype(dt)
         k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (b, t, nh, rp))], -1)
-        blk = self.attn_block
-        if blk and b > blk and b % blk == 0:
-            # a block of contexts at a time, its scores made again in the
-            # backward pass: [blk, H, T, T] float32 live, not [B, H, T, T]
-            split = lambda x: x.reshape(b // blk, blk, *x.shape[1:])
-            o = jax.lax.map(
-                lambda qkv: jax.checkpoint(self.attend)(*qkv),
-                (split(q), split(k), split(v)))
-            o = o.reshape(b, t, nh, vd)
-        else:
-            o = self.attend(q, k, v)
-        return Linear(dt, d, name="o")(o.reshape(b, t, nh * vd), jnp.float32)
+            [k_nope, jnp.broadcast_to(k_r[:, None], (b, nh, t, rp))], -1)
+        o = attention.causal_attention(q, k, v, (nope + rp) ** -0.5)
+        w_o = Weight(dt, (nh * vd, d), name="o")()
+        return mm("bhtd,hdf->btf", o, w_o.reshape(nh, vd, d))
 
 
 class MoE(_Base):
@@ -331,7 +345,7 @@ class Block(_Base):
             x = x + MLA(dt, c["num_heads"], c["q_lora_rank"],
                         c["kv_lora_rank"], c["qk_nope_head_dim"],
                         c["qk_rope_head_dim"], c["v_head_dim"],
-                        c["rope_theta"], eps, c.get("attn_block", 0),
+                        c["rope_theta"], eps,
                         name="mla")(RMSNorm(eps, name="attn_norm")(x))
         h = RMSNorm(eps, name="ffn_norm")(x)
         if self.dense:
@@ -371,6 +385,15 @@ class Glm4MoeLiteQ(nn.Module):
         if self.n_held_experts is not None:
             c["n_held_experts"] = self.n_held_experts
         return c
+
+    def attention_path(self, platform: str) -> dict:
+        """Which implementation attention takes at this preset's widths in
+        a program compiled for ``platform``
+        (:func:`apex_tpu.ops.attention.attention_path`)."""
+        c = self.cfg
+        return attention.attention_path(
+            c["context"], c["qk_nope_head_dim"] + c["qk_rope_head_dim"],
+            c["v_head_dim"], platform)
 
     @nn.compact
     def __call__(self, obs, with_stats: bool = False):

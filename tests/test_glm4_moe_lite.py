@@ -42,9 +42,10 @@ HP = dict(lr=6.25e-5, lr_decay_steps=1000, lr_decay_rate=0.99,
           target_update_interval=2500)
 
 
-def model(dtype=jnp.float32, **kw):
-    return make_q_network(dict(torso=PRESET, num_actions=V,
-                               compute_dtype=dtype, **kw))
+def model(dtype=jnp.float32, preset: str = PRESET, **kw):
+    return make_q_network(dict(
+        torso=preset, num_actions=glm.PRESETS[preset]["vocab_held"],
+        compute_dtype=dtype, **kw))
 
 
 def seeded(m, seed: int):
@@ -53,7 +54,7 @@ def seeded(m, seed: int):
     return feed.make_weights(shapes, seed, ref.init_rule)
 
 
-def batch_of(seed: int):
+def batch_of(seed: int, B: int = B, T: int = T):
     rng = np.random.default_rng(seed)
     return dict(
         obs=jnp.asarray(rng.integers(0, 256, (B, 2 * T), dtype=np.uint8)),
@@ -329,9 +330,9 @@ def test_presets_hold_what_the_issue_counts():
 
 # -- what the compiled update asks of the grouped kernel ----------------------
 
-def _tpu_update_hlo() -> str:
-    """The toy's update compiled for a described v5e chip (libtpu compiles
-    without a chip; nothing runs)."""
+def _tpu_update_hlo(preset: str = PRESET, rows: int = B) -> str:
+    """A preset's update compiled for a described v5e chip (libtpu
+    compiles without a chip; nothing runs)."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     try:
@@ -340,7 +341,8 @@ def _tpu_update_hlo() -> str:
     except Exception as e:                       # no libtpu on this machine
         pytest.skip(f"no TPU compiler here: {e}")
     chip = SingleDeviceSharding(topo.devices[0])
-    m = model(jnp.bfloat16)
+    m = model(jnp.bfloat16, preset)
+    T = glm.PRESETS[preset]["context"]
 
     def described(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -348,8 +350,8 @@ def _tpu_update_hlo() -> str:
 
     p = described(jax.eval_shape(m.init, jax.random.key(0),
                                  jnp.zeros((1, 2 * T), jnp.uint8)))
-    batch = described(jax.eval_shape(lambda: batch_of(3)))
-    weights = described(jax.ShapeDtypeStruct((B,), jnp.float32))
+    batch = described(jax.eval_shape(lambda: batch_of(3, rows, T)))
+    weights = described(jax.ShapeDtypeStruct((rows,), jnp.float32))
 
     def grads(params, target, batch, weights):
         return jax.grad(lambda q: double_dqn_loss(
@@ -387,3 +389,40 @@ def test_compiled_update_calls_the_grouped_kernel_as_the_roofline_counts():
         assert torso_scopes.op_scope(name, op_name) == "experts"
     # and what stands around them does carry the path
     assert re.search(r'op_name="[^"]*/router/[^"]*/experts/', entry)
+
+
+def test_compiled_update_keeps_attention_in_the_fused_kernel(monkeypatch):
+    """At widths the kernel takes (``ops.attention.kernel_eligible``; the
+    toy's other widths, so the program stays small) the update compiled
+    for the chip holds, for every attention layer, four forward kernels
+    (online(s), the same made again under the layer's ``nn.remat``,
+    online(s') and the target pass) and the two of the backward pass
+    (``dkv``, ``dq``): no second rematerialisation.  Each carries its
+    ``jax.named_scope`` path, so ``torso_scopes`` places it under ``mla``
+    without an entry in ``KERNELS``; and no float32 ``[.., T, T]`` buffer
+    is anywhere in the program: scores and softmax stay on the chip."""
+    import re
+
+    from apex_tpu.ops import attention
+    from benchmark import torso_scopes
+
+    wide = dict(C, context=1024, qk_nope_head_dim=96, qk_rope_head_dim=32,
+                v_head_dim=128)
+    monkeypatch.setitem(glm.PRESETS, "eligible_toy", wide)
+    t = wide["context"]
+    assert attention.attention_path(t, 128, 128, "tpu")["fused"] == 1
+    hlo = _tpu_update_hlo("eligible_toy", rows=2)
+    calls = re.findall(r"\n\s*(%[\w.\-]+) = [^\n]*custom-call\([^\n]*"
+                       r"tpu_custom_call[^\n]*op_name=\"([^\"]*)\"", hlo)
+    kernels = [(n, op) for n, op in calls if "flash" in op]
+    layers = wide["n_dense_layers"] + wide["n_expert_layers"]
+    backward = [n for n, _op in kernels if "bwd" in n]
+    assert len(kernels) - len(backward) == 4 * layers, kernels
+    assert len(backward) == 2 * layers, backward
+    assert sum("dkv" in n for n in backward) == layers
+    for name, op_name in kernels:
+        assert not any(name.lstrip("%").startswith(k)
+                       for k in torso_scopes.KERNELS)
+        assert torso_scopes.scope_of(op_name + ":") == "mla", op_name
+        assert torso_scopes.op_scope(name, op_name) == "mla"
+    assert not re.search(rf"f32\[[\d,]*{t},{t}\]", hlo)

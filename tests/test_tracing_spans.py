@@ -341,6 +341,53 @@ def test_scan_dispatch_splits_its_keys_outside_the_gap(tmp_path, monkeypatch):
 
 # -- the step program's scopes -------------------------------------------------------
 
+@pytest.mark.parametrize("site,torso", [
+    ("trainer", "glm47_flash_tiny"), ("rollout", "glm47_flash_tiny"),
+    ("trainer", "dueling")])
+def test_attention_path_instant_says_which_way_attention_went(
+        site, torso, tmp_path, monkeypatch, capfd):
+    """Whoever builds a token torso (a trainer, an on-device rollout)
+    leaves one ``attention_path`` instant in the ring and one line on
+    stderr: here, on a CPU and at the toy's widths, ``fused: 0`` and no
+    block sizes; a dueling network has no attention and says nothing."""
+    from apex_tpu.config import (ActorConfig, ApexConfig, EnvConfig,
+                                 LearnerConfig, ReplayConfig)
+    from apex_tpu.training.anakin import make_anakin_engine
+    from apex_tpu.training.apex import ApexTrainer
+
+    tokens = torso != "dueling"
+    cfg = ApexConfig(
+        env=EnvConfig(env_id="ApexTokens-v0" if tokens
+                      else "ApexCatchSmall-v0",
+                      frame_stack=1 if tokens else 2, clip_rewards=False,
+                      episodic_life=False),
+        replay=ReplayConfig(capacity=256, warmup=32),
+        learner=LearnerConfig(batch_size=8, compute_dtype="float32",
+                              torso=torso),
+        actor=ActorConfig(n_actors=1, n_envs_per_actor=2, send_interval=16))
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        if site == "trainer":
+            ApexTrainer(cfg, pool=ScriptedPool([]), respawn_workers=False)
+        else:
+            make_anakin_engine(cfg, rollout_len=4)
+        found = [ev["args"] for ev
+                 in obs_trace.get_ring().to_chrome()["traceEvents"]
+                 if ev.get("name") == "attention_path"]
+    finally:
+        obs_trace.reset_for_tests()
+    err = capfd.readouterr().err
+    if not tokens:
+        assert found == [] and "attention_path" not in err
+        return
+    assert found == [{"site": site, "torso": torso, "fused": 0,
+                      "context": 16, "qk_head_dim": 16, "v_head_dim": 16,
+                      "platform": "cpu"}]
+    assert f"attention_path site={site} torso={torso} fused=0" in err
+
+
 def _hlo_ops(lowered) -> list[tuple[str, str]]:
     """``(opcode, op_name)`` of every instruction of the compiled program
     (compiled: XLA's inliner is what prefixes the operations of a called
