@@ -40,7 +40,6 @@ class ReplayConfig:
     # hard-coded in the loss/actor priority calcs, exactly as it does there.
     eps: float = 1e-6
     # TPU knobs
-    device_resident: bool = True     # HBM struct-of-arrays vs. host (C++/numpy) buffer
     frame_pool: bool = False         # dedup frame-pool storage layout for stacked pixels
     # Drivers refuse to allocate a replay shard whose estimated footprint
     # exceeds this (leaving headroom for params/activations on a 16GB chip).
@@ -79,36 +78,12 @@ class LearnerConfig:
     torso: str = "dueling"
     ingest_chunk: int = 512          # transitions folded into each fused step
     mesh_shape: tuple[int, ...] = (1,)
-    mesh_axes: tuple[str, ...] = ("dp",)
     # >1: when at least this many chunks are queued (i.e. the learner is
     # the bottleneck), drain and run them as ONE lax.scan dispatch of
     # scan_steps bit-identical fused steps — amortizes host dispatch
     # latency (training/learner.py:scan_fused_steps).  Both families (DQN
     # and AQL), single-shard only; on a dp>1 mesh it quietly stays at 1.
     scan_steps: int = 1
-    # Async ingest pipeline (training/ingest_pipeline.py): a staging thread
-    # drains worker chunks, merges ingest-only chunks into one payload, and
-    # device_puts the next dispatch's data into a bounded on-device ring
-    # while the current fused step runs — host decode, H2D staging, and
-    # device compute overlap instead of serializing.  Order-preserving and
-    # numerics-neutral (bit-parity pinned in tests/test_ingest_pipeline.py
-    # and, for dp>1, tests/test_sharded_pipeline.py).  Covers every
-    # concurrent trainer: single-shard learners stage chunk-granular
-    # slots; dp>1 meshes stage whole round-robin groups (per-shard merged
-    # when ingest-only, NamedSharding device_put over the dp axis) with
-    # per-chip PRNG keys pre-split + pre-placed off the hot loop.  The
-    # single-process drivers quietly ignore it.  False = the serial
-    # drain (kept reachable for A/B).
-    ingest_pipeline: bool = True
-    # Staged-slot ring depth.  2 = classic double buffering (the next
-    # dispatch's data is in HBM while the current one runs); deeper rings
-    # buy nothing but memory and backpressure latency.
-    pipeline_depth: int = 2
-    # Max frame chunks (dp>1: round-robin groups) coalesced into ONE
-    # ingest payload when the learner is not train-eligible (warmup fill /
-    # replay-ratio cap) — each merge of m turns m dispatches + m H2D
-    # copies into one.
-    pipeline_merge: int = 8
 
 
 @dataclass(frozen=True)
@@ -149,15 +124,14 @@ class ActorConfig:
     # group's env stepping overlaps the other group's inference.  Per-group
     # PRNG keys derive via fold_in(group) on the per-step key IN BOTH
     # MODES, so on/off trajectories are bit-identical per slot
-    # (tests/test_vector.py pins it) — the knob is a pure scheduling A/B,
-    # same discipline as LearnerConfig.ingest_pipeline.  Families fall
-    # back to the serial interleave when B < 2 (one group: nothing to
-    # overlap).  The win needs a spare host core or an off-host policy
-    # device; a 1-core box shows parity, not regression.
+    # (tests/test_vector.py pins it) — the knob is a pure scheduling A/B.
+    # Families fall back to the serial interleave when B < 2 (one group:
+    # nothing to overlap).  The win needs a spare host core or an off-host
+    # policy device; a 1-core box shows parity, not regression.
     double_buffer: bool = True
     # Vector steps between periodic ActorTimingStat emissions (policy-wait
     # / env-step / drain fractions + frames/s, shipped on the stat queue
-    # and surfaced in the learner logs and bench "actor_plane").  0 = off.
+    # and surfaced in the learner logs and ``actor_plane()``).  0 = off.
     timing_interval: int = 256
     # Centralized batched inference (apex_tpu/infer_service): instead of
     # running the policy on the actor host's CPU, each half-group's
@@ -236,12 +210,9 @@ class CommsConfig:
     replay_ip: str = "127.0.0.1"
     learner_ip: str = "127.0.0.1"
     batch_port: int = 51001          # actor -> replay transition stream
-    prios_port: int = 51002          # learner -> replay priority updates
-    sample_port: int = 51003         # replay -> learner sampled batches
     param_port: int = 52001          # learner PUB param broadcast
     barrier_port: int = 52002        # startup handshake ROUTER
     max_outstanding_sends: int = 3   # actor credit window (actor.py:110-112)
-    max_outstanding_prios: int = 16  # learner->replay window (learner.py:121-127)
     param_hwm: int = 3               # PUB high-water mark (learner.py:60)
     status_port: int = 52003         # fleet-status REP (--role status)
     # Learner-side decoder threads unpickling chunk payloads off the

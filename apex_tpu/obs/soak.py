@@ -5,9 +5,7 @@ run_local.sh`` for a configurable WALL budget (not a step target — the
 learner's step count is an outcome, not an input), samples the fleet SLO
 engine (:mod:`apex_tpu.obs.slo`) off the learner's status port every
 tick, and emits one machine-readable ``SOAK_*.json``: SLO compliance %
-per objective, the alert timeline, throughput vs offered load, and the
-measured ``effective_cores`` that makes numbers comparable across boxes
-(the bench discipline since part-1d).
+per objective, the alert timeline, and throughput vs offered load.
 
 The topology is whatever ``run_local.sh`` env twins say — the soak adds
 ``APEX_LOADGEN=N`` (on-device traffic sources saturating the chunk
@@ -37,7 +35,6 @@ import json
 import os
 import signal
 import subprocess
-import sys
 import time
 
 
@@ -152,18 +149,6 @@ def build_artifact(meta: dict, samples: list[dict],
     }
 
 
-def _effective_cores() -> float | None:
-    """Measured parallel CPU capacity (the bench part-1d helper), or
-    None when the bench module is unimportable here (soak must run from
-    a bare checkout without it)."""
-    try:
-        sys.path.insert(0, _repo_root())
-        from bench import _effective_cores as measure
-        return round(float(measure()), 3)
-    except Exception:
-        return None
-
-
 # -- the drive ---------------------------------------------------------------
 
 
@@ -199,8 +184,6 @@ def run_soak(args: argparse.Namespace) -> dict:
         "chaos_seed": os.environ.get("CHAOS_SEED") or None,
         "chaos_spec": os.environ.get("CHAOS_SPEC") or None,
         "remote_policy": os.environ.get("APEX_REMOTE_POLICY") or None,
-        "effective_cores": (None if args.no_effective_cores
-                            else _effective_cores()),
     }
     cmd = ["bash", os.path.join(root, "scripts", "run_local.sh"),
            args.env_id, str(args.actors), str(args.steps),
@@ -275,8 +258,6 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--status-port", type=int, default=52003)
     p.add_argument("--out", default=None,
                    help="artifact path (default SOAK_<env>_<ts>.json)")
-    p.add_argument("--no-effective-cores", action="store_true",
-                   help="skip the parallel-capacity measurement")
     p.add_argument("--verbose", action="store_true")
     args = p.parse_args(argv)
     artifact = run_soak(args)

@@ -177,7 +177,7 @@ class LearnerObs:
     """Learner-side span join: publish ledger + the headline histograms
     + chunk-lineage trace events.
 
-    Call order per consumed slot (both the pipelined and serial drains):
+    Call order per consumed slot:
     :meth:`pre_consume` immediately before the dispatch (stamps
     ``consume``), :meth:`post_consume` right after the dispatch call
     returns (stamps ``prio_wb``, feeds the histograms, emits lineage

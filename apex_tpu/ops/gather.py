@@ -111,8 +111,8 @@ def resolved_mode(frames: jax.Array, mode: str = "auto") -> str:
     gather kernel doesn't just fail, it can wedge the whole device for
     every later client.  Until a trace says it wins, the kernel path is
     strictly opt-in — ``APEX_GATHER_MODE=pallas`` or an explicit
-    ``gather_mode="pallas"`` — and ``bench.py`` attempts that opt-in
-    LAST, after the other numbers are recorded."""
+    ``gather_mode="pallas"`` — and ``chip_smoke.py`` attempts that
+    opt-in LAST, after the other stages are recorded."""
     if mode != "auto":
         if mode == "pallas":
             # explicit API opt-in gets the same per-operand eligibility

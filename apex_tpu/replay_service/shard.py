@@ -19,7 +19,7 @@ Bit-parity contract (the reason this class exists instead of an ad-hoc
 loop in the server): with ``strict_order=True`` and one shard, the
 sequence ``ingest(c1); b1=next_batch(); write_back(b1); ingest(c2); ...``
 produces bit-identical replay state, sampled batches, and key-chain
-position to the in-learner serial loop's ``fused_step(c1); fused_step(c2);
+position to the in-learner sequence ``fused_step(c1); fused_step(c2);
 ...`` — same tree, same beta schedule (beta is computed from the
 PRE-ingest transition count, exactly like the trainer's ``_beta()`` call
 before each fused dispatch), same keys.  tests/test_replay_service.py pins

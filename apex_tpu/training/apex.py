@@ -38,7 +38,6 @@ from apex_tpu.config import ApexConfig
 from apex_tpu.fleet.heartbeat import Heartbeat
 from apex_tpu.fleet.registry import FleetRegistry
 from apex_tpu.obs import spans as obs_spans
-from apex_tpu.parallel.aggregate import stack_chunk_messages
 from apex_tpu.envs.registry import (make_env, make_eval_env, num_actions,
                                     unstacked_env_spec)
 from apex_tpu.models import (learner_apply_fn, make_q_network,
@@ -53,7 +52,7 @@ from apex_tpu.serving.deploy import ServingStat
 from apex_tpu.tenancy.scheduler import TenancyStat
 from apex_tpu.training.checkpoint import (CheckpointableTrainer,
                                           Checkpointer)
-from apex_tpu.training.ingest_pipeline import KeyBlocks
+from apex_tpu.training.ingest_pipeline import IngestPipeline, KeyBlocks
 from apex_tpu.training.learner import LearnerCore
 from apex_tpu.training.state import create_train_state
 from apex_tpu.utils.metrics import MetricLogger, RateCounter
@@ -101,10 +100,10 @@ class ConcurrentTrainer(CheckpointableTrainer):
     scan_steps = 1
     scan_dispatches = 0      # K-step dispatches taken (observability)
     # async ingest pipeline (training/ingest_pipeline.py): live only
-    # inside train() when config.learner.ingest_pipeline — single-shard
-    # (chunk-granular) and dp>1 (round-robin-group-granular, pre-placed
-    # per-chip keys) alike; _ingest_multi is the scan-of-ingests dispatch
-    # for slots the replay-ratio cap says to absorb without training
+    # inside train() — single-shard (chunk-granular) and dp>1
+    # (round-robin-group-granular, pre-placed per-chip keys) alike;
+    # _ingest_multi is the scan-of-ingests dispatch for slots the
+    # replay-ratio cap says to absorb without training
     _pipeline = None
     _pipeline_base = 0       # self.ingested when the pipeline started
     _ingest_multi = None
@@ -157,10 +156,9 @@ class ConcurrentTrainer(CheckpointableTrainer):
     _obs = None
     # sharded replay service (apex_tpu/replay_service): when a
     # ReplayServiceClient is attached, sampling lives in the shard fleet —
-    # the loop consumes pre-sampled batches (pipeline "batch" slots, or
-    # direct client polls on the serial path), trains via
-    # core.update_from_batch, and routes priority write-backs to the
-    # owning shard.  The chunk path stays live as the direct-ingest
+    # the loop consumes pre-sampled batches (pipeline "batch" slots),
+    # trains via core.update_from_batch, and routes priority write-backs
+    # to the owning shard.  The chunk path stays live as the direct-ingest
     # fallback (actors reroute to the learner when their shard wedges).
     replay_client = None
     _train_batch = None
@@ -245,11 +243,12 @@ class ConcurrentTrainer(CheckpointableTrainer):
             # hand the staging thread an on-device COPY: the hot loop's
             # next fused step donates train_state, which would invalidate
             # the original buffers under the staging thread's device_get.
-            # The copy dispatch is async — no hot-loop drain (the serial
-            # path below drains the whole device pipeline per publish).
+            # The copy dispatch is async — no hot-loop drain.
             params = jax.tree.map(jnp.copy, self.train_state.params)
             self._pipeline.publish(self.param_version, params)
             return
+        # no pipeline: FusedApexTrainer.train (ondevice/fused.py) publishes
+        # through here with no staging thread to take the device_get
         host_params = jax.device_get(self.train_state.params)
         self.pool.publish_params(self.param_version, host_params)
 
@@ -311,8 +310,10 @@ class ConcurrentTrainer(CheckpointableTrainer):
         def _keys(key):
             # pre-split + pre-placed per-chip keys (the pipeline's
             # KeyPrefetcher hands raw uint32 key data already sharded
-            # over the mesh) pass straight through; a raw chain key pays
-            # the serial per-dispatch split + sharded put
+            # over the mesh) pass straight through; a raw chain key (the
+            # benchmark harness and FusedApexTrainer.train call these
+            # programs with no pipeline live) pays the per-dispatch split
+            # + sharded put
             if getattr(key, "dtype", None) == jnp.uint32:
                 return key
             return sl.device_keys(key)
@@ -354,27 +355,20 @@ class ConcurrentTrainer(CheckpointableTrainer):
             # dp>1 included: _make_batch_train shards the service batch
             # over the mesh and pmeans the update (PR 17)
             self._train_batch = self._make_batch_train()
-        pipeline = None
-        if self._use_pipeline():
-            from apex_tpu.training.ingest_pipeline import IngestPipeline
-            sharded = getattr(self, "sharded", None)
-            pipeline = IngestPipeline(
-                pool,
-                depth=getattr(cfg.learner, "pipeline_depth", 2),
-                scan_steps=(self.scan_steps if self._multi is not None
-                            else 1),
-                merge_max=getattr(cfg.learner, "pipeline_merge", 8),
-                state_fn=self._pipeline_state,
-                capacity=getattr(self.replay, "capacity", None),
-                frame_capacity=getattr(self.replay, "f_capacity", None),
-                # dp>1: group-granular staging + the key prefetcher takes
-                # over the dispatch key chain (seeded with self.key;
-                # _dispatch_key writes the advanced chain state back)
-                sharded=sharded,
-                key=self.key if sharded is not None else None,
-                replay_client=client)
-            self._pipeline = pipeline
-            self._pipeline_base = self.ingested
+        sharded = getattr(self, "sharded", None)
+        pipeline = self._pipeline = IngestPipeline(
+            pool,
+            scan_steps=self.scan_steps if self._multi is not None else 1,
+            state_fn=self._pipeline_state,
+            capacity=getattr(self.replay, "capacity", None),
+            frame_capacity=getattr(self.replay, "f_capacity", None),
+            # dp>1: group-granular staging + the key prefetcher takes
+            # over the dispatch key chain (seeded with self.key;
+            # _dispatch_key writes the advanced chain state back)
+            sharded=sharded,
+            key=self.key if sharded is not None else None,
+            replay_client=client)
+        self._pipeline_base = self.ingested
         if self.fleet is None:
             self.fleet = FleetRegistry(cfg.comms)
         try:
@@ -391,11 +385,10 @@ class ConcurrentTrainer(CheckpointableTrainer):
         if client is not None:
             client.learner_epoch = self.learner_epoch
         self._start_status_server()
-        if pipeline is not None:
-            # staging starts only once the pool is live: its thread owns
-            # every poll_chunks/publish_params call from here to stop()
-            # (see RemotePool's thread-affinity contract)
-            pipeline.start()
+        # staging starts only once the pool is live: its thread owns
+        # every poll_chunks/publish_params call from here to stop()
+        # (see RemotePool's thread-affinity contract)
+        pipeline.start()
         try:
             self._publish()
             last_publish = time.monotonic()
@@ -439,65 +432,24 @@ class ConcurrentTrainer(CheckpointableTrainer):
                     behind = (warm and floor is not None
                               and consumed < ingested_eff * floor)
 
+                    # consume ready-on-device slots; the staging thread
+                    # already polled/decoded/merged/staged while the
+                    # previous dispatch ran.  Service mode consumes even
+                    # when "behind" — behind means the learner owes MORE
+                    # training, and batch slots are exactly that
                     got_data = False
-                    if pipeline is not None:
-                        # pipelined: consume ready-on-device slots; the
-                        # staging thread already polled/decoded/merged/staged
-                        # while the previous dispatch ran.  Service mode
-                        # consumes even when "behind" — behind means the
-                        # learner owes MORE training, and batch slots are
-                        # exactly that
-                        slot = None
-                        if not behind or client is not None:
-                            with self._span("poll_slot"):
-                                slot = pipeline.poll_slot(
-                                    timeout=0 if (warm or client is not None)
-                                    else 0.05)
-                        if slot is not None:
-                            got_data = True
-                            m = self._consume_slot(slot, warm, budget,
-                                                   target_steps)
-                            if m is not None:
-                                metrics = m
-                    else:
-                        if client is not None \
-                                and self.steps_rate.total < budget:
-                            # serial service path: one pre-sampled batch per
-                            # iteration, write-back shipped inline
-                            with self._span("poll_slot"):
-                                item = client.poll_batch(timeout=0.02)
-                            if item is not None:
-                                got_data = True
-                                m = self._consume_slot(
-                                    self._host_batch_slot(item), warm, budget,
-                                    target_steps)
-                                if m is not None:
-                                    metrics = m
-                        # serial: scan dispatch (config.scan_steps > 1) asks
-                        # for K chunks only when the learner can take all K
-                        # steps within BOTH the ratio budget and the
-                        # remaining total_steps contract ("run total_steps
-                        # MORE updates" — a K-dispatch must not overshoot
-                        # it) — exactly the chunk-backlog regime where
-                        # dispatch latency, not data supply, bounds throughput
-                        want = 1
-                        if (self._multi is not None and warm
-                                and target_steps - self.steps_rate.total
-                                >= self.scan_steps
-                                and self.steps_rate.total + self.scan_steps - 1
-                                < budget):
-                            want = self.scan_steps
-
-                        msgs = []
-                        if not behind:
-                            with self._span("poll_slot"):
-                                msgs = pool.poll_chunks(
-                                    want, timeout=0 if warm else 0.05)
-                        if msgs:
-                            got_data = True
-                            m = self._drain_serial(msgs, want, warm, budget)
-                            if m is not None:
-                                metrics = m
+                    slot = None
+                    if not behind or client is not None:
+                        with self._span("poll_slot"):
+                            slot = pipeline.poll_slot(
+                                timeout=0 if (warm or client is not None)
+                                else 0.05)
+                    if slot is not None:
+                        got_data = True
+                        m = self._consume_slot(slot, warm, budget,
+                                               target_steps)
+                        if m is not None:
+                            metrics = m
                     if not got_data and warm \
                             and self.steps_rate.total < budget:
                         k = self._dispatch_key()
@@ -575,9 +527,8 @@ class ConcurrentTrainer(CheckpointableTrainer):
                                 "loop_keys_served": self._blocks().served,
                                 "loop_beta_puts": self.beta_puts,
                                 "loop_beta_reused": self.beta_reused}
-                            if pipeline is not None:
-                                extra |= {f"pipeline_{k}": v
-                                          for k, v in pipeline.stats.items()}
+                            extra |= {f"pipeline_{k}": v
+                                      for k, v in pipeline.stats.items()}
                             if self._obs is not None:
                                 extra |= self._obs.scalars()
                             if client is not None:
@@ -595,12 +546,11 @@ class ConcurrentTrainer(CheckpointableTrainer):
                         self._last_log = steps
                     this_pass.note(kind=self._pass_kind)
         finally:
-            if pipeline is not None:
-                # stop staging BEFORE the pool teardown (the staging
-                # thread is the pool's only chunk consumer while live)
-                self._pipeline_last_stats = dict(pipeline.stats)
-                pipeline.stop()
-                self._pipeline = None
+            # stop staging BEFORE the pool teardown (the staging thread
+            # is the pool's only chunk consumer while live)
+            self._pipeline_last_stats = dict(pipeline.stats)
+            pipeline.stop()
+            self._pipeline = None
             if self._fleet_status is not None:
                 self._fleet_status.stop()
                 self._fleet_status = None
@@ -1230,19 +1180,10 @@ class ConcurrentTrainer(CheckpointableTrainer):
 
     # -- async ingest pipeline (training/ingest_pipeline.py) ---------------
 
-    def _use_pipeline(self) -> bool:
-        """Pipeline staging applies to every concurrent learner,
-        single-shard and dp>1 alike: the sharded plan stages whole
-        round-robin groups (ChunkAggregator-stacked, per-shard-merged
-        when ingest-only) plus pre-split per-chip keys ahead of the
-        sharded dispatch.  ``ingest_pipeline=False`` keeps the serial
-        drain for A/B."""
-        return bool(getattr(self.cfg.learner, "ingest_pipeline", False))
-
     def _dispatch_key(self):
         """One dispatch's PRNG key, advancing the key chain exactly as
-        the serial loop's ``self.key, k = split(self.key)`` would, without
-        a program launched for it in this pass.  ``self.key`` is the
+        an eager ``self.key, k = split(self.key)`` would, without a
+        program launched for it in this pass.  ``self.key`` is the
         chain; whoever assigns it (this method, construction, a checkpoint
         restore, ``evaluate()``) is followed from there.
 
@@ -1429,10 +1370,9 @@ class ConcurrentTrainer(CheckpointableTrainer):
         polled-transition total (plus the ingested count the pipeline
         started from): when the chunk under consideration reaches the
         front of the (order-preserving) pipeline, the trainer's
-        ``ingested`` will equal exactly that — so the prediction
-        reproduces the serial loop's per-chunk warm/budget gating, and a
-        merge group never straddles the warmup boundary (bit-parity
-        depends on this)."""
+        ``ingested`` will equal exactly that — so the prediction is the
+        per-chunk warm/budget gate ``_consume_slot`` applies, and a merge
+        group never straddles the warmup boundary."""
         from apex_tpu.training.ingest_pipeline import PipelineState
         cfg = self.cfg
         pipe = self._pipeline
@@ -1521,26 +1461,10 @@ class ConcurrentTrainer(CheckpointableTrainer):
             return train_on_batch
         return jitted
 
-    def _host_batch_slot(self, item: dict):
-        """Serial-path twin of the pipeline's ``_build_batch_slot``:
-        host arrays go straight into the dispatch (the jit call ingests
-        numpy operands; there is no staging thread to hide an H2D)."""
-        from apex_tpu.training.ingest_pipeline import StagedSlot
-        spans = obs_spans.spans_of(item)
-        obs_spans.stamp_spans(spans, "stage")
-        return StagedSlot(
-            kind="batch", payload=item["batch"],
-            prios=np.asarray(item["weights"], np.float32),
-            n_trans=0, planned_steps=1, spans=tuple(spans),
-            idx=np.asarray(item["idx"]),
-            shard=int(item.get("shard", 0)), seq=int(item["seq"]),
-            update_key=item.get("update_key"))
-
     def _consume_batch_slot(self, slot):
         """Train on one shard-sampled batch and route the priority
-        write-back to its owning shard (via the staging thread when the
-        pipeline is live — the device_get must not land on the hot
-        loop)."""
+        write-back to its owning shard, via the staging thread (the
+        device_get must not land on the hot loop)."""
         with self._dispatch("batch", self._train_batch) as call:
             key = ()
             if slot.update_key is not None:
@@ -1551,23 +1475,17 @@ class ConcurrentTrainer(CheckpointableTrainer):
                 self.train_state, slot.payload, slot.prios, *key)
         self.steps_rate.tick()
         self.service_steps += 1
-        if self._pipeline is not None:
-            self._pipeline.write_back(slot.shard, slot.seq, slot.idx,
-                                      prios)
-        else:
-            self.replay_client.push_priorities(
-                slot.shard, slot.seq, slot.idx,
-                np.asarray(jax.device_get(prios), np.float32))
+        self._pipeline.write_back(slot.shard, slot.seq, slot.idx, prios)
         return metrics
 
     def _consume_slot(self, slot, warm: bool, budget: float,
                       target_steps: int):
-        """Dispatch one staged slot; returns metrics or None.  Mirrors
-        the serial drain's gating chunk for chunk: train-eligible singles
-        run the fused step, eligible scan stacks run the K-step scan
-        dispatch, everything else is absorbed ingest-only (the
-        replay-ratio cap is re-checked at consume time, so a stale
-        staging prediction can only under-train, never over-train)."""
+        """Dispatch one staged slot; returns metrics or None.  Gated
+        chunk for chunk: train-eligible singles run the fused step,
+        eligible scan stacks run the K-step scan dispatch, everything else
+        is absorbed ingest-only (the replay-ratio cap is re-checked at
+        consume time, so a stale staging prediction can only under-train,
+        never over-train)."""
         spans = slot.spans if self._obs is not None else ()
         self._pre_consume(spans)            # "consume": dispatch issued
         metrics = None
@@ -1613,64 +1531,6 @@ class ConcurrentTrainer(CheckpointableTrainer):
         self._post_consume(spans)           # "prio_wb" + the joins
         self.ingested += slot.n_trans
         self.frames_rate.tick(slot.n_trans)
-        return metrics
-
-    def _drain_serial(self, msgs: list, want: int, warm: bool,
-                      budget: float):
-        """The serial (pipeline-off) drain of one poll's messages.
-        Returns metrics or None."""
-        obs = self._obs
-        if obs is not None:
-            for m in msgs:
-                obs_spans.stamp(m, "recv")  # no staging thread: poll=recv
-        metrics = None
-        if want > 1 and len(msgs) > 1:
-            # scan batch: j chunks -> one device dispatch, quantized to a
-            # power of two so shortfalls (j < K) compile O(log K) scan
-            # programs instead of degrading to j separate dispatches;
-            # the remainder falls through to the per-chunk path IN ORDER.
-            # Betas are the per-step stack the single-dispatch path would
-            # have produced (step i sees ingestion through chunk i-1), so
-            # the annealing schedule is dispatch-shape-invariant.
-            from apex_tpu.training.ingest_pipeline import _pow2_floor
-            j = _pow2_floor(len(msgs))
-            take, msgs = msgs[:j], msgs[j:]
-            payload, prios, n_new = stack_chunk_messages(take)
-            spans = obs_spans.merge_spans(take) if obs is not None else ()
-            n_per = np.asarray([int(m["n_trans"]) for m in take])
-            offsets = np.concatenate([[0], np.cumsum(n_per)[:-1]])
-            betas = np.asarray(
-                [self._beta(self.ingested + int(o))
-                 for o in offsets], np.float32)
-            self._pre_consume(spans)
-            mm = self._dispatch_scan(payload, prios, j, betas)
-            self._post_consume(spans)
-            # scalar observability coarsens to per-dispatch under scan:
-            # report the mean over the j stacked steps
-            metrics = jax.tree.map(lambda x: x.mean(0), mm)
-            self.steps_rate.tick(j)
-            self.scan_dispatches += 1
-            self.ingested += n_new
-            self.frames_rate.tick(n_new)
-        for msg in msgs:
-            # single-chunk path (and scan spillover, one by one)
-            prios = jnp.asarray(msg["priorities"])
-            n_new = int(msg["n_trans"])
-            payload = msg["payload"]
-            spans = obs_spans.spans_of(msg) if obs is not None else ()
-            self._pre_consume(spans)
-            # The replay-ratio cap applies on the chunk path too: an
-            # over-budget learner ingests WITHOUT the fused train half,
-            # so the documented ``train_ratio`` really is the ceiling
-            # (ingesting raises the budget for later steps).
-            if warm and self.steps_rate.total < budget:
-                metrics = self._dispatch_fused(payload, prios)
-                self.steps_rate.tick()
-            else:
-                self._dispatch_ingest(payload, prios)
-            self._post_consume(spans)
-            self.ingested += n_new
-            self.frames_rate.tick(n_new)
         return metrics
 
     # -- checkpointing (A4): format/IO in CheckpointableTrainer ------------

@@ -1,13 +1,13 @@
 """Async ingest pipeline: overlap host decode, H2D staging, and compute.
 
-The serial concurrent loop (:meth:`ConcurrentTrainer.train`) does all of
-this on ONE thread, in sequence, per step: poll the chunk queue (pickle /
-shm decode), stack arrays with host numpy, hand host buffers to the jitted
+A learner loop (:meth:`ConcurrentTrainer.train`) that does all of this on
+ONE thread, in sequence, per step — poll the chunk queue (pickle / shm
+decode), stack arrays with host numpy, hand host buffers to the jitted
 step (whose H2D copy runs synchronously inside the dispatch), then poll
-again.  Host decode, H2D transfer, and device compute therefore never
-overlap — the exact decoupling failure Ape-X exists to avoid (Horgan et
-al. 2018), and the standard fix is double-buffered staging (Stooke &
-Abbeel 2018, PAPERS.md "Accelerated Methods for Deep RL").
+again — never overlaps host decode, H2D transfer, and device compute: the
+exact decoupling failure Ape-X exists to avoid (Horgan et al. 2018), and
+the standard fix is double-buffered staging (Stooke & Abbeel 2018,
+PAPERS.md "Accelerated Methods for Deep RL").
 
 This module runs a single background STAGING thread that:
 
@@ -17,9 +17,8 @@ This module runs a single background STAGING thread that:
   live counters (``state_fn``):
 
   - train-eligible chunks -> a ``lax.scan`` stack of j chunks (one
-    dispatch, j bit-identical fused steps) — this also fixes the serial
-    scan shortfall where j < scan_steps chunks degraded to j separate
-    dispatches;
+    dispatch, j bit-identical fused steps), a shortfall of j <
+    scan_steps chunks quantized to a power of two;
   - ingest-only chunks (warmup fill, replay-ratio cap) -> ONE merged
     payload via :func:`merge_chunk_messages` — m dispatches and m H2D
     copies become one, bit-identically (see below);
@@ -31,7 +30,7 @@ This module runs a single background STAGING thread that:
 Ordering / backpressure / numerics contract:
 
 * Chunks enter slots strictly in poll order and the ring is FIFO — the
-  replay sees the same transition stream as the serial loop.
+  replay sees the transition stream the pool delivered.
 * The ring is BOUNDED and the staging thread polls nothing while it is
   full (or while the replay-ratio floor says the learner is behind), so
   the bounded worker chunk queue backpressures the actor fleet exactly as
@@ -68,10 +67,10 @@ stages group-granular slots:
   shards are independent replays, so bit-parity reduces to the
   single-shard merge contract per shard;
 * per-chip PRNG keys are PRE-SPLIT and PRE-PLACED by a
-  :class:`KeyPrefetcher` that owns the trainer's dispatch key chain: the
-  serial loop pays a sharded ``device_put`` inside every dispatch
-  (``ShardedLearner.device_keys``); the prefetcher generates the exact
-  same chain ahead of time on the staging side.
+  :class:`KeyPrefetcher` that owns the trainer's dispatch key chain: a
+  raw chain key pays a split and a sharded ``device_put`` inside every
+  dispatch (``ShardedLearner.device_keys``); the prefetcher generates the
+  exact same chain ahead of time on the staging side.
 """
 
 from __future__ import annotations
@@ -234,13 +233,13 @@ class KeyPrefetcher:
     holds no chain of its own) — one chain, one owner at a time.
 
     Entry i is ``(device_keys(k_i), chain_{i+1})`` where ``chain_{i+1},
-    k_i = split(chain_i)`` — the EXACT per-dispatch sequence the serial
-    loop produces with ``self.key, k = split(self.key)`` followed by
-    ``ShardedLearner.device_keys(k)``.  The consumer pops entries in
-    dispatch order and assigns the returned chain state back to its
-    ``self.key``, so pipelined runs consume bit-identical keys to serial
-    runs of the same dispatch count AND leave the trainer's key where a
-    serial run would (checkpoints taken mid-train stay exact).
+    k_i = split(chain_i)`` — the EXACT per-dispatch sequence an eager
+    ``self.key, k = split(self.key)`` followed by
+    ``ShardedLearner.device_keys(k)`` produces.  The consumer pops
+    entries in dispatch order and assigns the returned chain state back
+    to its ``self.key``, so a run consumes the eager chain's keys for its
+    dispatch count AND leaves the trainer's key where the eager chain
+    stands (checkpoints taken mid-train stay exact).
 
     The staging thread refills between polls; an empty queue (startup,
     key-hungry burst) generates synchronously under the same lock, so
@@ -331,7 +330,7 @@ class PipelineState:
     NEXT chunk will be trained on or absorbed ingest-only — computed from
     the monotone :meth:`IngestPipeline.polled_total` (plus
     :meth:`IngestPipeline.staged_train_steps` on the budget side) so the
-    prediction sees exactly what the serial loop's warm/budget gate would
+    prediction sees exactly what the consume-time warm/budget gate will
     see when that chunk reaches the front of the queue."""
 
     behind: bool = False
@@ -445,8 +444,7 @@ class IngestPipeline:
         # poll_slot treats "ring empty + staging idle" as dry and may
         # return None; while work is in flight it waits for the slot
         # instead of letting the trainer burn a replay-only step on data
-        # that is milliseconds away (the serial loop's queue poll has the
-        # same preference for fresh data)
+        # that is milliseconds away
         self._idle = threading.Event()
         self._idle.set()
         self._error: BaseException | None = None
@@ -485,7 +483,7 @@ class IngestPipeline:
         warm/budget prediction in ``state_fn`` is race-free: when the
         staging thread asks about the NEXT chunk, this is exactly the
         transition count preceding it in the (order-preserved) stream,
-        i.e. the value the serial loop's per-chunk warm gate would see.
+        i.e. the ``ingested`` the consume-time warm gate will see.
         (``ingested + staged_ahead`` is the same quantity only between
         consumptions — mid-consume it undercounts and a train-eligible
         chunk could get merged into an ingest-only payload.)"""

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache, placed from outside.
 
 A cold TPU compile of the fused learner step is tens of seconds; every
-entry point that jits (``runtime/cli.main``, ``bench.main``,
+entry point that jits (``runtime/cli.main``, ``benchmark/harness.py``,
 ``chip_smoke.py``) calls :func:`ensure_compile_cache` before its first
 jit so a second launch — and every actor worker it spawns — finds the
 programs already built.

@@ -1,16 +1,16 @@
-"""Profiler hooks + MFU accounting (SURVEY.md §5.1).
+"""Profiler hooks and host-loop timers (SURVEY.md §5.1).
 
 The reference's observability is wall-clock BPS prints
-(``origin_repo/learner.py:171-175``).  TPU-side we add the two numbers that
-actually locate a bottleneck:
+(``origin_repo/learner.py:171-175``).  TPU-side we add what locates a
+bottleneck on the host side of a device loop:
 
 * :func:`trace` — ``jax.profiler`` trace context; open the dump in
   TensorBoard/XProf to see per-op HBM + MXU utilization.
-* :func:`flops_per_call` / :func:`mfu` — XLA's own cost analysis for a
-  jitted callable, turned into model-FLOPs-utilization given the chip's
-  peak (:func:`device_peak_flops`, keyed by ``device_kind``).  This is
-  the honest "how much of the MXU are we using" metric for the fused
-  learner step (bench.py reports it).
+* :class:`PhaseTimer` / :class:`DispatchGapTimer` — where a host loop's
+  wall time goes between device dispatches.
+
+FLOP counts, device peaks and utilization live with the benchmark
+(``benchmark/costs.py``, ``benchmark/peaks.json``).
 """
 
 from __future__ import annotations
@@ -23,28 +23,6 @@ from typing import Iterator
 import jax
 
 from apex_tpu.utils.metrics import percentile  # noqa: F401 (re-export)
-
-# bf16 peak FLOP/s of one chip, keyed by ``jax.devices()[0].device_kind``.
-# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
-# HBM at 819 GB/s).  A device that is not in the table is an error, not a
-# default — a utilization against the wrong peak is worse than none.
-PEAK_FLOPS = {
-    "TPU v5 lite": 197e12,
-}
-
-
-def device_peak_flops(device_kind: str | None = None) -> float:
-    """Peak bf16 FLOP/s for ``device_kind`` (default: the first JAX
-    device's).  Raises ``KeyError`` for a device the table does not know."""
-    if device_kind is None:
-        device_kind = jax.devices()[0].device_kind
-    try:
-        return PEAK_FLOPS[device_kind]
-    except KeyError:
-        raise KeyError(
-            f"no peak FLOP/s on record for device_kind={device_kind!r} "
-            f"(known: {sorted(PEAK_FLOPS)}) — add it to "
-            f"apex_tpu.utils.profiling.PEAK_FLOPS with its source") from None
 
 
 @contextlib.contextmanager
@@ -62,27 +40,6 @@ def trace(logdir: str) -> Iterator[None]:
     finally:
         ring.annotate(False)
         jax.profiler.stop_trace()
-
-
-def flops_per_call(jitted, *args, **kwargs) -> float | None:
-    """XLA-estimated FLOPs of one call of a jitted function, or None when
-    the backend's cost analysis carries no FLOP count (some CPU builds).
-    Lowering and compile errors propagate."""
-    analysis = jitted.lower(*args, **kwargs).compile().cost_analysis()
-    if isinstance(analysis, list):      # one entry per device program
-        analysis = analysis[0] if analysis else None
-    if not analysis or "flops" not in analysis:
-        return None
-    return float(analysis["flops"])
-
-
-def mfu(flops: float | None, calls_per_sec: float,
-        peak_flops: float) -> float | None:
-    """Model-FLOPs-utilization in [0, 1] against ``peak_flops``
-    (:func:`device_peak_flops` looks the running chip's up)."""
-    if flops is None or peak_flops <= 0:
-        return None
-    return flops * calls_per_sec / peak_flops
 
 
 class PhaseTimer:

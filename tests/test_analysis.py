@@ -395,7 +395,7 @@ def test_j006_silent_outside_loop():
 
 def test_j006_silent_in_timing_harness():
     """A loop that reads the clock is a measurement harness — timing a
-    device fence is the one legitimate hot-loop sync (bench.py's rep
+    device fence is the one legitimate hot-loop sync (a benchmark's rep
     loops)."""
     assert not fires("""
         import time, jax

@@ -72,7 +72,7 @@ def test_trainer_rejects_replay_over_hbm_budget():
 @pytest.mark.slow
 def test_apex_mechanics_atari_shapes():
     """The FLAGSHIP shapes end to end: 84x84x1 uint8 frames, stack 4 —
-    the exact Nature-DQN geometry bench.py and the Pong target use.  This
+    the exact Nature-DQN geometry the benchmark and the Pong target use.  This
     exercises the tile-padded frame ring (7056 -> 7168 rows), the conv
     trunk, and chunked actor ingest at real frame sizes; a few training
     steps prove shape plumbing, not learning."""

@@ -235,23 +235,98 @@ def test_pipeline_staging_error_surfaces_to_consumer():
         pipe.stop()
 
 
-# -- end-to-end bit-parity: pipelined vs serial trainer loop ----------------
+# -- end-to-end: the loop's state is the plain fold of its own dispatches -----
 
-def _run_trainer(pipeline_on: bool, msgs, total_steps: int):
+def train_recorded(trainer, **train_kw) -> list:
+    """``trainer.train(**train_kw)`` with its three programs wrapped; what
+    it dispatched, ``[("ingest", 64), ("fused", 16), ("train", 0), ...]``:
+    each dispatch's program and the transitions it consumed
+    (``trainer.ingested`` from one dispatch to the next)."""
+    log = []
+    for kind in ("ingest", "fused", "train"):
+        fn = getattr(trainer, "_" + kind)
+
+        def program(*operands, _fn=fn, _kind=kind):
+            log.append((_kind, trainer.ingested))
+            return _fn(*operands)
+        program.__name__ = fn.__name__      # _dispatch names its span by it
+        setattr(trainer, "_" + kind, program)
+    trainer.train(log_every=10 ** 9, **train_kw)
+    marks = [at for _kind, at in log[1:]] + [trainer.ingested]
+    return [(kind, after - at) for (kind, at), after in zip(log, marks)]
+
+
+def fold_reference(trainer, sequence: list) -> None:
+    """The plain reference: on a trainer built from the same seed over the
+    same scripted messages (never ``train()``-ed: no staging thread, no
+    merge, no key block), walk its pool's messages in order as ``sequence``
+    says.  An ``ingest`` of n transitions folds its messages one by one
+    through ``_ingest``; a ``fused`` takes one message (one round-robin
+    group at dp>1) and a ``train`` none, each with the next key of the
+    eager chain ``key, k = split(key)`` and ``_beta`` of what was ingested
+    before it."""
+    t = trainer
+
+    def message(n_most: int) -> tuple:
+        (msg,) = t.pool.poll_chunks(1)
+        assert 0 < int(msg["n_trans"]) <= n_most
+        return (msg["payload"], np.asarray(msg["priorities"], np.float32),
+                int(msg["n_trans"]))
+
+    for kind, n in sequence:
+        if kind == "ingest":
+            while n:
+                payload, prios, took = message(n)
+                t.replay_state = t._ingest(t.replay_state, payload, prios)
+                t.ingested += took
+                n -= took
+            continue
+        t.key, k = jax.random.split(t.key)
+        beta = jax.numpy.float32(t._beta())
+        if kind == "fused":
+            payload, prios, took = message(n)
+            assert took == n
+            t.train_state, t.replay_state, _ = t._fused(
+                t.train_state, t.replay_state, payload, prios, k, beta)
+            t.ingested += took
+        else:
+            assert kind == "train" and n == 0
+            t.train_state, t.replay_state, _ = t._train(
+                t.train_state, t.replay_state, k, beta)
+        t.steps_rate.tick()
+
+
+def assert_same_learner(a, b, replay_shards: int | None = None) -> None:
+    """Train state (params, target, optimizer, step), every replay field,
+    the counters and the key chain of two trainers, bit for bit."""
+    flat_a = jax.tree_util.tree_leaves_with_path(
+        jax.device_get(a.train_state))
+    flat_b = dict(jax.tree_util.tree_leaves_with_path(
+        jax.device_get(b.train_state)))
+    assert flat_a and len(flat_a) == len(flat_b)
+    for path, leaf in flat_a:
+        assert np.array_equal(np.asarray(leaf), np.asarray(flat_b[path])), \
+            f"train state diverged at {jax.tree_util.keystr(path)}"
+    if replay_shards is not None:
+        assert np.asarray(a.replay_state.pos).shape[0] == replay_shards
+    _assert_states_identical(a.replay_state, b.replay_state)
+    assert a.ingested == b.ingested
+    assert a.steps_rate.total == b.steps_rate.total
+    assert np.array_equal(np.asarray(jax.random.key_data(a.key)),
+                          np.asarray(jax.random.key_data(b.key)))
+
+
+def _parity_trainer(msgs, train_ratio=None):
     from apex_tpu.training.apex import ApexTrainer
 
     cfg = small_test_config(capacity=256, batch_size=16, n_actors=1)
     cfg = cfg.replace(
         replay=dataclasses.replace(cfg.replay, warmup=64),
         learner=dataclasses.replace(cfg.learner,
-                                    ingest_pipeline=pipeline_on,
                                     target_update_interval=20))
-    pool = ScriptedPool(copy.deepcopy(msgs))
-    trainer = ApexTrainer(cfg, pool=pool, publish_min_seconds=10.0,
-                          respawn_workers=False)
-    trainer.train(total_steps=total_steps, max_seconds=120,
-                  log_every=10 ** 9)
-    return jax.device_get(trainer.train_state.params), trainer
+    return ApexTrainer(cfg, pool=ScriptedPool(copy.deepcopy(msgs)),
+                       publish_min_seconds=10.0, respawn_workers=False,
+                       train_ratio=train_ratio)
 
 
 def _cartpole_chunk_messages(n_chunks: int) -> list[dict]:
@@ -273,48 +348,37 @@ def _cartpole_chunk_messages(n_chunks: int) -> list[dict]:
     return msgs[:n_chunks]
 
 
-def test_pipelined_loop_bit_parity_with_serial():
-    """The acceptance pin: the SAME deterministic chunk stream through the
-    pipelined and serial trainer loops yields bit-identical params after N
-    fused steps.  The stream crosses the warmup boundary, so the pipeline
-    exercises merged warmup ingest, staged fused singles, AND replay-only
-    steps — and must reproduce the serial key/beta/schedule exactly."""
+@pytest.mark.parametrize("train_ratio,total_steps", [(None, 40), (0.5, 10)],
+                         ids=["uncapped", "ratio_capped"])
+def test_loop_state_is_the_fold_of_its_own_dispatches(train_ratio,
+                                                      total_steps):
+    """The acceptance pin: whatever sequence of dispatches the loop chose
+    for a deterministic chunk stream, its state is the plain fold of the
+    same programs over that sequence — merged ingests equal sequential
+    ones, keys are the eager chain, beta follows ingestion, order is kept.
+    The stream crosses the warmup boundary (merged warmup ingest, staged
+    fused singles); uncapped it ends in replay-only steps, and under a
+    replay-ratio cap (12 steps for 384 transitions) chunks the cap turns
+    away after warmup are absorbed ingest-only."""
     msgs = _cartpole_chunk_messages(24)      # 24 * 16 = 384 transitions
-    n = 40                                   # > post-warm chunk count:
-    #                                          tail steps sample replay only
-    serial, t_serial = _run_trainer(False, msgs, n)
-    piped, t_piped = _run_trainer(True, msgs, n)
+    loop = _parity_trainer(msgs, train_ratio)
+    sequence = train_recorded(loop, total_steps=total_steps, max_seconds=120)
 
-    assert t_serial.steps_rate.total == t_piped.steps_rate.total == n
-    assert t_serial.ingested == t_piped.ingested == 384
-    flat_s = jax.tree_util.tree_leaves_with_path(serial)
-    flat_p = dict(jax.tree_util.tree_leaves_with_path(piped))
-    assert flat_s and len(flat_s) == len(flat_p)
-    for path, leaf in flat_s:
-        assert np.array_equal(np.asarray(leaf), np.asarray(flat_p[path])), \
-            f"params diverged at {jax.tree_util.keystr(path)}"
-    # the pipelined run must actually have staged slots (not silently
-    # fallen back to the serial drain)
-    stats = t_piped._pipeline_last_stats
+    assert loop.steps_rate.total == total_steps
+    kinds = [kind for kind, _n in sequence]
+    assert {"ingest", "fused"} <= set(kinds)
+    if train_ratio is None:
+        assert loop.ingested == 384 and "train" in kinds    # replay-only tail
+    else:
+        assert "ingest" in kinds[kinds.index("fused"):], \
+            "the cap never turned a warm chunk away"
+    # the run staged slots and merged its warmup fill
+    stats = loop._pipeline_last_stats
     assert stats is not None and stats["slots"] > 0
     assert stats["merged_chunks"] >= 2, \
         "warmup fill never exercised the merged-ingest path"
+    assert max(n for kind, n in sequence if kind == "ingest") > 16
 
-
-def test_trainer_pipeline_gate():
-    """ingest_pipeline=False keeps the serial drain (the A/B lane);
-    default-on covers single-shard AND dp>1 (the sharded plan's parity
-    pin lives in tests/test_sharded_pipeline.py)."""
-    from apex_tpu.training.apex import ApexTrainer
-
-    cfg = small_test_config()
-    cfg_off = cfg.replace(learner=dataclasses.replace(
-        cfg.learner, ingest_pipeline=False))
-    t = ApexTrainer(cfg_off, pool=ScriptedPool([]))
-    assert not t._use_pipeline()
-    t2 = ApexTrainer(cfg, pool=ScriptedPool([]))
-    assert t2._use_pipeline()
-    cfg_dp = cfg.replace(learner=dataclasses.replace(
-        cfg.learner, mesh_shape=(4,), batch_size=32, ingest_chunk=32))
-    t3 = ApexTrainer(cfg_dp, pool=ScriptedPool([]))
-    assert t3.n_dp == 4 and t3._use_pipeline()
+    reference = _parity_trainer(msgs, train_ratio)
+    fold_reference(reference, sequence)
+    assert_same_learner(loop, reference)

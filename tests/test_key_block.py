@@ -22,11 +22,11 @@ from apex_tpu.training.ingest_pipeline import KEY_BLOCK, KeyBlocks
 from tests.test_ingest_pipeline import ScriptedPool, _cartpole_chunk_messages
 
 
-def _trainer(msgs=(), pipeline: bool = False, **kw) -> ApexTrainer:
+def _trainer(msgs=(), **kw) -> ApexTrainer:
     cfg = small_test_config(capacity=256, batch_size=16, n_actors=1)
     cfg = cfg.replace(
         replay=dataclasses.replace(cfg.replay, warmup=64),
-        learner=dataclasses.replace(cfg.learner, ingest_pipeline=pipeline,
+        learner=dataclasses.replace(cfg.learner,
                                     target_update_interval=20))
     return ApexTrainer(cfg, pool=ScriptedPool(list(msgs)),
                        publish_min_seconds=10.0, respawn_workers=False, **kw)
@@ -37,7 +37,7 @@ def _bits(key) -> list:
 
 
 def _eager(chain, n: int):
-    """``n`` steps of the serial loop's chain: the keys, the chain after."""
+    """``n`` steps of the eager chain: the keys, the chain after."""
     keys = []
     for _ in range(n):
         chain, k = jax.random.split(chain)
@@ -95,7 +95,7 @@ def test_dispatch_keys_across_a_refill_are_the_eager_chain():
 @pytest.mark.parametrize("outside", ["evaluate_split", "assigned_key"])
 def test_key_assigned_from_outside_drops_the_block(outside):
     """Between two dispatches something else moves ``self.key``: the chain
-    goes on from the assigned key, as the serial loop's would."""
+    goes on from the assigned key, as the eager chain would."""
     tr = _trainer()
     first = [tr._dispatch_key() for _ in range(5)]
     if outside == "evaluate_split":                 # evaluate()'s own line
@@ -135,30 +135,26 @@ def test_checkpoint_saved_mid_block_restores_to_the_same_next_key(tmp_path):
     assert back.steps_rate.total == 7
     assert [_bits(back._dispatch_key()) for _ in range(3)] == next_keys
     assert _bits(back.key) == _bits(tr.key)
-    # and both are where the serial chain stands after as many dispatches
+    # and both are where the eager chain stands after as many dispatches
     _, chain = _eager(jax.random.split(jax.random.key(tr.cfg.env.seed))[0],
                       tr._blocks().served)
     assert _bits(tr.key) == _bits(chain)
 
 
-@pytest.mark.parametrize("pipeline", [False, True],
-                         ids=["serial", "pipelined"])
-def test_loop_and_harness_operands_share_one_program(pipeline):
+def test_loop_and_harness_operands_share_one_program():
     """The benchmark drives ``_fused`` / ``_train`` itself with a
     ``jax.random.key``-derived key and ``jnp.float32(beta)`` and then holds
     the window's loop to the same compiled program (``one_program_each``)."""
     msgs = _cartpole_chunk_messages(12)
-    tr = _trainer(msgs[:6], pipeline=pipeline)
+    tr = _trainer(msgs[:6])
     beta = jnp.float32(tr.cfg.replay.beta)
-    # a chunk's priorities as that loop hands them over on this backend
-    # (the harness stages as the plan it runs does)
-    stage = (lambda p: np.asarray(p, np.float32)) if pipeline \
-        else jnp.asarray
     for i, msg in enumerate(msgs[6:]):
         key = jax.random.fold_in(jax.random.key(5), 1000 + i)
+        # a chunk's priorities as the staging thread hands them over on
+        # this backend (the harness stages as the plan it runs does)
         tr.train_state, tr.replay_state, _ = tr._fused(
             tr.train_state, tr.replay_state, msg["payload"],
-            stage(msg["priorities"]), key, beta)
+            np.asarray(msg["priorities"], np.float32), key, beta)
         tr.ingested += int(msg["n_trans"])
     tr.train_state, tr.replay_state, _ = tr._train(
         tr.train_state, tr.replay_state,

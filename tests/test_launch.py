@@ -74,13 +74,13 @@ def test_compile_cache_defaults_to_a_fixed_in_tree_path():
 # -- peaks keyed by device_kind ----------------------------------------------
 
 def test_peak_lookup_raises_on_an_unknown_device():
-    from apex_tpu.utils import profiling
+    from benchmark import costs
 
-    assert profiling.device_peak_flops("TPU v5 lite") == 197e12
+    assert costs.peaks_for("TPU v5 lite")["flops_per_s"] == 197e12
     with pytest.raises(KeyError, match="TPU v9000"):
-        profiling.device_peak_flops("TPU v9000")
-    with pytest.raises(KeyError, match="cpu"):    # this process's device
-        profiling.device_peak_flops()
+        costs.peaks_for("TPU v9000")
+    with pytest.raises(KeyError, match="cpu"):    # what a CPU run reports
+        costs.peaks_for("cpu")
 
 
 # -- chip_smoke.py CPU rehearsal ---------------------------------------------

@@ -154,20 +154,3 @@ def test_dqn_learns_cartpole():
     score = trainer.evaluate(episodes=5, epsilon=0.0, max_steps=500)
     assert last > 1.5 * first, f"no training-curve improvement: {first}->{last}"
     assert score > 40.0, f"eval reward {score} <= 40: not learning"
-
-
-def test_profiling_flops_and_mfu(key):
-    """XLA cost analysis drives the MFU metric (A1)."""
-    import jax
-
-    from apex_tpu.utils import profiling
-
-    f = jax.jit(lambda a, b: a @ b)
-    a = jnp.ones((64, 64), jnp.float32)
-    flops = profiling.flops_per_call(f, a, a)
-    if flops is not None:                  # backend-dependent availability
-        assert flops >= 2 * 64 ** 3 * 0.9
-        util = profiling.mfu(flops, calls_per_sec=1000.0,
-                             peak_flops=1e12)
-        assert 0 < util < 1
-    assert profiling.mfu(None, 10.0, peak_flops=1e12) is None

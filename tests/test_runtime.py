@@ -175,6 +175,33 @@ def test_remote_pool_reports_silent_peers():
         pool.receiver.stop()
 
 
+_CONFIG_CLASSES = ("ReplayConfig", "LearnerConfig", "ActorConfig",
+                   "EnvConfig", "R2D2Config", "AQLConfig", "CommsConfig",
+                   "ApexConfig", "RoleIdentity")
+
+
+@pytest.mark.parametrize("cls_name", _CONFIG_CLASSES)
+def test_every_config_field_has_a_reader(cls_name):
+    """A field of ``config.py`` that nothing in ``apex_tpu/`` names is a
+    switch wired to nothing: it goes, or gets its reader."""
+    import re
+    from pathlib import Path
+
+    import apex_tpu
+    from apex_tpu import config
+
+    package = Path(apex_tpu.__file__).parent
+    sources = [p.read_text() for p in sorted(package.rglob("*.py"))
+               if p != package / "config.py"]
+    assert set(_CONFIG_CLASSES) == {
+        name for name, obj in vars(config).items()
+        if dataclasses.is_dataclass(obj) and isinstance(obj, type)}
+    unread = [f.name for f in dataclasses.fields(getattr(config, cls_name))
+              if not any(re.search(rf"\b{f.name}\b", text)
+                         for text in sources)]
+    assert not unread, f"{cls_name}: no reader in apex_tpu/ for {unread}"
+
+
 def test_cli_parser_roles_and_env_twins(monkeypatch):
     from apex_tpu.runtime.cli import (build_parser, config_from_args,
                                       identity_from_args)

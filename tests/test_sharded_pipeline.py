@@ -1,9 +1,10 @@
 """Sharded (dp>1) ingest pipeline: per-shard group-merge bit-parity, the
 key-prefetcher chain contract, group-granular staging mechanics, and the
-acceptance pin — dp=4 pipelined-vs-serial bit-parity of params AND
-per-shard replay tree state on the same chunk stream, in a
-subprocess-spawned pytest on a ``--xla_force_host_platform_device_count=4``
-CPU mesh (``apex_tpu/training/ingest_pipeline.py`` sharded mode)."""
+acceptance pin — the dp=4 loop's train state AND per-shard replay state
+equal the plain fold of its own dispatch sequence over the same chunk
+stream, in a subprocess-spawned pytest on a
+``--xla_force_host_platform_device_count=4`` CPU mesh
+(``apex_tpu/training/ingest_pipeline.py`` sharded mode)."""
 
 import copy
 import dataclasses
@@ -220,79 +221,59 @@ def test_sharded_pipeline_behind_pauses_draining():
         pipe.stop()
 
 
-# -- the acceptance pin: dp=4 pipelined vs serial, bit for bit --------------
+# -- the acceptance pin: dp=4, the fold of the loop's own dispatches ---------
 
 _INNER_ENV = "APEX_DP_PARITY_INNER"
 
 
-def _run_dp_trainer(pipeline_on: bool, msgs, total_steps: int):
+def _dp_trainer(msgs):
     from apex_tpu.training.apex import ApexTrainer
 
     cfg = small_test_config(capacity=256, batch_size=16, n_actors=1)
     cfg = cfg.replace(
         replay=dataclasses.replace(cfg.replay, warmup=256),
         learner=dataclasses.replace(cfg.learner, mesh_shape=(4,),
-                                    ingest_pipeline=pipeline_on,
                                     target_update_interval=20))
-    pool = ScriptedPool(copy.deepcopy(msgs))
-    trainer = ApexTrainer(cfg, pool=pool, publish_min_seconds=10.0,
-                          respawn_workers=False)
-    trainer.train(total_steps=total_steps, max_seconds=300,
-                  log_every=10 ** 9)
-    return trainer
+    return ApexTrainer(cfg, pool=ScriptedPool(copy.deepcopy(msgs)),
+                       publish_min_seconds=10.0, respawn_workers=False)
 
 
 @pytest.mark.skipif(os.environ.get(_INNER_ENV) != "1",
-                    reason="spawned by test_dp4_pipelined_vs_serial_"
-                           "bit_parity on a 4-device mesh")
+                    reason="spawned by test_dp4_loop_state_is_the_fold_of_"
+                           "its_own_dispatches on a 4-device mesh")
 def test_dp4_parity_inner():
-    """Runs inside the subprocess pytest: the SAME deterministic chunk
-    stream through the dp=4 pipelined and serial trainer loops must give
-    bit-identical params, per-shard replay tree state, AND post-train
-    key chain.  The stream crosses the warmup boundary (merged
-    round-robin groups), continues through staged trainable groups, and
-    ends in replay-only catch-up steps (prefetched keys past the data)."""
+    """Runs inside the subprocess pytest: whatever sequence of dispatches
+    the dp=4 loop chose for a deterministic chunk stream, its train state,
+    per-shard replay state, counters AND post-train key chain are the
+    plain fold of the same sharded programs over that sequence
+    (``tests/test_ingest_pipeline.fold_reference``: round-robin groups one
+    by one, raw chain keys).  The stream crosses the warmup boundary
+    (merged round-robin groups), continues through staged trainable groups,
+    and ends in replay-only catch-up steps (prefetched keys past the
+    data)."""
+    from tests.test_ingest_pipeline import (assert_same_learner,
+                                            fold_reference, train_recorded)
     assert jax.device_count() == 4
 
     msgs = _cartpole_chunk_messages(80)      # 20 groups of 4 x 16 trans
     n = 30                                   # > post-warm group count
-    t_serial = _run_dp_trainer(False, msgs, n)
-    t_piped = _run_dp_trainer(True, msgs, n)
+    loop = _dp_trainer(msgs)
+    sequence = train_recorded(loop, total_steps=n, max_seconds=300)
 
-    assert t_serial.steps_rate.total == t_piped.steps_rate.total == n
-    assert t_serial.ingested == t_piped.ingested == 80 * K
-
-    ps = jax.device_get(t_serial.train_state.params)
-    pp = jax.device_get(t_piped.train_state.params)
-    flat_s = jax.tree_util.tree_leaves_with_path(ps)
-    flat_p = dict(jax.tree_util.tree_leaves_with_path(pp))
-    assert flat_s and len(flat_s) == len(flat_p)
-    for path, leaf in flat_s:
-        assert np.array_equal(np.asarray(leaf), np.asarray(flat_p[path])), \
-            f"params diverged at {jax.tree_util.keystr(path)}"
-
-    # per-shard replay trees: leading axis = the 4 shards
-    for name in ("frames", "action", "reward", "discount", "obs_ids",
-                 "next_ids", "frame_epoch", "sum_tree", "min_tree",
-                 "pos", "f_epoch", "size", "max_priority"):
-        va = np.asarray(getattr(t_serial.replay_state, name))
-        vb = np.asarray(getattr(t_piped.replay_state, name))
-        assert va.shape[0] == 4, f"replay field {name} lost its shard axis"
-        assert np.array_equal(va, vb), f"replay field {name} diverged"
-
-    # the key-prefetcher chain left self.key exactly where serial did
-    np.testing.assert_array_equal(
-        np.asarray(jax.random.key_data(t_serial.key)),
-        np.asarray(jax.random.key_data(t_piped.key)))
-
-    # the pipelined run actually staged (merged warmup groups included)
-    stats = t_piped._pipeline_last_stats
+    assert loop.steps_rate.total == n and loop.ingested == 80 * K
+    assert {"ingest", "fused", "train"} <= {kind for kind, _n in sequence}
+    # the run staged slots and merged its warmup groups
+    stats = loop._pipeline_last_stats
     assert stats is not None and stats["slots"] > 0
     assert stats["merged_chunks"] >= 2, \
         "warmup fill never exercised the sharded merged-group path"
 
+    reference = _dp_trainer(msgs)
+    fold_reference(reference, sequence)
+    assert_same_learner(loop, reference, replay_shards=4)
 
-def test_dp4_pipelined_vs_serial_bit_parity():
+
+def test_dp4_loop_state_is_the_fold_of_its_own_dispatches():
     """Acceptance pin, tier-1-safe: spawn the inner parity test in a
     fresh pytest on a CPU backend forced to exactly 4 devices — the
     sharded plan under the precise emulation geometry the issue names

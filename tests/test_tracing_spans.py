@@ -169,14 +169,13 @@ def _scripted_messages(n: int = 24) -> list[dict]:
     return msgs
 
 
-def _small_trainer(msgs, pipeline: bool, scan_steps: int = 1):
+def _small_trainer(msgs, scan_steps: int = 1):
     from apex_tpu.training.apex import ApexTrainer
 
     cfg = small_test_config(capacity=256, batch_size=8, n_actors=1)
     cfg = cfg.replace(
         replay=dataclasses.replace(cfg.replay, warmup=32),
         learner=dataclasses.replace(cfg.learner, target_update_interval=50,
-                                    ingest_pipeline=pipeline,
                                     scan_steps=scan_steps))
     return ApexTrainer(cfg, pool=ScriptedPool(msgs),
                        publish_min_seconds=30.0, respawn_workers=False)
@@ -191,7 +190,7 @@ def traced_run(tmp_path_factory):
     mp.setenv("APEX_TRACE_FLUSH_S", "0")            # no flusher thread
     obs_trace.reset_for_tests()
     try:
-        trainer = _small_trainer(_scripted_messages(), pipeline=False)
+        trainer = _small_trainer(_scripted_messages())
         trainer.train(total_steps=12, max_seconds=120, log_every=4)
         chrome = obs_trace.get_ring().to_chrome()
     finally:
@@ -231,9 +230,11 @@ def test_loop_iter_spans_hold_their_children(traced_run):
         parent = by_it[it]
         assert parent["ts"] - 1 <= ev["ts"]
         assert ev["ts"] + ev["dur"] <= parent["ts"] + parent["dur"] + 1
+    # which passes trained alone depends on when the ring ran dry
     programs = {ev["args"]["program"] for ev in children
                 if ev["name"] == "dispatch"}
-    assert programs == {"jit_fused_step", "jit_ingest"}
+    assert {"jit_fused_step", "jit_ingest"} <= programs \
+        <= {"jit_fused_step", "jit_ingest", "jit_train_step"}
 
 
 def _host_gap_leaves_out_the_dispatch_interval(events: list[dict]) -> None:
@@ -288,7 +289,7 @@ def test_pipelined_loop_spans_and_train_alone_kind(tmp_path, monkeypatch):
     monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
     obs_trace.reset_for_tests()
     try:
-        trainer = _small_trainer(_scripted_messages(12), pipeline=True)
+        trainer = _small_trainer(_scripted_messages(12))
         trainer.train(total_steps=30, max_seconds=120, log_every=10)
         chrome = obs_trace.get_ring().to_chrome()
     finally:
@@ -313,8 +314,7 @@ def test_scan_dispatch_splits_its_keys_outside_the_gap(tmp_path, monkeypatch):
     monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
     obs_trace.reset_for_tests()
     try:
-        trainer = _small_trainer(_scripted_messages(), pipeline=False,
-                                 scan_steps=2)
+        trainer = _small_trainer(_scripted_messages(), scan_steps=2)
         trainer.train(total_steps=12, max_seconds=120, log_every=4)
         chrome = obs_trace.get_ring().to_chrome()
     finally:
@@ -402,7 +402,7 @@ def _lowered(family: str, program: str):
     beta = jnp.float32(0.4)
     if family == "dqn":
         msgs = _scripted_messages(2)
-        trainer = _small_trainer([], pipeline=False)
+        trainer = _small_trainer([])
         ts, rs = trainer.train_state, trainer.replay_state
         if program == "fused":
             return trainer._fused.lower(
@@ -492,7 +492,7 @@ def test_learner_obs_lag_steps_with_gaps_and_an_evicted_version():
 
 
 def test_publish_records_the_step_the_version_left_at():
-    trainer = _small_trainer([], pipeline=False)
+    trainer = _small_trainer([])
     trainer._obs = LearnerObs()
     trainer.steps_rate.tick(40)
     trainer._publish()
