@@ -440,13 +440,20 @@ class IngestPipeline:
         self.put_device = put_device
         self._ring: queue_lib.Queue = queue_lib.Queue(maxsize=self.depth)
         self._stop = threading.Event()
-        # set whenever the staging thread is parked with NOTHING in hand:
-        # poll_slot treats "ring empty + staging idle" as dry and may
-        # return None; while work is in flight it waits for the slot
-        # instead of letting the trainer burn a replay-only step on data
-        # that is milliseconds away
+        # clear while the staging thread HOLDS a chunk (polled, being
+        # merged, staged or put): poll_slot then waits for the slot that
+        # is on its way rather than let the trainer burn a replay-only
+        # step on data that is milliseconds away.  Set while it waits on
+        # an empty transport: "ring empty + staging idle" is dry, and
+        # poll_slot says so at once.  A pool whose poll PRODUCES (the
+        # on-device rollout) is polled with the flag as the last pass left
+        # it: clear after a put, so the loop waits for the rollout's
+        # chunks; set after a ``behind`` pause.  ``_wake`` guards the flag
+        # and is notified on every put, so poll_slot sleeps on the event
+        # that ends its wait, never on a quantum.
         self._idle = threading.Event()
         self._idle.set()
+        self._wake = threading.Condition()
         self._error: BaseException | None = None
         self._pub_lock = threading.Lock()
         self._pub: tuple | None = None
@@ -456,7 +463,10 @@ class IngestPipeline:
         self._staged_steps = 0          # planned train steps not yet consumed
         self.stats = {"slots": 0, "scan_slots": 0, "merged_slots": 0,
                       "merged_chunks": 0, "publishes": 0,
-                      "batch_slots": 0, "writebacks": 0}
+                      "batch_slots": 0, "writebacks": 0,
+                      # poll_slot, on the trainer's thread: answers of None
+                      # without a wait; calls that waited, and their seconds
+                      "dry_polls": 0, "waited_polls": 0, "poll_wait_s": 0.0}
         # obs plane: staging-thread activity lands on its own track of
         # the learner's trace ring (host clocks only — J006/J010 clean)
         self.ring = get_ring()
@@ -514,29 +524,56 @@ class IngestPipeline:
 
     def poll_slot(self, timeout: float = 0.0) -> StagedSlot | None:
         """Next ready slot in stream order, or None when the pipeline is
-        dry (no slot staged, none in flight, and the pool poll came up
-        empty) and ``timeout`` has elapsed."""
+        dry: the ring is empty, the staging thread holds no chunk (it
+        waits on an empty transport, or pauses ``behind``) and ``timeout``
+        has run out.  Nothing staged and nothing on its way is answered
+        at once, for ``timeout=0`` with no wait at all; only while a slot
+        is on its way, or a caller's ``timeout`` still runs, does the
+        call wait, and then on the put or on the staging thread going
+        idle (``_wake``)."""
         deadline = time.monotonic() + timeout
-        while True:
-            try:
-                # blocking get: a condition-variable wakeup on put, not a
-                # sleep-quantum poll (matters on few-core hosts where the
-                # staging and consumer threads share the GIL)
-                slot = self._ring.get(timeout=0.005)
-            except queue_lib.Empty:
+        waited = 0.0
+        try:
+            while True:
+                try:
+                    slot = self._ring.get_nowait()
+                    break
+                except queue_lib.Empty:
+                    pass
                 if self._error is not None:
                     raise RuntimeError(
                         "ingest pipeline staging thread died"
                     ) from self._error
                 if self._stop.is_set():
                     return None
-                if self._idle.is_set() and time.monotonic() >= deadline:
-                    return None
-                continue
-            with self._ahead_lock:
-                self._staged_ahead -= slot.n_trans
-                self._staged_steps -= slot.planned_steps
-            return slot
+                t0 = time.monotonic()
+                with self._wake:
+                    if not self._ring.empty():
+                        continue
+                    # a held chunk ends in a put, which notifies; the cap
+                    # only bounds how late a stop() from another thread
+                    # is seen
+                    wait = deadline - t0 if self._idle.is_set() else 0.1
+                    if wait <= 0:
+                        if not waited:
+                            self.stats["dry_polls"] += 1
+                        return None
+                    self._wake.wait(wait)
+                waited += time.monotonic() - t0
+        finally:
+            if waited:
+                self.stats["waited_polls"] += 1
+                self.stats["poll_wait_s"] += waited
+        with self._ahead_lock:
+            self._staged_ahead -= slot.n_trans
+            self._staged_steps -= slot.planned_steps
+        return slot
+
+    def _set_idle(self) -> None:
+        with self._wake:
+            if not self._idle.is_set():
+                self._idle.set()
+                self._wake.notify_all()
 
     # -- staging thread ----------------------------------------------------
 
@@ -573,13 +610,20 @@ class IngestPipeline:
                 if st.behind:
                     # replay-ratio floor: pause draining so the bounded
                     # worker queue backpressures the actor fleet
-                    self._idle.set()
+                    self._set_idle()
                     time.sleep(0.002)
                     continue
-                msgs = self._poll(1, timeout=self.poll_timeout)
+                # what is there is taken without a wait, and only a timed
+                # wait on an empty transport is idle.  ``_idle`` is not
+                # touched before this poll: a pool that produces inside it
+                # (AnakinPool launches a rollout, whatever ``timeout``)
+                # keeps the flag the last pass left
+                msgs = self._poll(1, timeout=0)
                 if not msgs:
-                    self._idle.set()
-                    continue
+                    self._set_idle()
+                    msgs = self._poll(1, timeout=self.poll_timeout)
+                    if not msgs:
+                        continue
                 self._idle.clear()
                 # named by the slot it built, known only afterwards: a
                 # ring event written after the fact (no annotation)
@@ -591,7 +635,9 @@ class IngestPipeline:
                 self._put(slot)
         except BaseException as exc:      # surface to poll_slot, loudly
             self._error = exc
-            self._idle.set()
+            with self._wake:             # whatever the flag read before
+                self._idle.set()
+                self._wake.notify_all()
             return
         # clean stop: the shards are waiting on the final write-backs
         # (strict ordering) — flush what the trainer queued before stop()
@@ -770,6 +816,8 @@ class IngestPipeline:
         while not self._stop.is_set():
             try:
                 self._ring.put(slot, timeout=0.1)
+                with self._wake:
+                    self._wake.notify_all()
                 return
             except queue_lib.Full:
                 # param publishes (and shard write-backs — a strict shard

@@ -4,6 +4,7 @@ order preservation, and bounded-ring backpressure
 
 import copy
 import dataclasses
+import threading
 import time
 
 import jax
@@ -235,6 +236,223 @@ def test_pipeline_staging_error_surfaces_to_consumer():
         pipe.stop()
 
 
+# -- the hand-off: a dry pipeline says so at once, a held chunk is waited for --
+
+class TransportPool(ScriptedPool):
+    """A host-fed pool's transport: ``timeout=0`` answers at once, a timed
+    poll waits for a delivery as long as it was given (``ActorPool``'s
+    chunk queue).  Notes, for each timed wait, whether the pipeline was
+    idle when it began."""
+
+    def __init__(self, msgs=()):
+        super().__init__([])
+        self._arrived = threading.Event()
+        self._todo = list(msgs)
+        self.pipe = None
+        self.idle_at_timed_wait = []
+
+    def deliver(self, n=1):
+        self._msgs.extend(self._todo[:n])
+        del self._todo[:n]
+        self._arrived.set()
+
+    def poll_chunks(self, max_chunks, timeout=0.0):
+        if timeout and not self._msgs:
+            self.idle_at_timed_wait.append(self.pipe._idle.is_set())
+            self._arrived.wait(timeout)
+        self._arrived.clear()
+        return super().poll_chunks(max_chunks)
+
+
+class ProducingPool(ScriptedPool):
+    """``AnakinPool``'s stand-in: the poll itself produces the chunk and
+    blocks meanwhile, whatever ``timeout`` says (there a rollout on the
+    device, here a semaphore the test releases)."""
+
+    def __init__(self, msgs):
+        super().__init__(msgs)
+        self.go = threading.Semaphore(0)
+        self.producing = threading.Event()
+
+    def poll_chunks(self, max_chunks, timeout=0.0):
+        self.producing.set()
+        try:
+            if not self.go.acquire(timeout=30.0):
+                return []
+        finally:
+            self.producing.clear()
+        return super().poll_chunks(1)
+
+
+def _transport_pipeline(pool, state_fn=PipelineState):
+    pipe = IngestPipeline(pool, state_fn=state_fn)
+    pool.pipe = pipe
+    return pipe
+
+
+def _wait_until(predicate, seconds=5.0):
+    deadline = time.monotonic() + seconds
+    while not predicate() and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert predicate()
+
+
+def test_dry_pipeline_answers_none_without_a_timed_get():
+    """Nothing staged, nothing in hand, nothing sent: ``poll_slot(0)`` is
+    told so at once — the ring is only ever tried without a wait."""
+    pool = TransportPool()
+    pipe = _transport_pipeline(pool)
+    gets = []
+    ring_get = pipe._ring.get
+
+    def get(block=True, timeout=None):
+        gets.append((block, timeout))
+        return ring_get(block, timeout)
+    pipe._ring.get = get
+    pipe.start()
+    try:
+        _wait_until(lambda: len(pool.idle_at_timed_wait) >= 2)
+        for _ in range(50):
+            assert pipe.poll_slot(timeout=0) is None
+        assert gets and all(block is False for block, _t in gets)
+        assert pipe.stats["dry_polls"] == 50
+        assert pipe.stats["waited_polls"] == 0
+        assert pipe.stats["poll_wait_s"] == 0.0
+    finally:
+        pipe.stop()
+
+
+def test_idle_through_the_transport_wait_and_clear_while_a_chunk_is_held():
+    """``_idle`` is set through every timed wait on the empty transport
+    and clear from a chunk's arrival (staged, put) until the thread waits
+    again."""
+    pool = TransportPool(_random_chunk_messages(seed=5, n_chunks=2))
+    pipe = _transport_pipeline(pool)
+    held = []
+    stage, ring_put = pipe._stage, pipe._ring.put
+
+    def staged(x):
+        held.append(("stage", pipe._idle.is_set()))
+        return stage(x)
+
+    def put(slot, timeout=None):
+        held.append(("put", pipe._idle.is_set()))
+        return ring_put(slot, timeout=timeout)
+    pipe._stage, pipe._ring.put = staged, put
+    pipe.start()
+    try:
+        for _ in range(2):
+            waits = len(pool.idle_at_timed_wait)
+            _wait_until(lambda: len(pool.idle_at_timed_wait) > waits)
+            pool.deliver()                  # arrives inside a timed wait
+            slot = pipe.poll_slot(timeout=5.0)
+            assert slot is not None and slot.kind == "single"
+            waits = len(pool.idle_at_timed_wait)
+            _wait_until(lambda: len(pool.idle_at_timed_wait) > waits)
+            assert pipe.poll_slot(timeout=0) is None
+        assert pool.idle_at_timed_wait and all(pool.idle_at_timed_wait)
+        assert {what for what, _idle in held} == {"stage", "put"}
+        assert not any(idle for _what, idle in held)
+    finally:
+        pipe.stop()
+
+
+@pytest.mark.parametrize("after_pause", [False, True],
+                         ids=["after_a_put", "after_a_behind_pause"])
+def test_a_producing_poll_keeps_the_flag_the_last_pass_left(after_pause):
+    """A pool that produces inside its poll is polled with ``_idle``
+    untouched.  After a slot was put and taken the loop is NOT told "dry"
+    while the next poll produces: it waits, and gets the slot on the put.
+    After a ``behind`` pause the flag is set, and the loop trains alone
+    meanwhile (the on-device cell's replay ratio rests on both)."""
+    pool = ProducingPool(_random_chunk_messages(seed=6, n_chunks=2))
+    state = {"behind": False}
+    pipe = IngestPipeline(
+        pool, state_fn=lambda: PipelineState(behind=state["behind"]))
+    pipe.start()
+    try:
+        pool.go.release()
+        assert pipe.poll_slot(timeout=5.0) is not None
+        if after_pause:
+            state["behind"] = True
+            pool.go.release()       # the poll already under way comes home
+            assert pipe.poll_slot(timeout=5.0) is not None
+            _wait_until(pipe._idle.is_set)
+            state["behind"] = False
+            pool._msgs = _random_chunk_messages(seed=8, n_chunks=1)
+        _wait_until(pool.producing.is_set)
+
+        if after_pause:
+            before = pipe.stats["dry_polls"]
+            assert pipe.poll_slot(timeout=0) is None
+            assert pipe.stats["dry_polls"] == before + 1
+            pool.go.release()
+            assert pipe.poll_slot(timeout=5.0) is not None
+            return
+        got = []
+        waiter = threading.Thread(
+            target=lambda: got.append(pipe.poll_slot(timeout=0)))
+        waiter.start()
+        waiter.join(timeout=0.3)
+        assert waiter.is_alive() and not got, \
+            "told dry while the poll produced"
+        pool.go.release()
+        waiter.join(timeout=5.0)
+        assert not waiter.is_alive()
+        assert got and got[0] is not None
+        assert pipe.stats["waited_polls"] >= 1
+        assert pipe.stats["poll_wait_s"] > 0.0
+        assert pipe.stats["dry_polls"] == 0
+    finally:
+        pool.go.release()
+        pipe.stop()
+
+
+def test_a_timeout_on_a_dry_pipeline_runs_to_its_deadline():
+    pool = TransportPool()
+    pipe = _transport_pipeline(pool)
+    pipe.start()
+    try:
+        t0 = time.monotonic()
+        assert pipe.poll_slot(timeout=0.2) is None
+        took = time.monotonic() - t0
+        assert 0.2 <= took < 2.0
+        assert pipe.stats["waited_polls"] == 1
+        assert pipe.stats["dry_polls"] == 0
+        assert 0.1 < pipe.stats["poll_wait_s"] <= took
+    finally:
+        pipe.stop()
+
+
+@pytest.mark.parametrize("timeout", [0, 30.0], ids=["nowait", "waiting"])
+def test_a_staging_error_raises_from_poll_slot(timeout):
+    """Dead before the call, ``poll_slot(0)`` raises where it would have
+    said None; dying under a caller that waits wakes it."""
+    fuse = threading.Event()
+
+    class ExplodingPool(TransportPool):
+        def poll_chunks(self, max_chunks, timeout=0.0):
+            if fuse.is_set():
+                raise RuntimeError("decode blew up")
+            return super().poll_chunks(max_chunks, timeout)
+
+    pool = ExplodingPool()
+    pipe = _transport_pipeline(pool)
+    pipe.start()
+    try:
+        if timeout:
+            threading.Timer(0.1, fuse.set).start()
+        else:
+            fuse.set()
+            _wait_until(lambda: pipe._error is not None)
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError, match="staging thread died"):
+            pipe.poll_slot(timeout=timeout)
+        assert time.monotonic() - t0 < 10.0
+    finally:
+        pipe.stop()
+
+
 # -- end-to-end: the loop's state is the plain fold of its own dispatches -----
 
 def train_recorded(trainer, **train_kw) -> list:
@@ -316,7 +534,30 @@ def assert_same_learner(a, b, replay_shards: int | None = None) -> None:
                           np.asarray(jax.random.key_data(b.key)))
 
 
-def _parity_trainer(msgs, train_ratio=None):
+class PacedPool(ScriptedPool):
+    """A fleet slower than the learner: a chunk no sooner than 20 ms after
+    the one before, a timed poll waiting as long as it was given."""
+
+    every = 0.02
+
+    def __init__(self, msgs):
+        super().__init__(msgs)
+        self._last = 0.0
+
+    def poll_chunks(self, max_chunks, timeout=0.0):
+        due = self._last + self.every - time.monotonic()
+        if due > 0:
+            if due > timeout:
+                time.sleep(timeout)
+                return []
+            time.sleep(due)
+        out = super().poll_chunks(1)
+        if out:
+            self._last = time.monotonic()
+        return out
+
+
+def _parity_trainer(msgs, train_ratio=None, pool_cls=ScriptedPool):
     from apex_tpu.training.apex import ApexTrainer
 
     cfg = small_test_config(capacity=256, batch_size=16, n_actors=1)
@@ -324,7 +565,7 @@ def _parity_trainer(msgs, train_ratio=None):
         replay=dataclasses.replace(cfg.replay, warmup=64),
         learner=dataclasses.replace(cfg.learner,
                                     target_update_interval=20))
-    return ApexTrainer(cfg, pool=ScriptedPool(copy.deepcopy(msgs)),
+    return ApexTrainer(cfg, pool=pool_cls(copy.deepcopy(msgs)),
                        publish_min_seconds=10.0, respawn_workers=False,
                        train_ratio=train_ratio)
 
@@ -380,5 +621,28 @@ def test_loop_state_is_the_fold_of_its_own_dispatches(train_ratio,
     assert max(n for kind, n in sequence if kind == "ingest") > 16
 
     reference = _parity_trainer(msgs, train_ratio)
+    fold_reference(reference, sequence)
+    assert_same_learner(loop, reference)
+
+
+def test_loop_trains_alone_between_the_chunks_of_a_slow_fleet():
+    """A chunk every 20 ms and a warm loop that needs a tenth of that a
+    pass: told "dry" at once, it trains alone between one chunk and the
+    next (``f t t .. f``), and its state is still the plain fold of the
+    sequence it chose."""
+    msgs = _cartpole_chunk_messages(24)
+    loop = _parity_trainer(msgs, pool_cls=PacedPool)
+    sequence = train_recorded(loop, total_steps=150, max_seconds=120)
+
+    assert loop.steps_rate.total == 150
+    kinds = [kind for kind, _n in sequence]
+    fused = [i for i, kind in enumerate(kinds) if kind == "fused"]
+    assert len(fused) >= 2
+    assert "train" in kinds[fused[0]:fused[-1]], \
+        "never trained alone between two chunks"
+    stats = loop._pipeline_last_stats
+    assert stats["dry_polls"] > 0
+
+    reference = _parity_trainer(msgs)
     fold_reference(reference, sequence)
     assert_same_learner(loop, reference)
