@@ -73,8 +73,8 @@ class LearnerConfig:
     # TPU knobs
     compute_dtype: str = "bfloat16"  # MXU-native matmul dtype; params stay f32
     # The Q-network's torso (--torso): "dueling" is the reference's
-    # Nature-CNN / MLP dueling net; any other name is a preset of
-    # apex_tpu/models/glm4_moe_lite.py (apex_tpu.models.make_q_network)
+    # Nature-CNN / MLP dueling net; any other name is a preset of a
+    # token-torso family under apex_tpu/models/ (make_q_network finds it)
     torso: str = "dueling"
     ingest_chunk: int = 512          # transitions folded into each fused step
     mesh_shape: tuple[int, ...] = (1,)

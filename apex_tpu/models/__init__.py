@@ -3,8 +3,9 @@ through.
 
 A ``model_spec`` is the plain dict that travels to worker processes and
 into checkpoint metadata.  Without a ``torso`` key it is today's dueling
-network's keyword arguments, unchanged; with one it names a preset of
-:mod:`apex_tpu.models.glm4_moe_lite`.
+network's keyword arguments, unchanged; with one it names a preset of a
+token-torso family (:mod:`apex_tpu.models.glm4_moe_lite`,
+:mod:`apex_tpu.models.nemotron_h`), found by the preset's name.
 """
 
 from __future__ import annotations
@@ -16,9 +17,25 @@ import jax
 DEFAULT_TORSO = "dueling"
 
 
+def _token_torsos() -> dict[str, tuple[type, dict]]:
+    """``{preset name: (module class, preset)}`` over the token-torso
+    families: a family is a module with ``PRESETS`` and the class that
+    reads them."""
+    from apex_tpu.models import glm4_moe_lite, nemotron_h
+    return {name: (cls, preset) for module, cls in (
+        (glm4_moe_lite, glm4_moe_lite.Glm4MoeLiteQ),
+        (nemotron_h, nemotron_h.NemotronHQ))
+        for name, preset in module.PRESETS.items()}
+
+
 def torso_names() -> list[str]:
-    from apex_tpu.models.glm4_moe_lite import PRESETS
-    return [DEFAULT_TORSO, *PRESETS]
+    return [DEFAULT_TORSO, *_token_torsos()]
+
+
+def token_preset(torso: str) -> dict:
+    """A token torso's preset: ``context`` ids a frame and ``vocab_held``
+    ids are what an env under it is sized to."""
+    return _token_torsos()[torso][1]
 
 
 def q_model_spec(torso: str, *, num_actions: int, obs_is_image: bool,
@@ -38,28 +55,29 @@ def make_q_network(model_spec: dict):
     if torso == DEFAULT_TORSO:
         from apex_tpu.models.dueling import DuelingDQN
         return DuelingDQN(**spec)
-    from apex_tpu.models.glm4_moe_lite import Glm4MoeLiteQ
-    return Glm4MoeLiteQ(preset=torso, **spec)
+    return _token_torsos()[torso][0](preset=torso, **spec)
 
 
-def note_attention_path(model, site: str) -> None:
-    """Which implementation a token torso's attention takes in this
-    process's programs (:func:`apex_tpu.ops.attention.attention_path`:
-    decided by platform and widths when a program is lowered, so once a
-    program's builder is enough): one ``attention_path`` instant in the
-    trace ring and one start-up line on stderr, from the ``site`` that
-    built the model (``trainer``, ``rollout``).  A model without attention
-    says nothing."""
-    path_of = getattr(model, "attention_path", None)
-    if path_of is None:
-        return
+def note_torso(model, site: str) -> None:
+    """What a token torso says of itself where a ``site`` (``trainer``,
+    ``rollout``) builds it, each as one instant in the trace ring and one
+    start-up line on stderr: ``attention_path``, which implementation its
+    attention takes in this process's programs
+    (:func:`apex_tpu.ops.attention.attention_path`: decided by platform
+    and widths when a program is lowered, so once a program's builder is
+    enough), and ``torso_layout``, what this chip holds of each layer.  A
+    model says nothing of what it does not have."""
     from apex_tpu.obs.trace import get_ring
-    args = {"site": site, "torso": model.preset,
-            **path_of(jax.default_backend())}
-    get_ring().instant("attention_path", None, args)
-    print("torso: attention_path "
-          + " ".join(f"{k}={v}" for k, v in args.items()),
-          file=sys.stderr, flush=True)
+    for name, args in (("attention_path", (jax.default_backend(),)),
+                       ("torso_layout", ())):
+        said = getattr(model, name, None)
+        if said is None:
+            continue
+        said = {"site": site, "torso": model.preset, **said(*args)}
+        get_ring().instant(name, None, said)
+        print(f"torso: {name} "
+              + " ".join(f"{k}={v}" for k, v in said.items()),
+              file=sys.stderr, flush=True)
 
 
 def learner_apply_fn(model):

@@ -145,26 +145,35 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, self.eps)
 
 
-class SwiGLU(_Base):
-    """``(silu(h W_g) * (h W_u)) W_d`` -> float32, ``token_block`` tokens
-    at a time where that is set and the input is larger: the two wide
-    products of a block are made again in the backward pass, so a layer of
-    width 10,240 keeps 4 k tokens' worth of them, not 16 k (2 GB less at
-    the peak of the update, where every gradient is already live)."""
+class FeedForward(_Base):
+    """``(silu(h W_g) * (h W_u)) W_d`` (``act="swiglu"``: three matrices)
+    or ``relu(h W_u)^2 W_d`` (``"relu2"``: two) -> float32,
+    ``token_block`` tokens at a time where that is set and the input is
+    larger: the wide products of a block are made again in the backward
+    pass, so a layer of width 10,240 keeps 4 k tokens' worth of them, not
+    16 k (2 GB less at the peak of the update, where every gradient is
+    already live)."""
 
     width: int = 0
     token_block: int = 0
+    act: str = "swiglu"
 
     @nn.compact
     def __call__(self, h):
         d, f = h.shape[-1], self.width
-        w_gate, w_up = self.kernel("gate", (d, f)), self.kernel("up", (d, f))
+        if self.act == "swiglu":
+            w_gate = self.kernel("gate", (d, f))
+        w_up = self.kernel("up", (d, f))
         w_down = self.kernel("down", (f, d))
 
         def ffn(x):
-            g = self.dot(x, w_gate, jnp.float32)
-            u = self.dot(x, w_up, jnp.float32)
-            return self.dot(jax.nn.silu(g) * u, w_down, jnp.float32)
+            if self.act == "swiglu":
+                g = self.dot(x, w_gate, jnp.float32)
+                u = self.dot(x, w_up, jnp.float32)
+                mid = jax.nn.silu(g) * u
+            else:
+                mid = jnp.square(jax.nn.relu(self.dot(x, w_up, jnp.float32)))
+            return self.dot(mid, w_down, jnp.float32)
 
         x = h.reshape(-1, d)
         blk = self.token_block
@@ -173,6 +182,10 @@ class SwiGLU(_Base):
         else:
             y = ffn(x)
         return y.reshape(h.shape)
+
+
+#: this torso's feed-forward: the default ``act``
+SwiGLU = FeedForward
 
 
 class MLA(_Base):
@@ -232,7 +245,10 @@ class MLA(_Base):
 
 
 class MoE(_Base):
-    """One shared expert and this chip's share of the routed experts."""
+    """One shared expert and this chip's share of the routed experts, for
+    every token torso: what differs between models is a field (``act``:
+    gated three-matrix ``silu`` experts or two-matrix ``relu^2`` ones; the
+    shared expert's width; the counts; the scaling)."""
 
     width: int = 1536
     n_routed_experts: int = 64
@@ -241,6 +257,8 @@ class MoE(_Base):
     num_experts_per_tok: int = 4
     routed_scaling_factor: float = 1.8
     expert_rows: int = 0        # rows a round; 0 = a quarter of the pairs
+    act: str = "swiglu"         # or "relu2" (:class:`FeedForward`)
+    shared_width: int = 0       # 0 = the routed experts' width
 
     def grouped(self, xs, w, group_sizes, live):
         """``xs[i] @ w[g(i)]`` -> float32 for the rows the groups cover.
@@ -269,9 +287,10 @@ class MoE(_Base):
                    / picked.sum(-1, keepdims=True))
         return picks.astype(jnp.int32), weights
 
-    def routed(self, h, picks, weights, w_gate, w_up, w_down):
+    def routed(self, h, picks, weights, *kernels):
         """The held experts' part of the layer output, ``f32[N, D]``, and
-        the pairs that landed on each of them, ``i32[n_held]``."""
+        the pairs that landed on each of them, ``i32[n_held]``.
+        ``kernels``: the stacked experts' (gate, up, down) or (up, down)."""
         n, k = picks.shape
         held, lo = self.n_held_experts, self.expert_rank * self.n_held_experts
         local = (picks >= lo) & (picks < lo + held)
@@ -286,7 +305,7 @@ class MoE(_Base):
 
         def one_round(r):
             @jax.checkpoint
-            def run(out, h, w_gate, w_up, w_down):
+            def run(out, h, *kernels):
                 a = r * rows
                 idx = jax.lax.dynamic_slice_in_dim(order, a, rows)
                 tok = idx // k
@@ -295,16 +314,21 @@ class MoE(_Base):
                 live = ((a + jnp.arange(rows)) < n_local)[:, None]
                 xs = h[tok]
                 with jax.named_scope("experts"):
-                    g = self.grouped(xs, w_gate, sizes, live)
-                    u = self.grouped(xs, w_up, sizes, live)
-                    y = self.grouped(jax.nn.silu(g) * u, w_down, sizes, live)
+                    if self.act == "swiglu":
+                        g = self.grouped(xs, kernels[0], sizes, live)
+                        u = self.grouped(xs, kernels[1], sizes, live)
+                        mid = jax.nn.silu(g) * u
+                    else:
+                        mid = jnp.square(jax.nn.relu(
+                            self.grouped(xs, kernels[0], sizes, live)))
+                    y = self.grouped(mid, kernels[-1], sizes, live)
                 y = y * flat_w[idx][:, None]
                 return out.at[tok].add(y)
             return run
 
         out = jnp.zeros((n, h.shape[-1]), jnp.float32)
         for r in range(-(-n * k // rows)):
-            args = (out, h, w_gate, w_up, w_down)
+            args = (out, h, *kernels)
             out = one_round(r)(*args) if r == 0 else jax.lax.cond(
                 n_local > r * rows, one_round(r), lambda out, *_: out, *args)
         return out, counts
@@ -314,19 +338,21 @@ class MoE(_Base):
         b, t, d = h32.shape
         dt = self.compute_dtype
         with jax.named_scope("shared_expert"):
-            y = SwiGLU(dt, self.width, name="shared")(h32)
+            y = FeedForward(dt, self.shared_width or self.width, 0, self.act,
+                            name="shared")(h32)
         # router: scores, picks, and the sort / gather / scatter that take
         # pairs to their experts and back (``experts``: the products alone)
         with jax.named_scope("router"):
             picks, weights = self.route(h32.reshape(b * t, d))
         e, f = self.n_held_experts, self.width
-        w_gate = self.param("experts_gate", _normal(), (e, d, f))
-        w_up = self.param("experts_up", _normal(), (e, d, f))
-        w_down = self.param("experts_down", _normal(), (e, f, d))
+        names = (("gate", "up", "down") if self.act == "swiglu"
+                 else ("up", "down"))
+        kernels = [self.param(f"experts_{name}", _normal(),
+                              (e, f, d) if name == "down" else (e, d, f))
+                   for name in names]
         with jax.named_scope("router"):
             part, counts = self.routed(
-                h32.reshape(b * t, d).astype(dt), picks, weights,
-                w_gate, w_up, w_down)
+                h32.reshape(b * t, d).astype(dt), picks, weights, *kernels)
         return y + part.reshape(b, t, d), counts
 
 

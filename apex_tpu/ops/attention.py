@@ -1,6 +1,6 @@
-"""Causal attention ``softmax(q k^T * scale) v`` for the token torso
-(:mod:`apex_tpu.models.glm4_moe_lite`): one algorithm, two
-implementations, picked by what the code can observe.
+"""Causal attention ``softmax(q k^T * scale) v`` for the token torsos
+(:mod:`apex_tpu.models`): one algorithm, two implementations, picked by
+what the code can observe.
 
 ``plain`` is the ``jax.numpy`` path: scores and softmax in float32, the
 two products in the operands' dtype with float32 accumulation.  It writes
@@ -92,7 +92,16 @@ def fused(q: jax.Array, k: jax.Array, v: jax.Array,
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      scale: float) -> jax.Array:
     """``softmax_causal(q k^T * scale) v`` for ``[b, H, T, d]`` operands
-    (already in the dtype the MXU is to multiply)."""
+    (already in the dtype the MXU is to multiply).  Grouped queries: where
+    ``k`` and ``v`` have fewer heads than ``q``, query heads ``i * H / H_kv
+    .. (i + 1) * H / H_kv - 1`` read key/value head ``i``.  Both
+    implementations take equal head counts, so the shared heads are
+    repeated (``[16, 16, 1024, 128]`` bfloat16 is 67 MB each for ``k`` and
+    ``v``); their gradient is the sum over the query heads that read
+    them."""
+    groups = q.shape[1] // k.shape[1]
+    if groups > 1:
+        k, v = (jnp.repeat(x, groups, axis=1) for x in (k, v))
     if not kernel_eligible(q.shape[2], q.shape[3], v.shape[3]):
         return plain(q, k, v, scale)
     return jax.lax.platform_dependent(

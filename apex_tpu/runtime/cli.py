@@ -80,7 +80,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "share of each layer, over token contexts "
                         "(--env-id ApexTokens-v0, sized by the preset); "
                         "'glm47_flash_tiny' its toy "
-                        "(apex_tpu/models/glm4_moe_lite.py)")
+                        "(apex_tpu/models/glm4_moe_lite.py); "
+                        "'nemotron_twotower_ep16' is the causal tower of "
+                        "Nemotron-Labs-TwoTower-30B-A3B (Mamba-2 mixers, "
+                        "grouped-query attention, a shared and 6 of 128 "
+                        "routed relu^2 experts, by its layer pattern) at "
+                        "published widths, one of 16 chips' share of each "
+                        "layer, 'nemotron_h_tiny' its toy "
+                        "(apex_tpu/models/nemotron_h.py)")
     p.add_argument("--token-vocab", type=int, default=0,
                    help="ApexTokens-v0 under a token torso: the ids the "
                         "env draws from, which are the actions and the "
@@ -426,7 +433,7 @@ def _mesh_shape(args: argparse.Namespace) -> tuple[int, ...]:
 
 
 def config_from_args(args: argparse.Namespace) -> ApexConfig:
-    from apex_tpu.models import DEFAULT_TORSO, torso_names
+    from apex_tpu.models import DEFAULT_TORSO, token_preset, torso_names
     if args.torso not in torso_names():
         raise SystemExit(f"--torso {args.torso!r}: known are "
                          f"{torso_names()}")
@@ -434,8 +441,7 @@ def config_from_args(args: argparse.Namespace) -> ApexConfig:
     if args.torso != DEFAULT_TORSO:
         # the preset's model reads frames of `context` ids: ApexTokens-v0
         # is sized to it, and the model holds the ids the env draws from
-        from apex_tpu.models.glm4_moe_lite import PRESETS
-        preset = PRESETS[args.torso]
+        preset = token_preset(args.torso)
         tokens = dict(token_context=preset["context"],
                       token_vocab=args.token_vocab or preset["vocab_held"])
     return ApexConfig(

@@ -575,15 +575,14 @@ def make_anakin_engine(cfg: ApexConfig, rollout_len: int | None = None,
     (:func:`apex_tpu.actors.vector.worker_slots`)."""
     from apex_tpu.actors.pool import actor_epsilons
     from apex_tpu.envs.registry import make_jax_env
-    from apex_tpu.models import (acting_params, make_q_network,
-                                 note_attention_path)
+    from apex_tpu.models import acting_params, make_q_network, note_torso
     from apex_tpu.models.dueling import make_policy_fn
     from apex_tpu.training.apex import dqn_env_specs
 
     env = make_jax_env(cfg.env.env_id, cfg.env)
     model_spec, _shape, _dtype, frame_stack = dqn_env_specs(cfg)
     model = make_q_network(model_spec)
-    note_attention_path(model, "rollout")
+    note_torso(model, "rollout")
     b = n_envs or max(cfg.actor.n_actors, 1) * max(
         1, cfg.actor.n_envs_per_actor)
     total = max(total_slots or 0, (slot_band + 1) * b)

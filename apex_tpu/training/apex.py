@@ -40,8 +40,8 @@ from apex_tpu.fleet.registry import FleetRegistry
 from apex_tpu.obs import spans as obs_spans
 from apex_tpu.envs.registry import (make_env, make_eval_env, num_actions,
                                     unstacked_env_spec)
-from apex_tpu.models import (learner_apply_fn, make_q_network,
-                             note_attention_path, q_model_spec)
+from apex_tpu.models import (learner_apply_fn, make_q_network, note_torso,
+                             q_model_spec)
 from apex_tpu.models.dueling import make_policy_fn
 from apex_tpu.ops.losses import make_optimizer
 from apex_tpu.replay.base import check_hbm_budget
@@ -1593,7 +1593,7 @@ class ApexTrainer(ConcurrentTrainer):
             dqn_env_specs(cfg)
 
         self.model = make_q_network(self.model_spec)
-        note_attention_path(self.model, "trainer")
+        note_torso(self.model, "trainer")
         self.replay = FramePoolReplay(
             capacity=cfg.replay.capacity, frame_shape=frame_shape,
             frame_stack=frame_stack, frame_dtype=np.dtype(frame_dtype).name,

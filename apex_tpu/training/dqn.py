@@ -24,8 +24,7 @@ import numpy as np
 
 from apex_tpu.config import ApexConfig
 from apex_tpu.envs.registry import make_env, make_eval_env, num_actions
-from apex_tpu.models import (make_q_network, note_attention_path,
-                             q_model_spec)
+from apex_tpu.models import make_q_network, note_torso, q_model_spec
 from apex_tpu.models.dueling import make_policy_fn
 from apex_tpu.replay.nstep import NStepAccumulator
 from apex_tpu.training import learner as learner_lib
@@ -78,7 +77,7 @@ class DQNTrainer(CheckpointableTrainer):
             compute_dtype=jnp.dtype(self.cfg.learner.compute_dtype),
             scale_uint8=self.env.observation_space.dtype == np.uint8)
         self.model = make_q_network(self.model_spec)
-        note_attention_path(self.model, "trainer")
+        note_torso(self.model, "trainer")
 
         lc = self.cfg.learner
         example_obs = jnp.zeros((1,) + obs_shape,

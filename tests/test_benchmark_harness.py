@@ -3,10 +3,10 @@
 ``benchmark/tests/test_harness.py`` (the driver's tier-1 command collects
 ``tests/`` only) keeps its 17 cases where a ``benchmark`` PR edits them;
 this module imports them, fixtures included, so that every PR runs them.
-Plus the new cell's CPU rehearsal: ``glmq_ondevice`` through ``build()``,
-``checked_steps()``, ``reference()`` and ``judge()`` reads ``correct``, and
-reads not ``correct`` with the routed experts left out of the program's
-side.
+Plus the token cells' CPU rehearsals: ``glmq_ondevice`` and
+``twotowerq_ondevice`` through ``build()``, ``checked_steps()``,
+``reference()`` and ``judge()`` read ``correct``, and read not ``correct``
+with the routed experts left out of the program's side.
 """
 
 from __future__ import annotations
@@ -47,18 +47,26 @@ _REAL = ((cli, "build_trainer", cli.build_trainer),
          (feed, "make_weights", feed.make_weights))
 
 
-@pytest.fixture(scope="module")
-def glmq():
-    """The new cell at its rehearsal size, built once, through the real
+def _rehearsal(workload: str, seed: int):
+    """A token cell at its rehearsal size, built through the real
     ``load_cell`` and ``build_trainer`` whatever order the cases run in."""
     with pytest.MonkeyPatch.context() as mp:
         for owner, name, real in _REAL:
             mp.setattr(owner, name, real)
-        run = harness.Run("glmq_ondevice", 2_900_000_011, 0.0, False, True,
-                          time.monotonic())
+        run = harness.Run(workload, seed, 0.0, False, True, time.monotonic())
         run.start()
         run.build()
     return run
+
+
+@pytest.fixture(scope="module")
+def glmq():
+    return _rehearsal("glmq_ondevice", 2_900_000_011)
+
+
+@pytest.fixture(scope="module")
+def twotowerq():
+    return _rehearsal("twotowerq_ondevice", 3_300_000_014)
 
 
 def _verdict(run):
@@ -95,3 +103,60 @@ def test_glmq_ondevice_without_its_routed_experts_is_not_correct(glmq):
     assert not ok, numbers
     # the experts' gradient is nought on the program's side
     assert numbers["grad_gap"][0] > numbers["grad_gap"][1]
+
+
+def test_twotowerq_ondevice_rehearsal_reads_correct(twotowerq):
+    """The Nemotron-H cell on the CPU at the toy preset: the family's
+    reference and costs are found by the config's ``family``, the trainer
+    acts on the device, holds its share, and the three checked updates
+    through the window's own programs (the chunked scan) agree with the
+    float32 reference (the recurrence) inside the cell's limits."""
+    run = twotowerq
+    assert run.config["family"] == "nemotron_h_q"
+    assert run.family.__name__ == "benchmark.reference.nemotron_h_q"
+    assert run.cfg.learner.torso == "nemotron_h_tiny"
+    assert run.cfg.env.token_context == 32
+    assert run.cfg.env.token_vocab == run.config["check"]["action_count"]
+    assert type(run.trainer.pool).__name__ == "AnakinPool"
+    layout = run.trainer.model.torso_layout()
+    assert (layout["mamba_heads"], layout["experts"]) == ("2/4", "2/8")
+    run.checked_steps()
+    ok, numbers = _verdict(run)
+    assert ok, numbers
+    # the numbers the cell is held to: the loss, the gradient and the
+    # parameters' change by their MEDIAN leaf (what tells precisions apart)
+    # and the change by its worst leaf (what tells a leaf left unchanged);
+    # the worst leaf's gradient is not held: one pick that falls the other
+    # way on a last-position token moves it as far as the fp8 control does
+    # (PERF.md section 6)
+    assert set(numbers) == {"writeback_miss", "loss_gap", "grad_median_gap",
+                            "dparam_median_gap", "dparam_gap"}
+    assert run.trainer._fused._cache_size() == 1
+    assert run.trainer._train._cache_size() == 1
+    # the benchmark's own counts of the configuration it runs
+    from benchmark import costs
+    cost = costs.step_cost(run.config)
+    assert cost["params"] == 577_780_864
+    assert costs.program_cost(run.config, dict(
+        learner_steps=1, acting_forwards=16))["flops"] > cost["flops"]
+
+
+def test_twotowerq_ondevice_without_its_routed_experts_is_not_correct(
+        twotowerq):
+    """The fault ``readings_big.py`` plants swaps the ONE expert layer's
+    ``routed``: it reaches this torso's two-matrix experts too."""
+    run = twotowerq
+    real = run.trainer._fused, run.trainer._train
+    put_back = _load("readings_big").plant_left_out_experts(run)
+    try:
+        run.reset_state(3_300_000_015)
+        run.checked_steps()
+        ok, numbers = _verdict(run)
+    finally:
+        put_back()
+        run.trainer._fused, run.trainer._train = real
+    assert not ok, numbers
+    # the experts' gradient is nought on the program's side: their leaves
+    # stay where they were, which the worst leaf's change reads as 1.0
+    assert numbers["dparam_gap"][0] == pytest.approx(1.0)
+    assert numbers["dparam_gap"][0] > numbers["dparam_gap"][1]

@@ -388,6 +388,58 @@ def test_attention_path_instant_says_which_way_attention_went(
     assert f"attention_path site={site} torso={torso} fused=0" in err
 
 
+@pytest.mark.parametrize("site,torso", [
+    ("trainer", "nemotron_h_tiny"), ("rollout", "nemotron_h_tiny"),
+    ("trainer", "glm47_flash_tiny")])
+def test_torso_layout_instant_says_what_the_chip_holds(
+        site, torso, tmp_path, monkeypatch, capfd):
+    """Whoever builds a torso that holds a share of its mixers' heads
+    leaves one ``torso_layout`` instant in the ring and one line on
+    stderr, beside ``attention_path``; a torso that says nothing of a
+    layout (GLM's) leaves none."""
+    from apex_tpu.config import (ActorConfig, ApexConfig, EnvConfig,
+                                 LearnerConfig, ReplayConfig)
+    from apex_tpu.training.anakin import make_anakin_engine
+    from apex_tpu.training.apex import ApexTrainer
+
+    cfg = ApexConfig(
+        env=EnvConfig(env_id="ApexTokens-v0", frame_stack=1,
+                      clip_rewards=False, episodic_life=False,
+                      token_context=32),
+        replay=ReplayConfig(capacity=256, warmup=32),
+        learner=LearnerConfig(batch_size=8, compute_dtype="float32",
+                              torso=torso),
+        actor=ActorConfig(n_actors=1, n_envs_per_actor=2, send_interval=16))
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        if site == "trainer":
+            ApexTrainer(cfg, pool=ScriptedPool([]), respawn_workers=False)
+        else:
+            make_anakin_engine(cfg, rollout_len=4)
+        events = obs_trace.get_ring().to_chrome()["traceEvents"]
+    finally:
+        obs_trace.reset_for_tests()
+    found = [ev["args"] for ev in events if ev.get("name") == "torso_layout"]
+    paths = [ev["args"] for ev in events
+             if ev.get("name") == "attention_path"]
+    err = capfd.readouterr().err
+    assert [a["torso"] for a in paths] == [torso]
+    if torso != "nemotron_h_tiny":
+        assert found == [] and "torso_layout" not in err
+        return
+    assert found == [{
+        "site": site, "torso": torso, "pattern": "ME*ME",
+        "mamba_heads": "2/4", "groups": "1/2", "attn_heads": "2/4",
+        "kv_heads": "1/2", "experts": "2/8", "expert_rank": 0,
+        "chunk": 8, "ssd_impl": "xla",
+        "params": found[0]["params"]}]
+    assert found[0]["params"] > 0
+    assert (f"torso_layout site={site} torso={torso} pattern=ME*ME "
+            "mamba_heads=2/4 groups=1/2") in err
+
+
 def _hlo_ops(lowered) -> list[tuple[str, str]]:
     """``(opcode, op_name)`` of every instruction of the compiled program
     (compiled: XLA's inliner is what prefixes the operations of a called
@@ -848,6 +900,158 @@ def test_torso_scopes_place_the_grouped_kernels_by_name(tmp_path):
     assert torso_scopes.scope_of("ragged-dot-none") is None
     assert torso_scopes.scope_of(
         step + "jvp(router)/transpose(jvp(experts))/mul:") == "experts"
+
+
+def test_nemotron_h_scopes_reduce_by_the_innermost_name(tmp_path):
+    """The device plane of one update of the Nemotron-H torso: an
+    operation counts under the innermost of the family's names on its path
+    (``ssd`` and ``conv`` inside ``mamba``, ``experts`` inside ``router``),
+    the grouped products by their own name; ``mamba_ms`` is the scope with
+    what lies inside it, ``ssd_ms`` the scan alone, and ``ssd_roofline``
+    the scan's least time by the family's counts over its device time."""
+    from benchmark import costs, nemotron_h_scopes
+
+    step = "jit(fused_step)/update/loss_grad/"
+    fwd = step + "jvp(NemotronHQ)/checkpoint/layers_0/mamba/mamba/"
+    bwd = (step + "transpose(jvp(NemotronHQ))/update/loss_grad/"
+           "jvp(NemotronHQ)/checkpoint/rematted_computation/layers_0/"
+           "mamba/mamba/")
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(123)", []),
+        10: ("%fusion.1 = bf16[16,1024,5120] fusion(",
+             [_stat("tf_op", fwd + "mamba.dot/dot_general:")]),
+        11: ("%fusion.2 = f32[16,1024,3072] fusion(",
+             [_stat("tf_op", fwd + "conv/jit(silu)/mul:")]),
+        12: ("%fusion.3 = f32[16,8,4,8,128,128] fusion(", [_stat(
+            "tf_op", fwd + "ssd/bclgn,bcsgn->bcgls/dot_general:")]),
+        13: ("%while.4 = (s32[], f32[16,4,8,64,128]) while(",
+             [_stat("tf_op", bwd + "ssd/while:")]),
+        14: ("%fusion.5 = f32[16,4,8,64,128] fusion(", []),  # in the while
+        15: ("%fusion.6 = f32[16,16,1024,128] fusion(", [_stat(
+            "tf_op", step + "jvp(NemotronHQ)/checkpoint/layers_5/attention/"
+            "attention/btd,dhk->bhtk/dot_general:")]),
+        16: ("%ragged-dot-none.1 = f32[12288,1856]{1,0} custom-call(",
+             [_stat("tf_op", "ragged-dot-none")]),
+        17: ("%fusion.7 = f32[16384,2688] fusion(", [_stat(
+            "tf_op", step + "jvp(NemotronHQ)/layers_1/moe/router/"
+            "moe.routed/checkpoint/mul:")]),
+        18: ("%fusion.8 = f32[2688] fusion(",
+             [_stat("tf_op", "jit(fused_step)/update/optimizer/mul:")]),
+    }, {
+        "XLA Modules": (0, [(1, 100, 1000, [])]),
+        "XLA Ops": (0, [
+            (10, 100, 100, []), (11, 200, 50, []), (12, 250, 200, []),
+            (13, 450, 100, []), (14, 460, 80, []), (15, 550, 150, []),
+            (16, 700, 120, []), (17, 820, 30, []), (18, 850, 100, [])]),
+    })
+    path = tmp_path / "nemotron.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, _plane("/host:metadata", {}, {}))))
+    planes = spans.read_xspace(str(path))
+    got = nemotron_h_scopes.reduce_planes(planes, ("jit_fused_step",))[
+        "jit_fused_step"]
+    us = 1e-6
+    assert {k: round(v / us, 2) for k, v in got["scopes"].items() if v} == {
+        "mamba": 100.0, "conv": 50.0, "ssd": 300.0, "attention": 150.0,
+        "experts": 120.0, "router": 30.0}
+    assert {k: round(v / us, 2) for k, v in got["rest"].items()} == {
+        "%fusion.8": 100.0}
+    # the GLM torso's reader knows none of these names: nothing to read
+    from benchmark import torso_scopes
+    assert torso_scopes.scope_of(fwd + "ssd/exp:") is None
+    assert nemotron_h_scopes.scope_of(
+        step + "transpose(jvp(ssd))/mul:") == "ssd"
+    assert nemotron_h_scopes.reduce_planes(
+        spans.read_xspace(os.path.join(FIXTURES, "scoped.xplane.pb")),
+        ("jit_fused_step", "jit_train_step")) is None
+    # the readers over the same plane
+    said = []
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nemotron_twotower_q_ep16.json")) as f:
+        config = json.load(f)
+    ctx = dict(_spans={"planes": planes, "ring": []},
+               traffic={"step_programs": {
+                   "jit_fused_step": {"learner_steps": 1}}},
+               trace={"busy_s": 850 * us}, config=config,
+               peaks=costs.peaks_for("TPU v5 lite"), say=said.append)
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(spans, "load", lambda c: c["_spans"])
+    try:
+        assert _reader("mamba_ms").read(ctx) == pytest.approx(0.45)
+        assert _reader("ssd_ms").read(ctx) == pytest.approx(0.30)
+        assert _reader("attention_ms").read(ctx) == pytest.approx(0.15)
+        fam = costs.family_costs("nemotron_h_q")
+        shapes = config["shapes"]
+        least = fam.ssd_bytes(shapes, 16_384, 6 * 4) / 819e9
+        assert least > 2 * 24 * fam.ssd_macs(shapes, 16_384) / 197e12
+        assert _reader("ssd_roofline").read(ctx) == pytest.approx(
+            100.0 * least / (300 * us))
+    finally:
+        monkey.undo()
+    assert any("torso scopes hold" in line for line in said)
+
+
+SHARED_SCOPES = ("embed", "router", "experts", "shared_expert", "q_head")
+
+
+@pytest.fixture(scope="module")
+def shared_plane(tmp_path_factory):
+    """One update whose operations lie under the names both torsos use
+    (the expert layer is one class, ``embed`` and ``q_head`` mean the
+    same), beside one operation of each family's own."""
+    step = "jit(fused_step)/update/loss_grad/jvp(Q)/"
+    moe = step + "layers_1/moe/"
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(7)", []),
+        10: ("%fusion.1 = f32[16384,2688] fusion(",
+             [_stat("tf_op", step + "embed/gather:")]),
+        11: ("%fusion.2 = f32[16384,128] fusion(",
+             [_stat("tf_op", moe + "router/moe.route/dot_general:")]),
+        12: ("%ragged-dot-none.1 = f32[24576,1856]{1,0} custom-call(",
+             [_stat("tf_op", "ragged-dot-none")]),
+        13: ("%fusion.3 = bf16[24576,1856] fusion(", [_stat(
+            "tf_op", moe + "router/moe.routed/checkpoint/experts/"
+            "moe.grouped/mul:")]),
+        14: ("%fusion.4 = f32[16384,3712] fusion(", [_stat(
+            "tf_op", moe + "shared_expert/shared/dot_general:")]),
+        15: ("%fusion.5 = f32[16,16384] fusion(",
+             [_stat("tf_op", step + "q_head/head/dot_general:")]),
+        16: ("%fusion.6 = f32[16,1024,5120] fusion(",
+             [_stat("tf_op", step + "layers_0/mamba/mamba/dot_general:")]),
+        17: ("%fusion.7 = f32[16,1024,5120] fusion(",
+             [_stat("tf_op", step + "layers_0/mla/mla/dot_general:")]),
+    }, {
+        "XLA Modules": (0, [(1, 100, 1000, [])]),
+        "XLA Ops": (0, [
+            (10, 100, 10, []), (11, 110, 20, []), (12, 130, 40, []),
+            (13, 170, 80, []), (14, 250, 160, []), (15, 410, 320, []),
+            (16, 730, 100, []), (17, 830, 100, [])]),
+    })
+    path = tmp_path_factory.mktemp("shared") / "shared.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, _plane("/host:metadata", {}, {}))))
+    return spans.read_xspace(str(path))
+
+
+@pytest.mark.parametrize("scope", SHARED_SCOPES)
+def test_both_torso_readers_agree_on_the_names_they_share(shared_plane,
+                                                          scope):
+    """``nemotron_h_scopes.py`` is ``torso_scopes.py``'s reduction over
+    another tuple of names (the benchmark's files may not be edited, so
+    the reader could not be made one: PERF.md section 7 queues that).
+    Until they are one, the names both know read the same seconds on the
+    same plane, by path and by kernel name alike."""
+    from benchmark import nemotron_h_scopes, torso_scopes
+
+    want = {"embed": 10, "router": 20, "experts": 120, "shared_expert": 160,
+            "q_head": 320}
+    glm = torso_scopes.reduce_planes(shared_plane, ("jit_fused_step",))
+    nem = nemotron_h_scopes.reduce_planes(shared_plane, ("jit_fused_step",))
+    assert glm["jit_fused_step"]["scopes"][scope] == pytest.approx(
+        want[scope] * 1e-6)
+    assert nem["jit_fused_step"]["scopes"][scope] == pytest.approx(
+        glm["jit_fused_step"]["scopes"][scope])
+    # each leaves the other family's own operation under no scope
+    assert set(glm["jit_fused_step"]["rest"]) == {"%fusion.6"}
+    assert set(nem["jit_fused_step"]["rest"]) == {"%fusion.7"}
 
 
 # -- the trace recorded on the chip ------------------------------------------------------
