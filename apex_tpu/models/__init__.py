@@ -65,10 +65,14 @@ def note_torso(model, site: str) -> None:
     attention takes in this process's programs
     (:func:`apex_tpu.ops.attention.attention_path`: decided by platform
     and widths when a program is lowered, so once a program's builder is
-    enough), and ``torso_layout``, what this chip holds of each layer.  A
-    model says nothing of what it does not have."""
+    enough), ``grouped_path``, the widths its expert layers hand the
+    grouped kernel (:func:`apex_tpu.ops.grouped.grouped_path`: decided the
+    same way), and ``torso_layout``, what this chip holds of
+    each layer.  A model says nothing of what it does not have."""
     from apex_tpu.obs.trace import get_ring
-    for name, args in (("attention_path", (jax.default_backend(),)),
+    platform = jax.default_backend()
+    for name, args in (("attention_path", (platform,)),
+                       ("grouped_path", (platform,)),
                        ("torso_layout", ())):
         said = getattr(model, name, None)
         if said is None:

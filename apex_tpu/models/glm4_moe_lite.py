@@ -18,8 +18,14 @@ no capacity factor.  The (token, expert) pairs that land on held experts are
 sorted by expert and go through a grouped matrix product
 (:func:`jax.lax.ragged_dot`) in rounds of ``expert_rows`` rows; a round
 past the first runs only when routing filled the rounds before it
-(``lax.cond``), so uneven routing costs time, never a pair.  The vocabulary
-is a slice too: ids are taken ``mod vocab_held``.
+(``lax.cond``), so uneven routing costs time, never a pair.  Inside a
+round the product goes the way :func:`apex_tpu.ops.grouped.plan` says: in
+a program compiled for a TPU, at widths of 512 or more that 512 does not
+divide, a kernel with tiles of its own is handed the operands with zeros
+up to a multiple of each tile (exact zeros, cut off the layer's output and
+off the weight gradients before they leave the round); everywhere else
+``ragged_dot`` is handed the widths as they are.  The vocabulary is a slice
+too: ids are taken ``mod vocab_held``.
 
 The repo's idiom: float32 parameters, ``compute_dtype`` operands on the
 MXU with float32 accumulation; norms, the router, softmax and the Q output
@@ -46,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from apex_tpu.ops import attention
+from apex_tpu.ops import attention, grouped
 
 #: ``--torso`` presets: the published widths at one of 8 chips' share of
 #: each layer, and the toy the CPU tests run.  ``context`` is the number of
@@ -260,16 +266,34 @@ class MoE(_Base):
     act: str = "swiglu"         # or "relu2" (:class:`FeedForward`)
     shared_width: int = 0       # 0 = the routed experts' width
 
-    def grouped(self, xs, w, group_sizes, live):
-        """``xs[i] @ w[g(i)]`` -> float32 for the rows the groups cover.
-        ``ragged_dot`` leaves the rows past the last group unwritten, in
-        its output and in the cotangent it hands back: both are masked
-        here, so no stray bits reach a live row."""
+    def grouped(self, xs, w, group_sizes, live, tiles=None):
+        """``xs[i] @ w[g(i)]`` -> float32 for the rows the groups cover,
+        by ``ragged_dot`` or, with ``tiles``, by the tiled kernel at the
+        widths handed (:func:`apex_tpu.ops.grouped.product`).  Either
+        leaves the rows past the last group unwritten, in its output and
+        in the cotangent it hands back: both are masked here, so no stray
+        bits reach a live row."""
         dt = self.compute_dtype
         xs = jnp.where(live, xs, 0).astype(dt)
-        y = jax.lax.ragged_dot(xs, w.astype(dt), group_sizes,
-                               preferred_element_type=jnp.float32)
+        y = grouped.product(xs, w.astype(dt), group_sizes, tiles)
         return jnp.where(live, y, 0.0)
+
+    def experts(self, xs, sizes, live, *kernels, tiles=None):
+        """The held experts' products over a round's sorted rows ``xs`` ->
+        ``f32[rows, D]``; ``tiles = (hidden's, width's)`` as
+        :meth:`grouped` takes them.  A padded ``mid`` goes to the down
+        product as it stands (``relu(0)^2`` and ``silu(0) * 0`` are zero);
+        only the output is cut back."""
+        up, down = (tiles, tiles[::-1]) if tiles else (None, None)
+        if self.act == "swiglu":
+            g = self.grouped(xs, kernels[0], sizes, live, up)
+            u = self.grouped(xs, kernels[1], sizes, live, up)
+            mid = jax.nn.silu(g) * u
+        else:
+            mid = jnp.square(jax.nn.relu(
+                self.grouped(xs, kernels[0], sizes, live, up)))
+        y = self.grouped(mid, kernels[-1], sizes, live, down)
+        return y[:, :xs.shape[1]]
 
     def route(self, h32):
         """``(picks i32[N, k], weights f32[N, k])`` over ALL routed
@@ -302,6 +326,9 @@ class MoE(_Base):
         starts, n_local = ends - counts, ends[-1]
         flat_w = weights.reshape(-1)
         rows = self.expert_rows or max(n * k // 4, 1)
+        # which kernel, by the platform the program is lowered for; where
+        # no platform changes it, one path and no choice in the program
+        on_tpu = grouped.plan(h.shape[-1], self.width, rows, "tpu")
 
         def one_round(r):
             @jax.checkpoint
@@ -314,14 +341,11 @@ class MoE(_Base):
                 live = ((a + jnp.arange(rows)) < n_local)[:, None]
                 xs = h[tok]
                 with jax.named_scope("experts"):
-                    if self.act == "swiglu":
-                        g = self.grouped(xs, kernels[0], sizes, live)
-                        u = self.grouped(xs, kernels[1], sizes, live)
-                        mid = jax.nn.silu(g) * u
-                    else:
-                        mid = jnp.square(jax.nn.relu(
-                            self.grouped(xs, kernels[0], sizes, live)))
-                    y = self.grouped(mid, kernels[-1], sizes, live)
+                    y = self.experts(xs, sizes, live, *kernels) \
+                        if on_tpu is None else jax.lax.platform_dependent(
+                            xs, sizes, live, *kernels, default=self.experts,
+                            tpu=functools.partial(self.experts,
+                                                  tiles=on_tpu))
                 y = y * flat_w[idx][:, None]
                 return out.at[tok].add(y)
             return run
@@ -420,6 +444,14 @@ class Glm4MoeLiteQ(nn.Module):
         return attention.attention_path(
             c["context"], c["qk_nope_head_dim"] + c["qk_rope_head_dim"],
             c["v_head_dim"], platform)
+
+    def grouped_path(self, platform: str) -> dict:
+        """What the expert layers hand the grouped kernel at this preset's
+        widths in a program compiled for ``platform``
+        (:func:`apex_tpu.ops.grouped.grouped_path`)."""
+        c = self.cfg
+        return grouped.grouped_path(c["hidden_size"],
+                                    c["moe_intermediate_size"], platform)
 
     @nn.compact
     def __call__(self, obs, with_stats: bool = False):

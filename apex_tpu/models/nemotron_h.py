@@ -57,7 +57,7 @@ from flax import linen as nn
 
 from apex_tpu.models.glm4_moe_lite import (Linear, MoE, RMSNorm, Weight, _Base,
                                            _normal, token_ids)
-from apex_tpu.ops import attention
+from apex_tpu.ops import attention, grouped
 
 #: what computes the scan (``torso_layout``'s ``ssd_impl``)
 SSD_IMPL = "xla"
@@ -347,6 +347,13 @@ class NemotronHQ(nn.Module):
         c = self.cfg
         return attention.attention_path(c["context"], c["head_dim"],
                                         c["head_dim"], platform)
+
+    def grouped_path(self, platform: str) -> dict:
+        """What the ``E`` layers hand the grouped kernel at this preset's
+        widths in a program compiled for ``platform``."""
+        c = self.cfg
+        return grouped.grouped_path(c["hidden_size"],
+                                    c["moe_intermediate_size"], platform)
 
     def torso_layout(self) -> dict:
         """What this chip holds of each layer: the arguments of the trace

@@ -180,7 +180,7 @@ def reference_moe(p, h):
         return jax.vmap(lambda x: ref.moe(x, p, m, "f32"))(h)
 
 
-def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer():
+def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(grouped_rule):
     """4 ranks x 2 of 8 experts: the routed parts of every rank, with the
     shared expert counted once, are the uncut reference's layer output."""
     p = uncut_moe_params(21)
@@ -204,7 +204,8 @@ def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer():
         atol=1e-5)
 
 
-def test_no_pair_is_dropped_when_every_token_picks_the_same_experts():
+def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(
+        grouped_rule):
     """A router bias that sends every token to experts 0 and 1, both held
     here: all ``N k`` pairs land on this rank, every round runs, and the
     layer still equals the reference."""

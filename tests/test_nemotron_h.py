@@ -17,6 +17,7 @@ from __future__ import annotations
 import functools
 import math
 import os
+import re
 import sys
 
 import numpy as np
@@ -34,7 +35,7 @@ from apex_tpu.models import (acting_params, learner_apply_fn,  # noqa: E402
                              make_q_network)
 from apex_tpu.models import glm4_moe_lite as glm  # noqa: E402
 from apex_tpu.models import nemotron_h as nh  # noqa: E402
-from apex_tpu.ops import attention  # noqa: E402
+from apex_tpu.ops import attention, grouped  # noqa: E402
 from apex_tpu.ops.losses import double_dqn_loss, make_optimizer  # noqa: E402
 from apex_tpu.training.learner import td_update  # noqa: E402
 from apex_tpu.training.state import create_train_state  # noqa: E402
@@ -316,7 +317,8 @@ def reference_moe(p, h):
         return jax.vmap(lambda x: ref.moe(x, p, M, "f32"))(h)
 
 
-def test_the_expert_shares_of_all_ranks_add_up_to_the_uncut_layer():
+def test_the_expert_shares_of_all_ranks_add_up_to_the_uncut_layer(
+        grouped_rule):
     """4 ranks x 2 of 8 two-matrix experts: the routed parts of every
     rank, with the shared expert (of its own width) counted once, are the
     uncut reference's layer output."""
@@ -341,7 +343,7 @@ def test_the_expert_shares_of_all_ranks_add_up_to_the_uncut_layer():
                                atol=1e-5)
 
 
-def test_no_pair_is_dropped_with_two_matrix_experts():
+def test_no_pair_is_dropped_with_two_matrix_experts(grouped_rule):
     """A router bias that sends every token to experts 0 and 1, both held
     here: all ``N k`` pairs land on this rank, every round runs, the layer
     still equals the reference and the gradient reaches both matrices of
@@ -359,6 +361,73 @@ def test_no_pair_is_dropped_with_two_matrix_experts():
         {"params": q}, h)[0].sum())(cut["params"])
     assert all(float(jnp.abs(g[k][e]).max()) > 0
                for k in ("experts_up", "experts_down") for e in range(held))
+
+
+def _round(act: str, case: str):
+    """A round's operands at widths no tile of 16 divides (40 x 24): rows
+    sorted by expert, ``case`` says what is special about the groups."""
+    d, f, e, rows = 40, 24, 4, 32
+    sizes = {"rows_past_the_last_group": [5, 9, 3, 7],
+             "an_empty_group": [11, 0, 9, 12]}[case]
+    keys = jax.random.split(jax.random.key(7), 5)
+    live = (jnp.arange(rows) < sum(sizes))[:, None]
+    n = 3 if act == "swiglu" else 2
+    kernels = [0.3 * jax.random.normal(k, (e, f, d) if i == n - 1
+                                       else (e, d, f))
+               for i, k in enumerate(keys[:n])]
+    xs = jax.random.normal(keys[3], (rows, d))
+    cot = jax.random.normal(keys[4], (rows, d))
+    return (glm.MoE(jnp.float32, f, act=act), xs,
+            jnp.array(sizes, jnp.int32), live, kernels, cot)
+
+
+@pytest.mark.parametrize("case", ["rows_past_the_last_group",
+                                  "an_empty_group"])
+@pytest.mark.parametrize("act", ["relu2", "swiglu"])
+def test_the_tiled_products_equal_the_plain_ones(act, case,
+                                                 interpreted_kernel):
+    """The tiled kernel handed zero-padded operands (40 x 24 in tiles of
+    16: 48 x 32) gives ``ragged_dot``'s output and its gradients, of the
+    rows and of every stacked kernel, at the shapes as they were: the
+    zeros add nothing and the cuts take nothing."""
+    layer, xs, sizes, live, kernels, cot = _round(act, case)
+
+    def run(tiles):
+        def loss(xs, *kernels):
+            y = layer.experts(xs, sizes, live, *kernels, tiles=tiles)
+            return jnp.sum(y * cot), y
+        (_, y), grads = jax.value_and_grad(
+            loss, argnums=tuple(range(1 + len(kernels))), has_aux=True)(
+                xs, *kernels)
+        return y, grads
+
+    want, want_grads = run(None)
+    got, got_grads = run(((16, 48), (16, 32)))
+    assert got.shape == want.shape and float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(got[int(sizes.sum()):], 0.0)
+    for g, w in zip(got_grads, want_grads):
+        assert g.shape == w.shape and float(jnp.abs(w).max()) > 0
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("hidden,width,platform,impl,handed,tiles", [
+    # the published widths: 3 x 896 as it stands, 1,856 -> 3 x 640
+    (2688, 1856, "tpu", "megablox_gmm", (2688, 1920), (896, 640)),
+    (2048, 1536, "tpu", "ragged_dot", (2048, 1536), (512, 512)),  # GLM's
+    (2688, 384, "tpu", "ragged_dot", (2688, 384), (128, 128)),    # < 512
+    (64, 32, "tpu", "ragged_dot", (64, 32), (128, 128)),          # the toy
+    (2688, 1856, "cpu", "ragged_dot", (2688, 1856), (0, 0))])     # no TPU
+def test_the_grouped_rule_leaves_alone_what_a_tile_divides(
+        hidden, width, platform, impl, handed, tiles):
+    said = grouped.grouped_path(hidden, width, platform)
+    assert said == {"hidden": hidden, "width": width, "platform": platform,
+                    "hidden_handed": handed[0], "width_handed": handed[1],
+                    "tile_k": tiles[0], "tile_n": tiles[1], "impl": impl}
+    assert (grouped.plan(hidden, width, 24576, platform) is None) == \
+        (impl == "ragged_dot")
+    # rows no tile of 128 divides: the kernel cannot take them
+    assert grouped.plan(hidden, width, 24576 + 64, platform) is None
 
 
 # -- (g) the presets ------------------------------------------------------------
@@ -646,6 +715,151 @@ def test_compiled_update_calls_the_grouped_kernel_as_the_roofline_counts():
     for name, op_name in kernels:
         assert nemotron_h_scopes.scope_of(op_name + ":") is None
         assert nemotron_h_scopes.op_scope(name, op_name) == "experts"
+
+
+def _pallas_blocks(jaxpr, out=None) -> list[list[tuple]]:
+    """The block shapes of every ``pallas_call`` in a jaxpr, the branches
+    of every ``cond`` and what ``remat`` / ``custom_vjp`` wrap included:
+    one list a call, a tuple an operand or result (``None`` for a squeezed
+    axis)."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append([tuple(getattr(b, "block_size", None)
+                              for b in m.block_shape)
+                        for m in eqn.params["grid_mapping"].block_mappings])
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_blocks(sub, out)
+    return out
+
+
+def test_compiled_update_hands_the_grouped_products_whole_tiles(monkeypatch):
+    """At widths like the published ones (hidden 1,344 and experts 928,
+    their halves: 512 divides neither) every grouped product of the update
+    compiled for the chip, the 12 of ``EXPERT_UNITS`` in each of the
+    layer's rounds, is the tiled kernel (``gmm``, and ``tgmm`` for the two
+    weight gradients) and none XLA's own, whose weight window there would
+    be 128 x 128; each carries its scope path and lies under ``experts``;
+    its weight block is the rule's (768 x 512 for 1,536 x 1,024 handed);
+    and a round hands back gradients of the shapes as published, never of
+    the padded ones."""
+    import re
+
+    from benchmark import costs_nemotron_h_q as costs_nh
+    from benchmark import nemotron_h_scopes
+
+    like = dict(C, hidden_size=1344, moe_intermediate_size=928,
+                moe_shared_expert_intermediate_size=256, pattern="ME",
+                context=64)
+    monkeypatch.setitem(nh.PRESETS, "published_like", like)
+    m = model(jnp.bfloat16, "published_like")
+    said = m.grouped_path("tpu")
+    assert said["impl"] == "megablox_gmm"
+    assert (said["hidden_handed"], said["width_handed"]) == (1536, 1024)
+    assert (said["tile_k"], said["tile_n"]) == (768, 512)
+    rounds, rows = 4, 4 * 64 * C["num_experts_per_tok"] // 4
+    want = 2 * sum(costs_nh.EXPERT_UNITS.values()) * rounds
+    hlo = _tpu_update_hlo("published_like", 4)
+    calls = re.findall(r"\n\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*custom-call\("
+                       r"[^\n]*tpu_custom_call[^\n]*op_name=\"([^\"]*)\"", hlo)
+    assert not any("ragged" in n for n, _op in calls)
+    kernels = [(n, op) for n, op in calls if "moe.grouped" in op]
+    assert len(kernels) == want, (len(kernels), want)
+    assert sum("tgmm" in n for n, _op in kernels) == 2 * rounds
+    for name, op_name in kernels:
+        assert op_name.endswith("gmm)/pallas_call"), op_name
+        assert nemotron_h_scopes.scope_of(op_name + ":") == "experts"
+        assert nemotron_h_scopes.op_scope(name, op_name) == "experts"
+    # what leaves a round: ``lax.cond``'s results
+    results = re.findall(r"\n\s*(?:ROOT )?%[\w.\-]+ = (\([^\n]*?\)|\S+) "
+                         r"conditional\(", hlo)
+    assert any("[2,1344,928]" in r for r in results)
+    assert any("[2,928,1344]" in r for r in results)
+    assert not any(re.search(r"\b(1536|1024)\b", r) for r in results), \
+        results
+    # the blocks, from the program as traced (both sides of the platform's
+    # choice are in it; only the kernel's side has a ``pallas_call``)
+    t = like["context"]
+    p = jax.eval_shape(m.init, jax.random.key(0),
+                       jnp.zeros((1, 2 * t), jnp.uint8))
+    batch = jax.eval_shape(lambda: batch_of(3, 4, t))
+    traced = jax.make_jaxpr(lambda q, b: jax.grad(lambda q: double_dqn_loss(
+        learner_apply_fn(m), q, q, b, jnp.ones(4))[0])(q))(p, batch)
+    blocks = _pallas_blocks(traced.jaxpr)
+    assert len(blocks) == want
+    for call in blocks:
+        weight = [b for b in call if len(b) == 3]
+        assert len(weight) == 1 and weight[0][0] is None, call
+        assert sorted(weight[0][1:]) == [512, 768], call
+        assert all(b[0] == rows for b in call if len(b) == 2), call
+
+
+@pytest.mark.parametrize("product", ["up", "down"])
+def test_the_tiled_kernels_compile_at_the_published_widths(product):
+    """The three kernels of a grouped product (forward, the rows' and the
+    weights' gradient) at the published widths and a round's 24,576 rows,
+    compiled for the described chip: the tiles the rule gives them fit
+    its VMEM, and no ``ragged_dot`` is left."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu on this machine
+        pytest.skip(f"no TPU compiler here: {e}")
+    chip = SingleDeviceSharding(topo.devices[0])
+    c = nh.PRESETS["nemotron_twotower_ep16"]
+    rows, e = 16 * c["context"] * c["num_experts_per_tok"] // 4, 8
+    tiles = grouped.plan(c["hidden_size"], c["moe_intermediate_size"], rows,
+                         "tpu")
+    assert tiles == ((896, 2688), (640, 1920))
+    k, n = (c["hidden_size"], c["moe_intermediate_size"])
+    if product == "down":
+        tiles, (k, n) = tiles[::-1], (n, k)
+
+    def both_gradients(xs, w, sizes, dy):
+        y, back = jax.vjp(lambda a, b: grouped.product(a, b, sizes, tiles),
+                          xs, w)
+        return y, back(dy)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    hlo = jax.jit(both_gradients).lower(
+        shaped((rows, k), jnp.bfloat16), shaped((e, k, n), jnp.bfloat16),
+        shaped((e,), jnp.int32), shaped((rows, tiles[1][1]), jnp.float32)
+    ).compile().as_text()
+    assert "ragged-dot" not in hlo
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                          r"gmm[^\n\"]*/pallas_call", hlo)) == 3
+
+
+def test_the_chips_compiler_knows_the_step_programs_option(monkeypatch):
+    """``learner.jit_step_program`` hands XLA:TPU an option by name; a
+    compiler that has dropped it refuses the program here, not on the
+    chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from apex_tpu.training import learner
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu on this machine
+        pytest.skip(f"no TPU compiler here: {e}")
+    with monkeypatch.context() as as_on_a_tpu:
+        as_on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        step = learner.jit_step_program(lambda x: x @ x)
+    x = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    assert "256,256" in step.lower(x).compile().as_text()
+    with pytest.raises(Exception, match="no_such_option"):
+        jax.jit(lambda x: x @ x, compiler_options={
+            "xla_tpu_no_such_option": "true"}).lower(x).compile()
 
 
 def test_compiled_update_takes_grouped_queries_to_the_fused_kernel(

@@ -440,6 +440,81 @@ def test_torso_layout_instant_says_what_the_chip_holds(
             "mamba_heads=2/4 groups=1/2") in err
 
 
+@pytest.mark.parametrize("site,torso", [
+    ("trainer", "glm47_flash_tiny"), ("rollout", "glm47_flash_tiny"),
+    ("trainer", "nemotron_h_tiny"), ("rollout", "nemotron_h_tiny"),
+    ("trainer", "dueling")])
+def test_grouped_path_instant_says_what_the_grouped_kernel_is_handed(
+        site, torso, tmp_path, monkeypatch, capfd):
+    """Whoever builds a torso with an expert layer leaves one
+    ``grouped_path`` instant in the ring and one line on stderr, beside
+    ``attention_path``: here, on a CPU, the widths as they are through
+    ``ragged_dot`` and no tile; a dueling network says nothing."""
+    from apex_tpu.config import (ActorConfig, ApexConfig, EnvConfig,
+                                 LearnerConfig, ReplayConfig)
+    from apex_tpu.training.anakin import make_anakin_engine
+    from apex_tpu.training.apex import ApexTrainer
+
+    tokens = torso != "dueling"
+    cfg = ApexConfig(
+        env=EnvConfig(env_id="ApexTokens-v0" if tokens
+                      else "ApexCatchSmall-v0",
+                      frame_stack=1 if tokens else 2, clip_rewards=False,
+                      episodic_life=False,
+                      **({"token_context": 32} if "nemotron" in torso
+                         else {})),
+        replay=ReplayConfig(capacity=256, warmup=32),
+        learner=LearnerConfig(batch_size=8, compute_dtype="float32",
+                              torso=torso),
+        actor=ActorConfig(n_actors=1, n_envs_per_actor=2, send_interval=16))
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        if site == "trainer":
+            ApexTrainer(cfg, pool=ScriptedPool([]), respawn_workers=False)
+        else:
+            make_anakin_engine(cfg, rollout_len=4)
+        found = [ev["args"] for ev
+                 in obs_trace.get_ring().to_chrome()["traceEvents"]
+                 if ev.get("name") == "grouped_path"]
+    finally:
+        obs_trace.reset_for_tests()
+    err = capfd.readouterr().err
+    if not tokens:
+        assert found == [] and "grouped_path" not in err
+        return
+    assert found == [{"site": site, "torso": torso, "hidden": 64,
+                      "width": 32, "platform": "cpu", "hidden_handed": 64,
+                      "width_handed": 32, "tile_k": 0, "tile_n": 0,
+                      "impl": "ragged_dot"}]
+    assert (f"grouped_path site={site} torso={torso} hidden=64 width=32 "
+            "platform=cpu hidden_handed=64 width_handed=32") in err
+
+
+@pytest.mark.parametrize("torso,platform,want", [
+    # 512 does not divide the published widths: the tiled kernel
+    ("nemotron_twotower_ep16", "tpu",
+     dict(hidden=2688, width=1856, hidden_handed=2688, width_handed=1920,
+          tile_k=896, tile_n=640, impl="megablox_gmm")),
+    # left alone: 512 divides both; under one tile; no TPU
+    ("glm47_flash_ep8", "tpu",
+     dict(hidden=2048, width=1536, hidden_handed=2048, width_handed=1536,
+          tile_k=512, tile_n=512, impl="ragged_dot")),
+    ("nemotron_h_tiny", "tpu",
+     dict(hidden=64, width=32, hidden_handed=64, width_handed=32,
+          tile_k=128, tile_n=128, impl="ragged_dot")),
+    ("nemotron_twotower_ep16", "cpu",
+     dict(hidden=2688, width=1856, hidden_handed=2688, width_handed=1856,
+          tile_k=0, tile_n=0, impl="ragged_dot"))])
+def test_grouped_path_of_the_presets(torso, platform, want):
+    """What each family's published preset says of its grouped products
+    in a program compiled for ``platform``."""
+    from apex_tpu.models import make_q_network
+    m = make_q_network(dict(torso=torso, num_actions=64))
+    assert m.grouped_path(platform) == dict(want, platform=platform)
+
+
 def _hlo_ops(lowered) -> list[tuple[str, str]]:
     """``(opcode, op_name)`` of every instruction of the compiled program
     (compiled: XLA's inliner is what prefixes the operations of a called
