@@ -254,7 +254,10 @@ class MoE(_Base):
     """One shared expert and this chip's share of the routed experts, for
     every token torso: what differs between models is a field (``act``:
     gated three-matrix ``silu`` experts or two-matrix ``relu^2`` ones; the
-    shared expert's width; the counts; the scaling)."""
+    shared expert's width; the counts; the scaling; ``scoring``, the rule
+    that turns the router's outputs into picks and weights, see
+    :meth:`route`; ``shared_gate``, whether the shared expert's output is
+    multiplied by ``sigmoid(h w_g)``, a learned scalar a token)."""
 
     width: int = 1536
     n_routed_experts: int = 64
@@ -265,6 +268,8 @@ class MoE(_Base):
     expert_rows: int = 0        # rows a round; 0 = a quarter of the pairs
     act: str = "swiglu"         # or "relu2" (:class:`FeedForward`)
     shared_width: int = 0       # 0 = the routed experts' width
+    scoring: str = "sigmoid_bias"       # or "softmax" (:meth:`route`)
+    shared_gate: bool = False   # the shared expert times sigmoid(h w_g)
 
     def grouped(self, xs, w, group_sizes, live, tiles=None):
         """``xs[i] @ w[g(i)]`` -> float32 for the rows the groups cover,
@@ -297,15 +302,24 @@ class MoE(_Base):
 
     def route(self, h32):
         """``(picks i32[N, k], weights f32[N, k])`` over ALL routed
-        experts: sigmoid scores, the ``k`` largest of score + bias, weights
-        ``scaling * s_i / sum_picked s_j``.  The bias only selects."""
+        experts, by the layer's scoring rule, in float32.
+        ``"sigmoid_bias"``: sigmoid scores, the ``k`` largest of score +
+        bias, a learned vector that only selects.  ``"softmax"``: a softmax
+        over all the router's outputs, its ``k`` largest, and no bias (the
+        layer has no such leaf).  Either way the weights are ``scaling *
+        s_i / sum_picked s_j``."""
         e = self.n_routed_experts
         w_r = self.param("router_kernel", _normal(), (h32.shape[-1], e))
-        bias = self.param("router_bias", nn.initializers.zeros, (e,))
-        s = jax.nn.sigmoid(jnp.dot(h32, w_r,
-                                   precision=jax.lax.Precision.HIGHEST))
-        _, picks = jax.lax.top_k(s + jax.lax.stop_gradient(bias),
-                                 self.num_experts_per_tok)
+        logits = jnp.dot(h32, w_r, precision=jax.lax.Precision.HIGHEST)
+        if self.scoring == "softmax":
+            s = ranked = jax.nn.softmax(logits, axis=-1)
+        elif self.scoring == "sigmoid_bias":
+            bias = self.param("router_bias", nn.initializers.zeros, (e,))
+            s = jax.nn.sigmoid(logits)
+            ranked = s + jax.lax.stop_gradient(bias)
+        else:
+            raise ValueError(f"scoring rule {self.scoring!r}")
+        _, picks = jax.lax.top_k(ranked, self.num_experts_per_tok)
         picked = jnp.take_along_axis(s, picks, axis=-1)
         weights = (self.routed_scaling_factor * picked
                    / picked.sum(-1, keepdims=True))
@@ -364,6 +378,9 @@ class MoE(_Base):
         with jax.named_scope("shared_expert"):
             y = FeedForward(dt, self.shared_width or self.width, 0, self.act,
                             name="shared")(h32)
+            if self.shared_gate:
+                y = y * jax.nn.sigmoid(
+                    Linear(dt, 1, name="shared_gate")(h32, jnp.float32))
         # router: scores, picks, and the sort / gather / scatter that take
         # pairs to their experts and back (``experts``: the products alone)
         with jax.named_scope("router"):

@@ -87,7 +87,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "routed relu^2 experts, by its layer pattern) at "
                         "published widths, one of 16 chips' share of each "
                         "layer, 'nemotron_h_tiny' its toy "
-                        "(apex_tpu/models/nemotron_h.py)")
+                        "(apex_tpu/models/nemotron_h.py); "
+                        "'qwen3_next_80b_ep16' is Qwen3-Next-80B-A3B's "
+                        "stack (three Gated DeltaNet mixers to one gated "
+                        "attention, each with a shared and 10 of 512 "
+                        "softmax-routed experts) at published widths, one "
+                        "of 16 chips' share of each layer, "
+                        "'qwen3_next_tiny' its toy "
+                        "(apex_tpu/models/qwen3_next.py)")
     p.add_argument("--token-vocab", type=int, default=0,
                    help="ApexTokens-v0 under a token torso: the ids the "
                         "env draws from, which are the actions and the "

@@ -3,8 +3,8 @@
 ``benchmark/tests/test_harness.py`` (the driver's tier-1 command collects
 ``tests/`` only) keeps its 17 cases where a ``benchmark`` PR edits them;
 this module imports them, fixtures included, so that every PR runs them.
-Plus the token cells' CPU rehearsals: ``glmq_ondevice`` and
-``twotowerq_ondevice`` through ``build()``, ``checked_steps()``,
+Plus the token cells' CPU rehearsals: ``glmq_ondevice``,
+``twotowerq_ondevice`` and ``qnextq_ondevice`` through ``build()``, ``checked_steps()``,
 ``reference()`` and ``judge()`` read ``correct``, and read not ``correct``
 with the routed experts left out of the program's side.
 """
@@ -67,6 +67,11 @@ def glmq():
 @pytest.fixture(scope="module")
 def twotowerq():
     return _rehearsal("twotowerq_ondevice", 3_300_000_014)
+
+
+@pytest.fixture(scope="module")
+def qnextq():
+    return _rehearsal("qnextq_ondevice", 3_500_000_016)
 
 
 def _verdict(run):
@@ -160,3 +165,78 @@ def test_twotowerq_ondevice_without_its_routed_experts_is_not_correct(
     # stay where they were, which the worst leaf's change reads as 1.0
     assert numbers["dparam_gap"][0] == pytest.approx(1.0)
     assert numbers["dparam_gap"][0] > numbers["dparam_gap"][1]
+
+
+def test_qnextq_ondevice_rehearsal_reads_correct(qnextq):
+    """The Qwen3-Next cell on the CPU at the toy preset: the family's
+    reference, costs and scope table are found by the config's ``family``,
+    the trainer acts on the device, holds its share, and the three checked
+    updates through the window's own programs (the chunked delta rule, each
+    part of a layer over blocks of contexts) agree with the float32
+    reference (the recurrence) inside the cell's limits."""
+    run = qnextq
+    assert run.config["family"] == "qwen3_next_q"
+    assert run.family.__name__ == "benchmark.reference.qwen3_next_q"
+    assert run.cfg.learner.torso == "qwen3_next_tiny"
+    assert run.cfg.env.token_context == 32
+    assert run.cfg.env.token_vocab == run.config["check"]["action_count"]
+    assert type(run.trainer.pool).__name__ == "AnakinPool"
+    layout = run.trainer.model.torso_layout()
+    assert (layout["pattern"], layout["key_heads"], layout["experts"]) == (
+        "DDDA", "2/4", "2/32")
+    run.checked_steps()
+    ok, numbers = _verdict(run)
+    assert ok, numbers
+    assert set(numbers) == {"writeback_miss", "loss_gap", "grad_median_gap",
+                            "dparam_median_gap", "dparam_gap"}
+    assert run.trainer._fused._cache_size() == 1
+    assert run.trainer._train._cache_size() == 1
+    # the benchmark's own counts of the configuration it runs
+    from benchmark import costs, family_scopes
+    cost = costs.step_cost(run.config)
+    assert cost["params"] == 561_458_144
+    assert costs.program_cost(run.config, dict(
+        learner_steps=1, acting_forwards=16))["flops"] > cost["flops"]
+    assert "delta" in family_scopes.table_for(run.config["family"]).SCOPES
+    # every catalog key the configuration's file holds is the published
+    # one, or is listed as reduced
+    import json
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "qwen3_next_q_ep16")
+    assert set(entry["reduced"]) == set(run.config["reduced_why"])
+    assert set(run.config["published"]) == set(entry["reduced"])
+
+
+def test_qnextq_ondevice_without_its_routed_experts_is_not_correct(qnextq):
+    """The fault ``readings_big.py`` plants swaps the ONE expert layer's
+    ``routed``: it reaches this torso's softmax-routed experts too."""
+    run = qnextq
+    real = run.trainer._fused, run.trainer._train
+    put_back = _load("readings_big").plant_left_out_experts(run)
+    try:
+        run.reset_state(3_500_000_015)
+        run.checked_steps()
+        ok, numbers = _verdict(run)
+    finally:
+        put_back()
+        run.trainer._fused, run.trainer._train = real
+    assert not ok, numbers
+    assert numbers["dparam_gap"][0] == pytest.approx(1.0)
+    assert numbers["dparam_gap"][0] > numbers["dparam_gap"][1]
+
+
+def test_qnextq_ondevice_rehearsal_reads_correct_where_a_pick_flipped(qnextq):
+    """The seed on which the toy reads highest: its worst leaf is a
+    router's (change off by 0.065, as a top-k pick that fell the other way
+    between the program's scores and the float32 ones would leave it) and
+    its median leaf's change reads 0.0027: inside the cell's limits all the
+    same."""
+    run = qnextq
+    run.reset_state(3_500_000_014)
+    run.checked_steps()
+    ok, numbers = _verdict(run)
+    assert ok, numbers
+    assert numbers["dparam_gap"][2].endswith("router_kernel")
+    assert 0.02 < numbers["dparam_gap"][0] < numbers["dparam_gap"][1]
+    assert 0.002 < numbers["dparam_median_gap"][0]

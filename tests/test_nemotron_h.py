@@ -504,6 +504,45 @@ def test_torso_layout_says_what_the_chip_holds():
     assert model().attention_path("tpu")["fused"] == 0
 
 
+#: the toy's gradient program as ``jax.jit(...).lower(...).as_text()`` gives
+#: it, hashed at commit a38378e, before PR 35 gave the shared expert layer
+#: its ``scoring`` and ``shared_gate`` fields, with jax 0.9.0 (the GLM
+#: torso's case is ``tests/test_glm4_moe_lite.py``'s)
+UPDATE_SHA256 = {"float32": "7b169c1e87301b2a", "bfloat16": "14f648587e1e36c3"}
+UPDATE_JAX = "0.9.0"
+
+
+@pytest.mark.skipif(jax.__version__ != UPDATE_JAX,
+                    reason=f"hashed as jax {UPDATE_JAX} lowers it")
+@pytest.mark.parametrize("dtype", sorted(UPDATE_SHA256))
+def test_lowered_update_is_the_program_it_was(dtype):
+    """A third family on the shared expert layer left this torso's
+    lowered update text-identical.  A PR that changes this program on
+    purpose records the new hash here and says so."""
+    import hashlib
+    m = model(jnp.dtype(dtype))
+    p, target = seeded(m, 11), seeded(m, 12)
+    rng = np.random.default_rng(3)
+    batch = dict(
+        obs=jnp.asarray(rng.integers(0, 256, (4, 2 * T), dtype=np.uint8)),
+        next_obs=jnp.asarray(rng.integers(0, 256, (4, 2 * T),
+                                          dtype=np.uint8)),
+        action=jnp.asarray(rng.integers(0, V, 4).astype(np.int32)),
+        reward=jnp.asarray(rng.normal(0, .5, 4).astype(np.float32)),
+        discount=jnp.full((4,), 0.97, jnp.float32))
+
+    def grads(params, target, batch, weights):
+        (loss, _out), g = jax.value_and_grad(lambda q: double_dqn_loss(
+            learner_apply_fn(m), q, target, batch, weights),
+            has_aux=True)(params)
+        return loss, g
+
+    text = jax.jit(grads).lower(p, target, batch,
+                                jnp.linspace(.5, 1., 4)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        UPDATE_SHA256[dtype]
+
+
 # -- (h) grouped queries in the fused kernel -------------------------------------
 
 @functools.cache
@@ -598,10 +637,11 @@ def _tpu_update_hlo(preset: str = PRESET, rows: int = B) -> str:
     return jax.jit(grads).lower(p, p, batch, weights).compile().as_text()
 
 
-def _instructions(hlo: str) -> list[tuple[str, str, set[str]]]:
+def _instructions(hlo: str, module: str = "models/nemotron_h.py"
+                  ) -> list[tuple[str, str, set[str]]]:
     """``(name, op_name, functions)`` of every instruction that carries
-    metadata: ``functions`` are the functions of this repo's
-    ``nemotron_h.py`` on the instruction's stack of frames (the module's
+    metadata: ``functions`` are the functions of this repo's ``module``
+    (``nemotron_h.py``) on the instruction's stack of frames (the module's
     ``FileNames`` / ``FunctionNames`` / ``FileLocations`` / ``StackFrames``
     tables, by ``stack_frame_id``)."""
     import re
@@ -630,7 +670,7 @@ def _instructions(hlo: str) -> list[tuple[str, str, set[str]]]:
             seen.add(frame)
             location, parent = frames[frame]
             file_id, function_id = locations[location]
-            if files[file_id].strip('"').endswith("models/nemotron_h.py"):
+            if files[file_id].strip('"').endswith(module):
                 out.add(functions[function_id].strip('"'))
             frame = parent
         return out

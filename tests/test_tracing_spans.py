@@ -506,13 +506,74 @@ def test_grouped_path_instant_says_what_the_grouped_kernel_is_handed(
           tile_k=128, tile_n=128, impl="ragged_dot")),
     ("nemotron_twotower_ep16", "cpu",
      dict(hidden=2688, width=1856, hidden_handed=2688, width_handed=1856,
-          tile_k=0, tile_n=0, impl="ragged_dot"))])
+          tile_k=0, tile_n=0, impl="ragged_dot")),
+    # 512 divides 2,048 and IS the experts' width: left on ``ragged_dot``
+    ("qwen3_next_80b_ep16", "tpu",
+     dict(hidden=2048, width=512, hidden_handed=2048, width_handed=512,
+          tile_k=512, tile_n=512, impl="ragged_dot"))])
 def test_grouped_path_of_the_presets(torso, platform, want):
     """What each family's published preset says of its grouped products
     in a program compiled for ``platform``."""
     from apex_tpu.models import make_q_network
     m = make_q_network(dict(torso=torso, num_actions=64))
     assert m.grouped_path(platform) == dict(want, platform=platform)
+
+
+@pytest.mark.parametrize("site", ["trainer", "rollout"])
+def test_the_third_family_says_its_layout_and_its_paths(
+        site, tmp_path, monkeypatch, capfd):
+    """Whoever builds the Qwen3-Next torso leaves one ``torso_layout``,
+    one ``grouped_path`` and one ``attention_path`` instant in the ring
+    and a line each on stderr: the layer pattern, the heads and experts
+    held over published, the chunk and how the chunk's triangular matrix
+    is inverted."""
+    from apex_tpu.config import (ActorConfig, ApexConfig, EnvConfig,
+                                 LearnerConfig, ReplayConfig)
+    from apex_tpu.training.anakin import make_anakin_engine
+    from apex_tpu.training.apex import ApexTrainer
+
+    torso = "qwen3_next_tiny"
+    cfg = ApexConfig(
+        env=EnvConfig(env_id="ApexTokens-v0", frame_stack=1,
+                      clip_rewards=False, episodic_life=False,
+                      token_context=32),
+        replay=ReplayConfig(capacity=256, warmup=32),
+        learner=LearnerConfig(batch_size=8, compute_dtype="float32",
+                              torso=torso),
+        actor=ActorConfig(n_actors=1, n_envs_per_actor=2, send_interval=16))
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        if site == "trainer":
+            ApexTrainer(cfg, pool=ScriptedPool([]), respawn_workers=False)
+        else:
+            make_anakin_engine(cfg, rollout_len=4)
+        events = obs_trace.get_ring().to_chrome()["traceEvents"]
+    finally:
+        obs_trace.reset_for_tests()
+    by_name = {name: [ev["args"] for ev in events if ev.get("name") == name]
+               for name in ("torso_layout", "grouped_path", "attention_path")}
+    err = capfd.readouterr().err
+    layout = by_name["torso_layout"]
+    assert layout == [{
+        "site": site, "torso": torso, "pattern": "DDDA", "key_heads": "2/4",
+        "value_heads": "4/8", "attn_heads": "2/4", "kv_heads": "1/2",
+        "experts": "2/32", "expert_rank": 0, "chunk": 8,
+        "inverse": "nilpotent_doubling_f32", "params": layout[0]["params"]}]
+    assert layout[0]["params"] > 0
+    assert (f"torso_layout site={site} torso={torso} pattern=DDDA "
+            "key_heads=2/4 value_heads=4/8") in err
+    assert "inverse=nilpotent_doubling_f32" in err
+    assert by_name["grouped_path"] == [{
+        "site": site, "torso": torso, "hidden": 64, "width": 32,
+        "platform": "cpu", "hidden_handed": 64, "width_handed": 32,
+        "tile_k": 0, "tile_n": 0, "impl": "ragged_dot"}]
+    assert by_name["attention_path"] == [{
+        "site": site, "torso": torso, "fused": 0, "context": 32,
+        "qk_head_dim": 16, "v_head_dim": 16, "platform": "cpu"}]
+    assert f"grouped_path site={site} torso={torso}" in err
+    assert f"attention_path site={site} torso={torso} fused=0" in err
 
 
 def _hlo_ops(lowered) -> list[tuple[str, str]]:
@@ -1127,6 +1188,144 @@ def test_both_torso_readers_agree_on_the_names_they_share(shared_plane,
     # each leaves the other family's own operation under no scope
     assert set(glm["jit_fused_step"]["rest"]) == {"%fusion.6"}
     assert set(nem["jit_fused_step"]["rest"]) == {"%fusion.7"}
+
+
+@pytest.fixture(scope="module")
+def qnext_ctx(tmp_path_factory):
+    """The device plane of one update of the Qwen3-Next torso, and the
+    context a metric's reader is handed over it."""
+    from benchmark import costs
+
+    step = "jit(fused_step)/update/loss_grad/"
+    fwd = step + "jvp(Qwen3NextQ)/layers_0/while/body/checkpoint/mixer/gdn/"
+    bwd = (step + "transpose(jvp(Qwen3NextQ))/layers_0/while/body/"
+           "checkpoint/rematted_computation/mixer/gdn/")
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(123)", []),
+        10: ("%fusion.1 = bf16[8,1024,6144] fusion(",
+             [_stat("tf_op", fwd + "in_proj_qkvz/dot_general:")]),
+        11: ("%fusion.2 = f32[8,1024,4096] fusion(",
+             [_stat("tf_op", fwd + "conv/jit(silu)/mul:")]),
+        12: ("%fusion.3 = f32[8,16,8,2,64,64] fusion(", [_stat(
+            "tf_op", fwd + "delta/bnhik,bnhjk->bnhij/dot_general:")]),
+        13: ("%while.4 = (s32[], f32[8,8,2,128,128]) while(",
+             [_stat("tf_op", bwd + "delta/while:")]),
+        14: ("%fusion.5 = f32[8,8,2,128,128] fusion(", []),  # in the while
+        15: ("%fusion.6 = f32[8,8,1024,256] fusion(", [_stat(
+            "tf_op", step + "jvp(Qwen3NextQ)/layers_3/while/body/checkpoint/"
+            "mixer/gated_attention/attention/btd,dhk->bhtk/dot_general:")]),
+        16: ("%ragged-dot-none.1 = f32[20480,512]{1,0} custom-call(",
+             [_stat("tf_op", "ragged-dot-none")]),
+        17: ("%fusion.7 = f32[8192,2048] fusion(", [_stat(
+            "tf_op", step + "jvp(Qwen3NextQ)/layers_1/while/body/checkpoint/"
+            "experts/moe/router/moe.routed/checkpoint/mul:")]),
+        18: ("%fusion.8 = f32[2048] fusion(",
+             [_stat("tf_op", "jit(fused_step)/update/optimizer/mul:")]),
+    }, {
+        "XLA Modules": (0, [(1, 100, 1000, [])]),
+        "XLA Ops": (0, [
+            (10, 100, 100, []), (11, 200, 50, []), (12, 250, 200, []),
+            (13, 450, 100, []), (14, 460, 80, []), (15, 550, 150, []),
+            (16, 700, 120, []), (17, 820, 30, []), (18, 850, 100, [])]),
+    })
+    path = tmp_path_factory.mktemp("qnext") / "qnext.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, _plane("/host:metadata", {}, {}))))
+    planes = spans.read_xspace(str(path))
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3_next_q_ep16.json")) as f:
+        config = json.load(f)
+    said = []
+    return dict(_spans={"planes": planes, "ring": []},
+                traffic={"step_programs": {
+                    "jit_fused_step": {"learner_steps": 1}}},
+                trace={"busy_s": 850e-6}, config=config,
+                peaks=costs.peaks_for("TPU v5 lite"), say=said.append,
+                said=said)
+
+
+def test_family_scopes_reduce_by_the_innermost_name_of_the_table(qnext_ctx):
+    """An operation counts under the innermost of the family's names on
+    its path (``delta`` and ``conv`` inside ``gdn``, ``experts`` inside
+    ``router``), the grouped products by their own name; a plane of
+    another family's program has no device time under this family's own
+    scopes."""
+    from benchmark import family_scopes
+
+    table = family_scopes.table_for("qwen3_next_q")
+    planes = qnext_ctx["_spans"]["planes"]
+    got = family_scopes.reduce_planes(table, planes, ("jit_fused_step",))[
+        "jit_fused_step"]
+    us = 1e-6
+    assert got["calls"] == 1
+    assert {k: round(v / us, 2) for k, v in got["scopes"].items() if v} == {
+        "gdn": 100.0, "conv": 50.0, "delta": 300.0,
+        "gated_attention": 150.0, "experts": 120.0, "router": 30.0}
+    assert got["kernels_s"] == pytest.approx(120 * us)
+    assert {k: round(v / us, 2) for k, v in got["rest"].items()} == {
+        "%fusion.8": 100.0}
+    assert family_scopes.scope_of(
+        table, "jit(f)/transpose(jvp(delta))/mul:") == "delta"
+    other = family_scopes.reduce_planes(
+        table, spans.read_xspace(os.path.join(FIXTURES, "scoped.xplane.pb")),
+        ("jit_fused_step", "jit_train_step"))
+    assert other and not any(
+        p["scopes"][s] for p in other.values()
+        for s in ("gdn", "conv", "delta", "gated_attention"))
+
+
+@pytest.mark.parametrize("metric,want_us", [
+    ("gdn_ms", 450.0), ("delta_ms", 300.0), ("gated_attention_ms", 150.0),
+    ("delta_roofline", None)])
+def test_the_third_familys_metrics_read_their_scopes(qnext_ctx, metric,
+                                                     want_us):
+    """``gdn_ms`` is the scope with what lies inside it, ``delta_ms`` the
+    rule alone, ``delta_roofline`` the rule's least time by the family's
+    counts (memory-bound) over its device time; over a run that left no
+    trace each reads nothing and does not raise."""
+    from benchmark import costs
+
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(spans, "load", lambda c: c["_spans"])
+    try:
+        got = _reader(metric).read(qnext_ctx)
+        bare = dict(qnext_ctx, _spans={"planes": None, "ring": []})
+        assert _reader(metric).read(bare) is None
+    finally:
+        monkey.undo()
+    if want_us is not None:
+        assert got == pytest.approx(want_us / 1000.0)
+        return
+    fam = costs.family_costs("qwen3_next_q")
+    shapes = qnext_ctx["config"]["shapes"]
+    least = fam.delta_bytes(shapes, 16_384, 6 * 3) / 819e9
+    assert least > 2 * 18 * fam.delta_macs(shapes, 16_384) / 197e12
+    assert got == pytest.approx(100.0 * least / 300e-6)
+    assert any("torso scopes hold" in line for line in qnext_ctx["said"])
+
+
+@pytest.mark.parametrize("scope", SHARED_SCOPES)
+@pytest.mark.parametrize("family", ["glm", "nemotron"])
+def test_the_one_reader_reads_what_the_two_copies_read(shared_plane, family,
+                                                       scope):
+    """``family_scopes.py`` handed the other two families' names as a
+    table reads, on the same plane, the seconds their own readers read: a
+    ``benchmark`` issue can fold ``torso_scopes.py`` and
+    ``nemotron_h_scopes.py`` into it (PERF.md section 7)."""
+    import types
+
+    from benchmark import family_scopes, nemotron_h_scopes, torso_scopes
+
+    copy = {"glm": torso_scopes, "nemotron": nemotron_h_scopes}[family]
+    table = types.SimpleNamespace(
+        SCOPES=getattr(copy, "TORSO", None) or copy.SCOPES,
+        KERNELS=copy.KERNELS, INSIDE=getattr(copy, "INSIDE", {}))
+    one = family_scopes.reduce_planes(table, shared_plane,
+                                      ("jit_fused_step",))["jit_fused_step"]
+    two = copy.reduce_planes(shared_plane,
+                             ("jit_fused_step",))["jit_fused_step"]
+    assert one["scopes"][scope] == pytest.approx(two["scopes"][scope])
+    assert one["scopes"][scope] > 0
+    assert one["rest"] == two["rest"] and one["seconds"] == two["seconds"]
 
 
 # -- the trace recorded on the chip ------------------------------------------------------
