@@ -16,9 +16,10 @@ n_held)``), routes over all ``n_routed_experts`` and adds nothing for the
 absent ones: no stand-in for other chips, no exchange, no dropped pair and
 no capacity factor.  The (token, expert) pairs that land on held experts are
 sorted by expert and go through a grouped matrix product
-(:func:`jax.lax.ragged_dot`) in rounds of ``expert_rows`` rows; a round
-past the first runs only when routing filled the rounds before it
-(``lax.cond``), so uneven routing costs time, never a pair.  Inside a
+(:func:`jax.lax.ragged_dot`) in rounds (:func:`expert_rounds`: the first
+sized by the held experts' share of the pairs); a round past the first
+runs only when routing filled the rounds before it (``lax.cond``), so
+uneven routing costs time, never a pair.  Inside a
 round the product goes the way :func:`apex_tpu.ops.grouped.plan` says: in
 a program compiled for a TPU, at widths of 512 or more that 512 does not
 divide, a kernel with tiles of its own is handed the operands with zeros
@@ -250,6 +251,54 @@ class MLA(_Base):
         return mm("bhtd,hdf->btf", o, w_o.reshape(nh, vd, d))
 
 
+def expert_rounds(pairs: int, held: int,
+                  routed: int) -> tuple[tuple[int, ...], ...]:
+    """The rows of each round of :meth:`MoE.routed` over a call's
+    ``pairs`` (token, expert) pairs, which lie sorted with the held
+    experts' first, grouped by the ``lax.cond`` that runs them; together
+    the rounds cover every pair.  Round 0 runs always: twice the held
+    experts' even share of the pairs (``held / routed``), up to a multiple
+    of a grouped product's best row tile (:data:`apex_tpu.ops.grouped.BEST`,
+    so the tiled kernel keeps it), a quarter of the pairs at most.  The
+    later rounds hold a quarter each, the last what is left; each group
+    runs only when routing filled the rounds before it.  Every group hands
+    back a gradient of each stacked expert kernel, so there are at most
+    three later groups, as many as three later rounds of a quarter made,
+    and the rounds past the fourth run in the last group, one after the
+    other (PERF.md, PR 36: by the AOT compile for the chip, one more cond
+    took 0.2-0.36 GB more of the update's temporaries, rounds larger than
+    a quarter 0.02-0.14)."""
+    quarter, tile = max(pairs // 4, 1), grouped.BEST
+    rounds = [min(-(-2 * pairs * held // (routed * tile)) * tile, quarter)]
+    while sum(rounds) < pairs:
+        rounds.append(min(quarter, pairs - sum(rounds)))
+    groups = [(rows,) for rows in rounds[:3]]
+    if rounds[3:]:
+        groups.append(tuple(rounds[3:]))
+    return tuple(groups)
+
+
+def routing_stats(routing, pairs: int) -> dict:
+    """A pass's routing counters over its expert layers' ``(counts,
+    overflow)``: the (token, expert) pairs that landed on held experts,
+    their share of all ``pairs`` (an even router gives held / routed), the
+    busiest held expert's load over the mean, and the rounds past the
+    first that ran (:func:`expert_rounds`)."""
+    load = jnp.sum(jnp.stack([c for c, _ in routing]), 0).astype(jnp.float32)
+    return {"moe_local_pairs": load.sum(),
+            "moe_local_share": load.sum() / pairs,
+            "moe_load_max_over_mean":
+                load.max() / jnp.maximum(load.mean(), 1.0),
+            "moe_overflow_rounds":
+                jnp.sum(jnp.stack([o for _, o in routing])).astype(
+                    jnp.float32)}
+
+
+def no_routing(held: int):
+    """What a layer without experts says of its routing."""
+    return jnp.zeros(held, jnp.int32), jnp.zeros((), jnp.int32)
+
+
 class MoE(_Base):
     """One shared expert and this chip's share of the routed experts, for
     every token torso: what differs between models is a field (``act``:
@@ -257,7 +306,9 @@ class MoE(_Base):
     shared expert's width; the counts; the scaling; ``scoring``, the rule
     that turns the router's outputs into picks and weights, see
     :meth:`route`; ``shared_gate``, whether the shared expert's output is
-    multiplied by ``sigmoid(h w_g)``, a learned scalar a token)."""
+    multiplied by ``sigmoid(h w_g)``, a learned scalar a token).  Returns
+    the layer's output and its routing: the pairs that landed on each held
+    expert and the rounds of :meth:`routed` past the first that ran."""
 
     width: int = 1536
     n_routed_experts: int = 64
@@ -265,7 +316,6 @@ class MoE(_Base):
     expert_rank: int = 0
     num_experts_per_tok: int = 4
     routed_scaling_factor: float = 1.8
-    expert_rows: int = 0        # rows a round; 0 = a quarter of the pairs
     act: str = "swiglu"         # or "relu2" (:class:`FeedForward`)
     shared_width: int = 0       # 0 = the routed experts' width
     scoring: str = "sigmoid_bias"       # or "softmax" (:meth:`route`)
@@ -325,6 +375,14 @@ class MoE(_Base):
                    / picked.sum(-1, keepdims=True))
         return picks.astype(jnp.int32), weights
 
+    def rounds(self, pairs: int) -> list[tuple[int, tuple[int, ...]]]:
+        """``(first pair, rows of each round)`` of each ``lax.cond`` of
+        :meth:`routed` over a call's ``pairs`` (:func:`expert_rounds`)."""
+        groups = expert_rounds(pairs, self.n_held_experts,
+                               self.n_routed_experts)
+        return [(sum(map(sum, groups[:g])), group)
+                for g, group in enumerate(groups)]
+
     def routed(self, h, picks, weights, *kernels):
         """The held experts' part of the layer output, ``f32[N, D]``, and
         the pairs that landed on each of them, ``i32[n_held]``.
@@ -339,15 +397,14 @@ class MoE(_Base):
         ends = jnp.cumsum(counts)
         starts, n_local = ends - counts, ends[-1]
         flat_w = weights.reshape(-1)
-        rows = self.expert_rows or max(n * k // 4, 1)
-        # which kernel, by the platform the program is lowered for; where
-        # no platform changes it, one path and no choice in the program
-        on_tpu = grouped.plan(h.shape[-1], self.width, rows, "tpu")
 
-        def one_round(r):
+        def one_round(a, rows):
+            # which kernel, by the platform the program is lowered for; where
+            # no platform changes it, one path and no choice in the program
+            on_tpu = grouped.plan(h.shape[-1], self.width, rows, "tpu")
+
             @jax.checkpoint
             def run(out, h, *kernels):
-                a = r * rows
                 idx = jax.lax.dynamic_slice_in_dim(order, a, rows)
                 tok = idx // k
                 sizes = jnp.clip(jnp.minimum(ends, a + rows)
@@ -364,11 +421,19 @@ class MoE(_Base):
                 return out.at[tok].add(y)
             return run
 
+        def in_turn(a, group):
+            # one cond's rounds, one after the other from pair ``a``
+            def run(out, h, *kernels):
+                for i, rows in enumerate(group):
+                    out = one_round(a + sum(group[:i]), rows)(out, h, *kernels)
+                return out
+            return run
+
         out = jnp.zeros((n, h.shape[-1]), jnp.float32)
-        for r in range(-(-n * k // rows)):
+        for g, (a, group) in enumerate(self.rounds(n * k)):
             args = (out, h, *kernels)
-            out = one_round(r)(*args) if r == 0 else jax.lax.cond(
-                n_local > r * rows, one_round(r), lambda out, *_: out, *args)
+            out = in_turn(a, group)(*args) if g == 0 else jax.lax.cond(
+                n_local > a, in_turn(a, group), lambda out, *_: out, *args)
         return out, counts
 
     @nn.compact
@@ -394,7 +459,13 @@ class MoE(_Base):
         with jax.named_scope("router"):
             part, counts = self.routed(
                 h32.reshape(b * t, d).astype(dt), picks, weights, *kernels)
-        return y + part.reshape(b, t, d), counts
+        # the rounds past the first that ran: those whose first pair
+        # routing reached, as :meth:`routed` decides it
+        n_local = counts.sum()
+        overflow = jnp.zeros((), jnp.int32)
+        for a, group in self.rounds(picks.size)[1:]:
+            overflow += len(group) * (n_local > a).astype(jnp.int32)
+        return y + part.reshape(b, t, d), (counts, overflow)
 
 
 class Block(_Base):
@@ -419,14 +490,13 @@ class Block(_Base):
             with jax.named_scope("dense_ffn"):
                 y = SwiGLU(dt, c["intermediate_size"],
                            c.get("ffn_block", 0), name="mlp")(h)
-            counts = jnp.zeros(c["n_held_experts"], jnp.int32)
+            routing = no_routing(c["n_held_experts"])
         else:
-            y, counts = MoE(dt, c["moe_intermediate_size"],
-                            c["n_routed_experts"], c["n_held_experts"],
-                            self.expert_rank, c["num_experts_per_tok"],
-                            c["routed_scaling_factor"],
-                            c.get("expert_rows", 0), name="moe")(h)
-        return x + y, counts
+            y, routing = MoE(dt, c["moe_intermediate_size"],
+                             c["n_routed_experts"], c["n_held_experts"],
+                             self.expert_rank, c["num_experts_per_tok"],
+                             c["routed_scaling_factor"], name="moe")(h)
+        return x + y, routing
 
 
 class Glm4MoeLiteQ(nn.Module):
@@ -479,27 +549,19 @@ class Glm4MoeLiteQ(nn.Module):
             x = emb[token_ids(obs, c["vocab_held"])]
         block = nn.remat(Block) if self.remat else Block
         frozen = tuple(sorted(c.items()))
-        loads = []
+        routing = []
         for i in range(c["n_dense_layers"] + c["n_expert_layers"]):
-            x, counts = block(dt, frozen, i < c["n_dense_layers"],
-                              self.expert_rank, name=f"layers_{i}")(x)
-            loads.append(counts)
+            x, r = block(dt, frozen, i < c["n_dense_layers"],
+                         self.expert_rank, name=f"layers_{i}")(x)
+            routing.append(r)
         with jax.named_scope("q_head"):
             last = RMSNorm(c["rms_norm_eps"], name="final_norm")(x[:, -1])
             q = Linear(dt, c["vocab_held"], name="head")(last, jnp.float32)
         if not with_stats:
             return q
-        # routing of this pass over its expert layers: the (token, expert)
-        # pairs that landed on held experts, their share of all pairs (an
-        # even router gives held / routed), the busiest held expert's load
-        # over the mean
-        load = jnp.sum(jnp.stack(loads), 0).astype(jnp.float32)
         pairs = (obs.shape[0] * (obs.shape[1] // 2) * c["n_expert_layers"]
                  * c["num_experts_per_tok"])
-        return q, {"moe_local_pairs": load.sum(),
-                   "moe_local_share": load.sum() / pairs,
-                   "moe_load_max_over_mean":
-                       load.max() / jnp.maximum(load.mean(), 1.0)}
+        return q, routing_stats(routing, pairs)
 
 
 def param_count(preset: str) -> int:

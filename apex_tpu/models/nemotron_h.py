@@ -56,7 +56,8 @@ import numpy as np
 from flax import linen as nn
 
 from apex_tpu.models.glm4_moe_lite import (Linear, MoE, RMSNorm, Weight, _Base,
-                                           _normal, token_ids)
+                                           _normal, no_routing, routing_stats,
+                                           token_ids)
 from apex_tpu.ops import attention, grouped
 
 #: what computes the scan (``torso_layout``'s ``ssd_impl``)
@@ -290,7 +291,7 @@ class Layer(_Base):
         c, dt = dict(self.cfg), self.compute_dtype
         held = held_widths(c)
         u = RMSNorm(c["norm_eps"], name="norm")(x)
-        counts = jnp.zeros(c["n_held_experts"], jnp.int32)
+        routing = no_routing(c["n_held_experts"])
         if self.kind == "M":
             with jax.named_scope("mamba"):
                 y = Mamba2(dt, held["mamba_heads"], c["mamba_head_dim"],
@@ -302,16 +303,15 @@ class Layer(_Base):
                 y = GQA(dt, held["attn_heads"], held["kv_heads"],
                         c["head_dim"], name="attention")(u)
         elif self.kind == "E":
-            y, counts = MoE(dt, c["moe_intermediate_size"],
-                            c["n_routed_experts"], c["n_held_experts"],
-                            self.expert_rank, c["num_experts_per_tok"],
-                            c["routed_scaling_factor"],
-                            c.get("expert_rows", 0), "relu2",
-                            c["moe_shared_expert_intermediate_size"],
-                            name="moe")(u)
+            y, routing = MoE(dt, c["moe_intermediate_size"],
+                             c["n_routed_experts"], c["n_held_experts"],
+                             self.expert_rank, c["num_experts_per_tok"],
+                             c["routed_scaling_factor"], "relu2",
+                             c["moe_shared_expert_intermediate_size"],
+                             name="moe")(u)
         else:
             raise ValueError(f"layer kind {self.kind!r} in the pattern")
-        return x + y, counts
+        return x + y, routing
 
 
 class NemotronHQ(nn.Module):
@@ -379,25 +379,19 @@ class NemotronHQ(nn.Module):
             x = emb[token_ids(obs, c["vocab_held"])]
         layer = nn.remat(Layer) if self.remat else Layer
         frozen = tuple(sorted(c.items()))
-        loads = []
+        routing = []
         for i, kind in enumerate(c["pattern"]):
-            x, counts = layer(dt, frozen, kind, self.expert_rank,
-                              name=f"layers_{i}")(x)
-            loads.append(counts)
+            x, r = layer(dt, frozen, kind, self.expert_rank,
+                         name=f"layers_{i}")(x)
+            routing.append(r)
         with jax.named_scope("q_head"):
             last = RMSNorm(c["norm_eps"], name="final_norm")(x[:, -1])
             q = Linear(dt, c["vocab_held"], name="head")(last, jnp.float32)
         if not with_stats:
             return q
-        # routing of this pass over its expert layers, as the GLM torso
-        # counts it
-        load = jnp.sum(jnp.stack(loads), 0).astype(jnp.float32)
         pairs = (obs.shape[0] * (obs.shape[1] // 2) * c["pattern"].count("E")
                  * c["num_experts_per_tok"])
-        return q, {"moe_local_pairs": load.sum(),
-                   "moe_local_share": load.sum() / pairs,
-                   "moe_load_max_over_mean":
-                       load.max() / jnp.maximum(load.mean(), 1.0)}
+        return q, routing_stats(routing, pairs)
 
 
 def share_of_layer(kind: str, p: dict, c: dict, head_rank: int) -> dict:

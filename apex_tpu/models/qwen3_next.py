@@ -67,7 +67,7 @@ from flax import linen as nn
 
 from apex_tpu.models.glm4_moe_lite import (Linear, MoE, Weight, _Base,
                                            _normal, rms_norm, rope,
-                                           token_ids)
+                                           routing_stats, token_ids)
 from apex_tpu.models.nemotron_h import (_a_log_init, _dt_bias_init,
                                         causal_conv)
 from apex_tpu.ops import attention, grouped
@@ -347,8 +347,7 @@ class Mixer(_Base):
 
 
 class Experts(_Base):
-    """``x + moe(norm(x))`` and the pairs that landed on each held
-    expert."""
+    """``x + moe(norm(x))`` and the block's routing (``MoE``)."""
 
     cfg: Any = None
     expert_rank: int = 0
@@ -356,15 +355,14 @@ class Experts(_Base):
     @nn.compact
     def __call__(self, x):
         c = dict(self.cfg)
-        y, counts = MoE(self.compute_dtype, c["moe_intermediate_size"],
-                        c["num_experts"], c["n_held_experts"],
-                        self.expert_rank, c["num_experts_per_tok"], 1.0,
-                        0, "swiglu",
-                        c["shared_expert_intermediate_size"], "softmax", True,
-                        name="moe")(
-                            ZeroCentredRMSNorm(c["rms_norm_eps"],
-                                               name="norm")(x))
-        return x + y, counts
+        y, routing = MoE(self.compute_dtype, c["moe_intermediate_size"],
+                         c["num_experts"], c["n_held_experts"],
+                         self.expert_rank, c["num_experts_per_tok"], 1.0,
+                         "swiglu", c["shared_expert_intermediate_size"],
+                         "softmax", True, name="moe")(
+                             ZeroCentredRMSNorm(c["rms_norm_eps"],
+                                                name="norm")(x))
+        return x + y, routing
 
 
 class Layer(_Base):
@@ -388,8 +386,8 @@ class Layer(_Base):
 
     def blocked(self, part, x):
         """``part(x)`` over blocks of contexts: the leaves of its output
-        that have ``x``'s shape are laid end to end, the others (counts)
-        summed."""
+        that have ``x``'s shape are laid end to end, the others (the
+        routing counters) summed."""
         def one(mdl, x):
             return mdl(x)
 
@@ -477,26 +475,20 @@ class Qwen3NextQ(nn.Module):
                              (c["vocab_held"], c["hidden_size"]))
             x = emb[token_ids(obs, c["vocab_held"])]
         frozen = tuple(sorted(c.items()))
-        loads = []
+        routing = []
         for i, kind in enumerate(pattern(c)):
-            x, counts = Layer(dt, frozen, kind, self.expert_rank, self.remat,
-                              name=f"layers_{i}")(x)
-            loads.append(counts)
+            x, r = Layer(dt, frozen, kind, self.expert_rank, self.remat,
+                         name=f"layers_{i}")(x)
+            routing.append(r)
         with jax.named_scope("q_head"):
             last = ZeroCentredRMSNorm(c["rms_norm_eps"],
                                       name="final_norm")(x[:, -1])
             q = Linear(dt, c["vocab_held"], name="head")(last, jnp.float32)
         if not with_stats:
             return q
-        # routing of this pass over its expert blocks, as the other token
-        # torsos count it
-        load = jnp.sum(jnp.stack(loads), 0).astype(jnp.float32)
         pairs = (obs.shape[0] * (obs.shape[1] // 2) * c["num_hidden_layers"]
                  * c["num_experts_per_tok"])
-        return q, {"moe_local_pairs": load.sum(),
-                   "moe_local_share": load.sum() / pairs,
-                   "moe_load_max_over_mean":
-                       load.max() / jnp.maximum(load.mean(), 1.0)}
+        return q, routing_stats(routing, pairs)
 
 
 def share_of_layer(kind: str, p: dict, c: dict, head_rank: int) -> dict:

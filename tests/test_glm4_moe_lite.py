@@ -149,6 +149,10 @@ def test_one_update_equals_the_reference_step(params):
     for suffix in ("", "_next", "_target"):
         assert 0 < float(metrics["moe_local_pairs" + suffix]) < pairs
         assert float(metrics["moe_load_max_over_mean" + suffix]) >= 1.0
+        # the rounds past the first that ran, of three a layer
+        overflow = float(metrics["moe_overflow_rounds" + suffix])
+        assert overflow == int(overflow)
+        assert 0 <= overflow <= 3 * C["n_expert_layers"]
 
 
 # -- (c), (d) the expert layer's shares ---------------------------------------
@@ -156,7 +160,7 @@ def test_one_update_equals_the_reference_step(params):
 def moe_layer(held: int, rank: int):
     return glm.MoE(jnp.float32, C["moe_intermediate_size"],
                    C["n_routed_experts"], held, rank,
-                   C["num_experts_per_tok"], C["routed_scaling_factor"], 0)
+                   C["num_experts_per_tok"], C["routed_scaling_factor"])
 
 
 def uncut_moe_params(seed: int):
@@ -190,8 +194,8 @@ def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(grouped_rule):
         {"params": p["shared"]}, h)
     total, pairs = shared, 0
     for rank in range(C["n_routed_experts"] // held):
-        out, counts = moe_layer(held, rank).apply(rank_slice(p, rank, held),
-                                                  h)
+        out, (counts, _) = moe_layer(held, rank).apply(
+            rank_slice(p, rank, held), h)
         total = total + (out - shared)
         pairs += int(counts.sum())
     assert pairs == B * T * C["num_experts_per_tok"]    # every pair, once
@@ -213,9 +217,11 @@ def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(
     p = dict(p, router_bias=p["router_bias"].at[:2].add(10.0))
     h = jax.random.normal(jax.random.key(6), (B, T, C["hidden_size"]))
     held = 2
-    out, counts = moe_layer(held, 0).apply(rank_slice(p, 0, held), h)
+    out, (counts, overflow) = moe_layer(held, 0).apply(
+        rank_slice(p, 0, held), h)
     assert int(counts.sum()) == B * T * C["num_experts_per_tok"]
     np.testing.assert_array_equal(counts, [B * T, B * T])
+    assert int(overflow) == 3       # a quarter of the pairs a round
     np.testing.assert_allclose(
         out, reference_moe(rank_slice(p, 0, held)["params"], h), rtol=1e-4,
         atol=1e-5)
@@ -226,6 +232,61 @@ def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(
     assert all(float(jnp.abs(g[k][e]).max()) > 0
                for k in ("experts_gate", "experts_up", "experts_down")
                for e in range(held))
+
+
+#: the rounds of each family's expert layer at its published widths, by
+#: the ``lax.cond`` that runs them, a call of 16 contexts (the update's
+#: passes and the 16-lane acting call; Qwen3-Next's blocks of 8) and of
+#: one (the call that initialises the parameters): round 0 twice the held
+#: share, later rounds a quarter, the fifth in the fourth's cond
+ROUNDS = {
+    ("glm47_flash_ep8", 16): ((16384,),) * 4,
+    ("glm47_flash_ep8", 1): ((1024,),) * 4,
+    ("nemotron_twotower_ep16", 16): ((12288,), (24576,), (24576,),
+                                     (24576, 12288)),
+    ("nemotron_twotower_ep16", 1): ((1024,), (1536,), (1536,), (1536, 512)),
+    ("qwen3_next_80b_ep16", 16): ((10240,), (20480,), (20480,),
+                                  (20480, 10240)),
+    ("qwen3_next_80b_ep16", 1): ((1536,), (2560,), (2560,), (2560, 1024)),
+}
+
+
+@pytest.mark.parametrize("preset,contexts", sorted(ROUNDS))
+def test_the_first_round_holds_twice_the_held_share(monkeypatch, preset,
+                                                    contexts):
+    """Each expert layer of a published preset, traced at a call's shape:
+    the rounds cover every pair, round 0 is the rule's value (twice the
+    held experts' even share up to a multiple of 512 rows, a quarter of
+    the pairs at most), no round is larger than a quarter and no more
+    conds hand back gradients than four rounds of a quarter did.  At
+    Nemotron's widths every round is one the tiled kernel takes."""
+    from apex_tpu.models import token_preset
+    from apex_tpu.ops import grouped
+    real, calls = glm.expert_rounds, []
+
+    def spied(pairs, held, routed):
+        calls.append((pairs, held, routed, real(pairs, held, routed)))
+        return calls[-1][-1]
+
+    monkeypatch.setattr(glm, "expert_rounds", spied)
+    c = token_preset(preset)
+    m = make_q_network(dict(torso=preset, num_actions=c["vocab_held"],
+                            compute_dtype=jnp.bfloat16))
+    jax.eval_shape(m.init, jax.random.key(0),
+                   jnp.zeros((contexts, 2 * c["context"]), jnp.uint8))
+    # each of the four expert layers asks, for its rounds and its counter
+    # (Qwen3-Next's block loop traces a body twice)
+    assert len(calls) >= 8
+    for pairs, held, routed, groups in calls:
+        assert groups == ROUNDS[preset, contexts]
+        rounds = [rows for group in groups for rows in group]
+        assert sum(rounds) == pairs
+        assert rounds[0] == min(-(-2 * pairs * held // (routed * 512)) * 512,
+                                pairs // 4)
+        assert max(rounds) <= pairs // 4 and len(groups) <= 4
+        if preset.startswith("nemotron"):
+            assert all(grouped.plan(2688, 1856, rows, "tpu") is not None
+                       for rows in rounds)
 
 
 # -- (e) the next-state pass ---------------------------------------------------

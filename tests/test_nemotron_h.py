@@ -208,6 +208,9 @@ def test_one_update_equals_the_reference_step():
     for suffix in ("", "_next", "_target"):
         assert 0 < float(metrics["moe_local_pairs" + suffix]) < pairs
         assert float(metrics["moe_load_max_over_mean" + suffix]) >= 1.0
+        overflow = float(metrics["moe_overflow_rounds" + suffix])
+        assert overflow == int(overflow)
+        assert 0 <= overflow <= 3 * C["pattern"].count("E")
 
 
 @pytest.mark.parametrize("part", ["mamba", "attention", "moe", "embedding"])
@@ -296,7 +299,7 @@ def test_the_head_shares_of_both_ranks_add_up_to_the_uncut_mixer(kind, part):
 def moe_layer(held: int, rank: int):
     return glm.MoE(jnp.float32, C["moe_intermediate_size"],
                    C["n_routed_experts"], held, rank,
-                   C["num_experts_per_tok"], C["routed_scaling_factor"], 0,
+                   C["num_experts_per_tok"], C["routed_scaling_factor"],
                    "relu2", C["moe_shared_expert_intermediate_size"])
 
 
@@ -334,8 +337,8 @@ def test_the_expert_shares_of_all_ranks_add_up_to_the_uncut_layer(
         "relu2").apply({"params": p["shared"]}, h)
     total, pairs = shared, 0
     for rank in range(C["n_routed_experts"] // held):
-        out, counts = moe_layer(held, rank).apply(rank_slice(p, rank, held),
-                                                  h)
+        out, (counts, _) = moe_layer(held, rank).apply(
+            rank_slice(p, rank, held), h)
         total = total + (out - shared)
         pairs += int(counts.sum())
     assert pairs == B * T * C["num_experts_per_tok"]    # every pair, once
@@ -353,8 +356,9 @@ def test_no_pair_is_dropped_with_two_matrix_experts(grouped_rule):
     h = jax.random.normal(jax.random.key(6), (B, T, D))
     held = 2
     cut = rank_slice(p, 0, held)
-    out, counts = moe_layer(held, 0).apply(cut, h)
+    out, (counts, overflow) = moe_layer(held, 0).apply(cut, h)
     np.testing.assert_array_equal(counts, [B * T, B * T])
+    assert int(overflow) == 3       # a quarter of the pairs a round
     np.testing.assert_allclose(out, reference_moe(cut["params"], h),
                                rtol=1e-4, atol=1e-5)
     g = jax.grad(lambda q: moe_layer(held, 0).apply(

@@ -288,6 +288,8 @@ def test_one_update_equals_the_reference_step():
     for suffix in ("", "_next", "_target"):
         assert 0 < float(metrics["moe_local_pairs" + suffix]) < pairs
         assert float(metrics["moe_load_max_over_mean" + suffix]) >= 1.0
+        # 2 of 32 held: round 0, a quarter of the pairs here, holds them
+        assert float(metrics["moe_overflow_rounds" + suffix]) == 0.0
 
 
 @pytest.mark.parametrize("part", ["gdn", "attention", "moe", "embedding"])
@@ -374,7 +376,7 @@ def test_the_head_shares_of_both_ranks_add_up_to_the_uncut_mixer(kind, part):
 def moe_layer(held: int, rank: int, routed: int = C["num_experts"],
               k: int = C["num_experts_per_tok"]):
     return glm.MoE(jnp.float32, C["moe_intermediate_size"], routed, held,
-                   rank, k, 1.0, 0, "swiglu",
+                   rank, k, 1.0, "swiglu",
                    C["shared_expert_intermediate_size"], "softmax", True)
 
 
@@ -419,8 +421,8 @@ def test_the_expert_shares_of_all_16_ranks_add_up_to_the_uncut_block(
     np.testing.assert_allclose(shared, want_shared, rtol=1e-4, atol=1e-5)
     total, pairs = shared, 0
     for rank in range(C["num_experts"] // held):
-        out, counts = moe_layer(held, rank).apply(rank_slice(p, rank, held),
-                                                  h)
+        out, (counts, _) = moe_layer(held, rank).apply(
+            rank_slice(p, rank, held), h)
         total = total + (out - shared)
         pairs += int(counts.sum())
     assert C["num_experts"] // held == 16
@@ -446,7 +448,7 @@ def test_no_pair_is_dropped_at_32_held_groups_under_skewed_routing(
         0, 4:].set(0.0)
     cut = rank_slice(p, 0, held)
     layer = moe_layer(held, 0, routed, k)
-    out, counts = layer.apply(cut, h)
+    out, (counts, _) = layer.apply(cut, h)
     np.testing.assert_array_equal(counts, [B * T] * 4 + [0] * 28)
     np.testing.assert_allclose(out, reference_moe(cut["params"], h, k),
                                rtol=1e-4, atol=1e-5)
@@ -455,6 +457,46 @@ def test_no_pair_is_dropped_at_32_held_groups_under_skewed_routing(
     for name in ("experts_gate", "experts_up", "experts_down"):
         assert all(float(jnp.abs(g[name][e]).max()) > 0 for e in range(4))
         assert float(jnp.abs(g[name][4:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("routing,ran", [("onto_held", [512, 1024, 1024]),
+                                         ("even", [512])])
+def test_nothing_is_dropped_once_round_0_overflows(monkeypatch, routing,
+                                                   ran):
+    """The toy's 2 of 32 held experts over 1,024 tokens: 4,096 pairs,
+    round 0 of 512 rows (twice the even 256), later rounds of 1,024.  A
+    router that gives every token both held experts fills 2,048 rows:
+    rounds 1 and 2 run, 3 and 4 do not, ``overflow`` says 2, the layer
+    equals the float32 reference and the gradient reaches every held
+    expert.  Under the router as drawn round 0 holds every held pair."""
+    held = C["n_held_experts"]
+    p = uncut_moe_params(23)
+    h = jax.random.normal(jax.random.key(7), (4, 256, D))
+    if routing == "onto_held":
+        h = h.at[..., 0].set(3.0)
+        p["router_kernel"] = p["router_kernel"].at[0, :held].set(10.0).at[
+            0, held:].set(0.0)
+    cut = rank_slice(p, 0, held)
+    layer = moe_layer(held, 0)
+    rows, real = [], glm.MoE.experts
+
+    def counted(self, xs, *args, **kw):
+        jax.debug.callback(lambda: rows.append(xs.shape[0]))
+        return real(self, xs, *args, **kw)
+
+    monkeypatch.setattr(glm.MoE, "experts", counted)
+    out, (counts, overflow) = layer.apply(cut, h)
+    assert rows == ran                      # the rounds that ran, in order
+    assert int(overflow) == len(ran) - 1
+    local = int(counts.sum())
+    assert sum(ran[:-1]) < local <= sum(ran)    # each round that ran was due
+    assert routing == "even" or local == 2 * 1024
+    np.testing.assert_allclose(out, reference_moe(cut["params"], h),
+                               rtol=1e-4, atol=1e-5)
+    g = jax.grad(lambda q: layer.apply({"params": q}, h)[0].sum())(
+        cut["params"])
+    for name in ("experts_gate", "experts_up", "experts_down"):
+        assert all(float(jnp.abs(g[name][e]).max()) > 0 for e in range(held))
 
 
 def test_the_scoring_rule_is_a_field_of_the_one_expert_layer():
