@@ -11,8 +11,9 @@ Three surfaces, one unit of account — a frame chunk:
   *frame-age-at-train*, *param-propagation-lag* (seconds) and *policy
   lag* (learner steps since the version a chunk was sent under left).
 * :mod:`apex_tpu.obs.trace` — a bounded, host-only trace-event ring per
-  process, dumped as Chrome trace-event JSON (perfetto-loadable) on
-  exit, periodically, or on SIGUSR2; its ``span`` is the one span
+  process, flushed as Chrome trace-event JSON (perfetto-loadable) on
+  exit, periodically, or on SIGUSR2, each flush a segment file of what
+  is new since the one before; its ``span`` is the one span
   primitive (a ring event and a ``jax.profiler.TraceAnnotation`` over
   the same interval, so the profiler's trace names the same phases on
   the device's clock); :mod:`apex_tpu.obs.merge` aligns

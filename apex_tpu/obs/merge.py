@@ -3,10 +3,13 @@
     python -m apex_tpu.obs.merge TRACE_DIR [-o merged_trace.json]
                                  [--fleet-summary fleet_summary.json]
 
-Each role process dumps ``trace-<label>-<pid>.json`` (Chrome trace-event
-JSON, timestamps already in its own wall-clock microseconds —
-:mod:`apex_tpu.obs.trace`).  Merging is then two corrections plus a
-concatenation:
+Each role process writes one segment ``trace-<label>-<pid>.<n>.json`` a
+flush, holding what it recorded since the flush before (Chrome
+trace-event JSON, timestamps already in its own wall-clock microseconds —
+:mod:`apex_tpu.obs.trace`); runs from before segments left one file a
+process, ``trace-<label>-<pid>.json``, and are read the same way.  A
+process's files are joined into one trace (by the label and pid in their
+metadata).  Merging is then two corrections plus a concatenation:
 
 * **Clock alignment.**  Wall clocks agree on one host but skew across
   hosts.  The learner's registry already measures each peer's offset
@@ -22,7 +25,7 @@ concatenation:
   label matches a peer identity is shifted onto the learner's
   timeline.  Files without a matching peer (the learner itself,
   same-host workers) shift by zero.
-* **Pid remapping.**  Every file becomes one perfetto process group
+* **Pid remapping.**  Every process becomes one perfetto process group
   (sequential pids, ``process_name`` = the role label), so two roles
   that happened to share an OS pid across hosts cannot collide.
 
@@ -36,6 +39,9 @@ import argparse
 import glob
 import json
 import os
+import re
+
+_SEGMENT = re.compile(r"^(.*)\.(\d+)\.json$")
 
 
 def load_offsets(summary: dict) -> dict[str, float]:
@@ -107,21 +113,51 @@ def merge_traces(traces: list[dict],
     }
 
 
+def _flush_order(path: str) -> tuple[str, int]:
+    """A process's segments in the order they were flushed (``.10`` after
+    ``.9``); a single-file dump first."""
+    m = _SEGMENT.match(path)
+    return (m.group(1), int(m.group(2))) if m else (path[:-len(".json")], 0)
+
+
+def load_traces(paths: list[str]) -> list[dict]:
+    """One trace a process: the files that carry the same label and pid
+    in their metadata joined, each metadata event kept once; a file that
+    names no process stands alone.  Unreadable files are skipped."""
+    by_process: dict[object, dict] = {}
+    for p in sorted(paths, key=_flush_order):
+        try:
+            with open(p, "r", encoding="utf-8") as fh:
+                trace = json.load(fh)
+        except (OSError, json.JSONDecodeError) as e:
+            print(f"obs.merge: skipping {p}: {e}")
+            continue
+        meta = trace.get("metadata", {})
+        key = (meta.get("label"), meta.get("pid")) if "pid" in meta else p
+        got = by_process.get(key)
+        if got is None:
+            by_process[key] = dict(trace,
+                                   traceEvents=list(trace["traceEvents"]))
+            continue
+        seen = {(ev.get("name"), ev.get("tid"))
+                for ev in got["traceEvents"] if ev.get("ph") == "M"}
+        got["traceEvents"] += [
+            ev for ev in trace["traceEvents"]
+            if ev.get("ph") != "M" or (ev.get("name"), ev.get("tid"))
+            not in seen]
+    return list(by_process.values())
+
+
 def merge_dir(trace_dir: str, out_path: str,
               fleet_summary: str | None = None) -> dict:
-    """Load every ``trace-*.json`` under ``trace_dir``, align, merge,
-    write ``out_path``.  Returns the merged trace dict."""
-    paths = sorted(glob.glob(os.path.join(trace_dir, "trace-*.json")))
+    """Load every ``trace-*.json`` under ``trace_dir`` (segments and
+    single-file dumps, one trace a process), align, merge, write
+    ``out_path``.  Returns the merged trace dict."""
+    paths = glob.glob(os.path.join(trace_dir, "trace-*.json"))
     if not paths:
         raise FileNotFoundError(f"no trace-*.json files in {trace_dir!r} "
                                 f"(set APEX_TRACE_DIR for the run)")
-    traces = []
-    for p in paths:
-        try:
-            with open(p, "r", encoding="utf-8") as fh:
-                traces.append(json.load(fh))
-        except (OSError, json.JSONDecodeError) as e:
-            print(f"obs.merge: skipping {p}: {e}")
+    traces = load_traces(paths)
     offsets: dict[str, float] = {}
     quality: dict[str, int] = {}
     if fleet_summary is None:
@@ -139,7 +175,7 @@ def merge_dir(trace_dir: str, out_path: str,
             if k in merged["metadata"]["merged_from"]}
     tmp = out_path + ".tmp"
     with open(tmp, "w", encoding="utf-8") as fh:
-        json.dump(merged, fh)
+        fh.write(json.dumps(merged))        # the C encoder; json.dump's is not
     os.replace(tmp, out_path)
     return merged
 

@@ -28,22 +28,37 @@ per-process file is immediately perfetto-loadable and
 :mod:`apex_tpu.obs.merge` only has to apply cross-host skew offsets and
 re-zero the fleet timeline.
 
-Dump triggers: atexit, a periodic flusher thread (every
-``APEX_TRACE_FLUSH_S``, default 10 — so SIGKILLed/terminated roles still
-leave a near-complete trace, the same evidence-survival discipline as
+Flushes: atexit, a periodic flusher thread (every ``APEX_TRACE_FLUSH_S``,
+default 10 — so SIGKILLed/terminated roles still leave everything up to
+their last flush, the same evidence-survival discipline as
 ``fleet_summary.json``), and SIGUSR2 when the process's main thread can
-install handlers.
+install handlers.  A flush writes the events recorded since the previous
+one, and only those, as one segment ``trace-<label>-<pid>.<n>.json`` (a
+whole Chrome trace with the clock anchor; each event formatted straight
+to JSON text, each ``args`` by the C encoder), so its cost follows what
+it writes, not how long the process has run.  The segments a process
+keeps on disk hold at most the ring's capacity of events: past it the
+oldest are deleted.  The flush is itself a span, ``ring_flush``
+(``events``, ``bytes``), on the track ``trace-flush``.
+
+While the ring is enabled, every garbage collection is a span ``gc``
+(``gen``, ``collected``) on the thread that triggered it, from
+``gc.callbacks``; no callback is registered otherwise.
 """
 
 from __future__ import annotations
 
 import atexit
+import gc
 import json
 import os
 import signal
+import sys
 import threading
 import time
 from collections import deque
+
+_ENCODE = json.JSONEncoder(separators=(",", ":")).encode   # the C encoder
 
 #: env knobs (read at ring creation)
 TRACE_DIR_ENV = "APEX_TRACE_DIR"
@@ -71,16 +86,16 @@ _ANNOTATION = None          # jax.profiler.TraceAnnotation, or False
 
 
 def _annotation():
-    """``jax.profiler.TraceAnnotation``, imported on the first live span
-    (this module stays importable without JAX); False where it is not to
-    be had."""
+    """``jax.profiler.TraceAnnotation`` once the process has imported JAX;
+    until then False, and nothing is imported: no profiler can run in a
+    process without JAX, and a span may open inside a garbage
+    collection."""
     global _ANNOTATION
     if _ANNOTATION is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _ANNOTATION = TraceAnnotation
-        except Exception:           # no JAX in this role: ring only
-            _ANNOTATION = False
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return False
+        _ANNOTATION = profiler.TraceAnnotation
     return _ANNOTATION
 
 
@@ -125,12 +140,23 @@ class TraceRing:
         self.enabled = enabled
         # spans are live while the ring records or a profiler trace runs
         self.live = enabled
+        self.capacity = capacity
         self._events: deque[tuple] = deque(maxlen=capacity)
+        # the same events, until a flush takes them off the left
+        self._unflushed: deque[tuple] = deque(maxlen=capacity)
         self._tracks: dict[str, int] = {}
         self._tracks_lock = threading.Lock()
         # wall<->perf anchor: dump converts perf-timebase events to wall
         self._anchor_wall = time.time()
         self._anchor_perf = time.perf_counter()
+        self._flush_lock = threading.Lock()
+        self._flushes = 0
+        self._names: dict[str, str] = {}      # event name -> its JSON text
+        self._segments: deque[tuple[str, int]] = deque()  # (path, events)
+        self._on_disk = 0
+        # events the last flush recorded itself (its span, a collection it
+        # set off): a flush with nothing besides writes nothing
+        self._own = 0
 
     # -- producers (hot-loop safe) ----------------------------------------
 
@@ -149,8 +175,9 @@ class TraceRing:
         """One complete ("X") event on the perf_counter timebase."""
         if not self.enabled:
             return
-        self._events.append(("perf", name, t0_perf, dur_s,
-                             self._tid(track), args))
+        ev = ("perf", name, t0_perf, dur_s, self._tid(track), args)
+        self._events.append(ev)
+        self._unflushed.append(ev)
 
     def span(self, name: str, track: str | None = None,
              args: dict | None = None):
@@ -174,15 +201,18 @@ class TraceRing:
         hops stamped in another process)."""
         if not self.enabled:
             return
-        self._events.append(("wall", name, t0_wall, dur_s,
-                             self._tid(track), args))
+        ev = ("wall", name, t0_wall, dur_s, self._tid(track), args)
+        self._events.append(ev)
+        self._unflushed.append(ev)
 
     def instant(self, name: str, track: str | None = None,
                 args: dict | None = None) -> None:
         if not self.enabled:
             return
-        self._events.append(("perf", name, time.perf_counter(), None,
-                             self._tid(track), args))
+        ev = ("perf", name, time.perf_counter(), None, self._tid(track),
+              args)
+        self._events.append(ev)
+        self._unflushed.append(ev)
 
     # -- dump --------------------------------------------------------------
 
@@ -192,8 +222,11 @@ class TraceRing:
         return self._anchor_wall + (t - self._anchor_perf)
 
     def to_chrome(self) -> dict:
-        """Chrome trace-event JSON (ts/dur in wall microseconds) with the
-        clock anchor + label in metadata."""
+        """Chrome trace-event JSON of the whole ring (ts/dur in wall
+        microseconds) with the clock anchor + label in metadata."""
+        return self._chrome(list(self._events))
+
+    def _chrome(self, items: list[tuple]) -> dict:
         pid = os.getpid()
         events: list[dict] = [
             {"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
@@ -204,7 +237,7 @@ class TraceRing:
         for track, tid in tracks.items():
             events.append({"ph": "M", "pid": pid, "tid": tid,
                            "name": "thread_name", "args": {"name": track}})
-        for timebase, name, t0, dur, tid, args in list(self._events):
+        for timebase, name, t0, dur, tid, args in items:
             ev = {"name": name, "pid": pid, "tid": tid,
                   "ts": round(self._to_wall(timebase, t0) * 1e6, 1)}
             if dur is None:
@@ -226,13 +259,73 @@ class TraceRing:
             },
         }
 
-    def dump(self, path: str) -> None:
-        """Atomic write (readers of a mid-run flush never see a torn
-        file)."""
-        tmp = path + ".tmp"
-        with open(tmp, "w", encoding="utf-8") as fh:
-            json.dump(self.to_chrome(), fh)
-        os.replace(tmp, path)
+    def _encoded(self, items: list[tuple]) -> list[str]:
+        """The events of :meth:`_chrome`, each already JSON text: a flush
+        spends its time under the interpreter lock, and formatting a
+        string is cheaper than building a dict for the encoder."""
+        pid = os.getpid()
+        wall, perf = self._anchor_wall, self._anchor_perf
+        names = self._names
+        out = []
+        for timebase, name, t0, dur, tid, args in items:
+            n = names.get(name)
+            if n is None:
+                n = names[name] = _ENCODE(name)
+            ts = (wall + (t0 - perf) if timebase == "perf" else t0) * 1e6
+            rest = ',"args":' + _ENCODE(args) if args else ""
+            if dur is None:
+                out.append('{"name":%s,"pid":%d,"tid":%d,"ts":%.1f,'
+                           '"ph":"i","s":"t"%s}' % (n, pid, tid, ts, rest))
+            else:
+                out.append('{"name":%s,"pid":%d,"tid":%d,"ts":%.1f,'
+                           '"ph":"X","dur":%.1f%s}'
+                           % (n, pid, tid, ts, dur * 1e6, rest))
+        return out
+
+    def flush(self, directory: str, wait: bool = True) -> str | None:
+        """Write the events recorded since the last flush to a segment of
+        their own under ``directory`` (atomic: a reader never sees a torn
+        file), under a ``ring_flush`` span; delete the oldest segments past
+        the ring's capacity.  Returns the path, or None where there was
+        nothing new, or (``wait`` False) another flush holds the lock."""
+        if not self._flush_lock.acquire(timeout=30.0 if wait else 0):
+            return None
+        try:
+            n = len(self._unflushed)
+            if n <= self._own:
+                return None
+            with self.span("ring_flush", "trace-flush") as span:
+                batch = [self._unflushed.popleft() for _ in range(n)]
+                head = self._chrome([])          # the metadata, the anchor
+                text = ('{"traceEvents":[%s],"displayTimeUnit":"ms",'
+                        '"metadata":%s}' % (",".join(
+                            [_ENCODE(ev) for ev in head["traceEvents"]]
+                            + self._encoded(batch)),
+                            _ENCODE(head["metadata"])))
+                self._flushes += 1
+                path = os.path.join(
+                    directory, f"trace-{self.label.replace('/', '_')}-"
+                               f"{os.getpid()}.{self._flushes}.json")
+                tmp = path + ".tmp"
+                with open(tmp, "w", encoding="utf-8") as fh:
+                    fh.write(text)
+                os.replace(tmp, path)
+                span.note(events=n, bytes=len(text))     # ASCII: one a char
+            own = {self._tid("trace-flush"), self._tid(None)}
+            self._own = sum(1 for ev in list(self._unflushed)
+                            if ev[4] in own)
+            self._segments.append((path, n))
+            self._on_disk += n
+            while self._on_disk > self.capacity:
+                old, k = self._segments.popleft()
+                self._on_disk -= k
+                try:
+                    os.remove(old)
+                except FileNotFoundError:
+                    pass
+            return path
+        finally:
+            self._flush_lock.release()
 
 
 # -- process-global ring ----------------------------------------------------
@@ -246,24 +339,16 @@ def trace_dir() -> str | None:
     return os.environ.get(TRACE_DIR_ENV) or None
 
 
-def _ring_path() -> str | None:
+def dump_ring(wait: bool = True) -> str | None:
+    """Flush what the process ring recorded since its last flush to a new
+    segment file; returns its path (None when disabled or nothing was
+    new).  Never raises — observability must not kill a run."""
     d = trace_dir()
-    if d is None or _RING is None:
-        return None
-    label = _RING.label.replace("/", "_")
-    return os.path.join(d, f"trace-{label}-{os.getpid()}.json")
-
-
-def dump_ring() -> str | None:
-    """Flush the process ring to its trace file; returns the path (None
-    when disabled).  Never raises — observability must not kill a run."""
-    path = _ring_path()
-    if path is None or not _RING.enabled:
+    if d is None or _RING is None or not _RING.enabled:
         return None
     try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        _RING.dump(path)
-        return path
+        os.makedirs(d, exist_ok=True)
+        return _RING.flush(d, wait=wait)
     except OSError:
         return None
 
@@ -274,9 +359,29 @@ def _flusher_loop(interval_s: float) -> None:
         dump_ring()
 
 
+_GC_SPAN = None         # the collection under way (one at a time)
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry: a ``gc`` span from a collection's start to
+    its stop, on the thread that triggered it."""
+    global _GC_SPAN
+    ring = _RING
+    if ring is None:                # reset, or the interpreter shutting down
+        return
+    if phase == "start":
+        _GC_SPAN = ring.span("gc", args={"gen": info["generation"]})
+        _GC_SPAN.__enter__()
+    elif _GC_SPAN is not None:
+        span, _GC_SPAN = _GC_SPAN, None
+        span.note(collected=info["collected"])
+        span.__exit__(None, None, None)
+
+
 def _install_triggers() -> None:
     global _FLUSHER
     atexit.register(dump_ring)
+    gc.callbacks.append(_gc_span)
     interval = float(os.environ.get(FLUSH_ENV, "10"))
     if interval > 0 and _FLUSHER is None:
         _FLUSHER = threading.Thread(target=_flusher_loop, args=(interval,),
@@ -284,8 +389,9 @@ def _install_triggers() -> None:
         _FLUSHER.start()
     try:
         # SIGUSR2 -> on-demand dump (main thread only; worker children
-        # spawned by mp enter here on their own main threads)
-        signal.signal(signal.SIGUSR2, lambda *_: dump_ring())
+        # spawned by mp enter here on their own main threads); it never
+        # waits, for the main thread may hold the flush already
+        signal.signal(signal.SIGUSR2, lambda *_: dump_ring(wait=False))
     except (ValueError, OSError, AttributeError):
         pass                        # non-main thread / platform without it
 
@@ -316,7 +422,11 @@ def set_process_label(label: str) -> None:
 
 
 def reset_for_tests() -> None:
-    """Drop the process-global ring (tests re-enter with fresh env)."""
-    global _RING
+    """Drop the process-global ring and its collection callback (tests
+    re-enter with fresh env)."""
+    global _RING, _GC_SPAN
     with _RING_LOCK:
+        while _gc_span in gc.callbacks:
+            gc.callbacks.remove(_gc_span)
+        _GC_SPAN = None
         _RING = None

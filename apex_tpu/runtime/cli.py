@@ -395,9 +395,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "scrape) so a stock Prometheus server can poll "
                         "directly; 0 = one-shot print")
     p.add_argument("--trace-dir", default=e.get("APEX_TRACE_DIR"),
-                   help="enable the per-role trace ring and dump Chrome "
-                        "trace-event JSON here (atexit/periodic/SIGUSR2); "
-                        "merge a fleet's dumps with "
+                   help="enable the per-role trace ring and flush Chrome "
+                        "trace-event JSON segments here (atexit/periodic/"
+                        "SIGUSR2); merge a fleet's segments with "
                         "`python -m apex_tpu.obs.merge DIR`")
     # misc
     p.add_argument("--logdir", default=e.get("APEX_LOGDIR"))

@@ -602,8 +602,8 @@ class IngestPipeline:
                         item = self.client.poll_batch(timeout=0)
                         if item is not None:
                             self._idle.clear()
-                            with self.ring.span("stage_batch",
-                                                "ingest-staging"):
+                            with self.ring.span("stage", "ingest-staging",
+                                                {"kind": "batch"}):
                                 slot = self._build_batch_slot(item)
                             self._put(slot)
                             continue
@@ -625,13 +625,11 @@ class IngestPipeline:
                     if not msgs:
                         continue
                 self._idle.clear()
-                # named by the slot it built, known only afterwards: a
-                # ring event written after the fact (no annotation)
-                t0 = time.perf_counter()
-                slot = self._build_slot(msgs[0], st)
-                self.ring.complete(f"stage_{slot.kind}", t0,
-                                   time.perf_counter() - t0,
-                                   track="ingest-staging")
+                # the slot's kind is known once it is built: the ring
+                # event gets it, the annotation opened without it
+                with self.ring.span("stage", "ingest-staging") as span:
+                    slot = self._build_slot(msgs[0], st)
+                    span.note(kind=slot.kind)
                 self._put(slot)
         except BaseException as exc:      # surface to poll_slot, loudly
             self._error = exc

@@ -57,10 +57,10 @@ LAUNCH_SHARED="${APEX_LAUNCH_SHARED:-1}"
 # ladder lineages restore the top's checkpoint with a mutated vector.
 export APEX_POPULATION="${APEX_POPULATION:-}"
 
-# Observability (apex_tpu/obs): every role dumps a per-process trace ring
-# (chunk lineage spans, phase/gap events) into APEX_TRACE_DIR — dumped on
-# exit AND flushed periodically, so the actors killed by the EXIT trap
-# still leave near-complete traces.  The learner's fleet_summary.json
+# Observability (apex_tpu/obs): every role flushes a per-process trace ring
+# (chunk lineage spans, phase/gap events) into APEX_TRACE_DIR — on exit AND
+# periodically, each flush a segment of what is new, so the actors killed
+# by the EXIT trap still leave near-complete traces.  The learner's fleet_summary.json
 # lands in the same dir, giving obs.merge the heartbeat-derived clock
 # offsets for the single merged perfetto timeline.
 TRACE_DIR="${APEX_TRACE_DIR:-/tmp/apex-obs-$$}"

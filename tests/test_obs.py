@@ -390,6 +390,123 @@ def test_ring_dump_and_phase_timer_integration(monkeypatch, tmp_path):
         obs_trace.reset_for_tests()
 
 
+def _written(paths) -> list[dict]:
+    """The events (metadata left out) of segment files, in order."""
+    out = []
+    for path in paths:
+        with open(path) as fh:
+            out += [ev for ev in json.load(fh)["traceEvents"]
+                    if ev.get("ph") != "M"]
+    return out
+
+
+def _segment_number(path: str) -> int:
+    return int(path.rsplit(".", 2)[1])
+
+
+def test_flushes_write_each_event_once_and_keep_a_ring_on_disk(tmp_path):
+    ring = TraceRing("learner", enabled=True, capacity=50)
+    paths = []
+    for k in range(3):
+        for i in range(10):
+            ring.complete("phase", 100.0 * k + i, 0.5, track="t",
+                          args={"k": k, "i": i})
+        paths.append(ring.flush(str(tmp_path)))
+    held = [ev for ev in ring.to_chrome()["traceEvents"]
+            if ev.get("ph") != "M"]
+    # together the segments hold what the ring holds, each event once; the
+    # last flush's own span goes out with the next flush
+    assert _written(paths) == held[:-1]
+    assert held[-1]["name"] == "ring_flush"
+    assert [ev["args"]["events"] for ev in held
+            if ev["name"] == "ring_flush"] == [10, 11, 11]
+    for path in paths:                   # each a whole trace, anchored
+        with open(path) as fh:
+            meta = json.load(fh)["metadata"]
+        assert meta["label"] == "learner" and "clock_sync" in meta
+    # past the ring's capacity the oldest segments go first
+    for k in range(3, 9):
+        for i in range(10):
+            ring.complete("phase", 100.0 * k + i, 0.5, track="t")
+        paths.append(ring.flush(str(tmp_path)))
+    kept = sorted((str(p) for p in tmp_path.glob("trace-learner-*.json")),
+                  key=_segment_number)
+    on_disk = _written(kept)
+    assert len(on_disk) <= ring.capacity
+    assert kept == paths[-len(kept):] and not os.path.exists(paths[0])
+    held = [ev for ev in ring.to_chrome()["traceEvents"]
+            if ev.get("ph") != "M"]
+    n = min(len(on_disk), len(held) - 1)
+    assert on_disk[-n:] == held[:-1][-n:]
+    # one segment fewer would have fit: only what had to go went
+    dropped = paths[-len(kept) - 1]
+    assert len(on_disk) + 11 > ring.capacity and not os.path.exists(dropped)
+
+
+def test_a_flush_encodes_what_came_since_the_flush_before(tmp_path):
+    ring = TraceRing("actor-0", enabled=True, capacity=10_000)
+    for _ in range(500):
+        ring.instant("tick", "t")
+    ring.flush(str(tmp_path))
+    # nothing but the first flush's own span is new: nothing is written
+    assert ring.flush(str(tmp_path)) is None
+    for _ in range(37):
+        ring.instant("tick", "t")
+    second = ring.flush(str(tmp_path))
+    flushes = [ev for ev in ring.to_chrome()["traceEvents"]
+               if ev["name"] == "ring_flush"]
+    # 37 new events and the span of the flush before, not the whole ring
+    assert [ev["args"]["events"] for ev in flushes] == [500, 38]
+    assert len(_written([second])) == 38
+    assert flushes[1]["args"]["bytes"] == os.path.getsize(second)
+    assert {ev["tid"] for ev in flushes} == {ring._tid("trace-flush")}
+    # a flush that finds another under way (SIGUSR2 on the thread that
+    # holds it) writes nothing rather than wait for itself
+    ring.instant("tick", "t")
+    ring._flush_lock.acquire()
+    try:
+        assert ring.flush(str(tmp_path), wait=False) is None
+    finally:
+        ring._flush_lock.release()
+
+
+def test_gc_spans_only_while_the_ring_is_enabled(monkeypatch, tmp_path):
+    import gc
+    import threading
+
+    from apex_tpu.obs import trace as obs_trace
+
+    monkeypatch.delenv("APEX_TRACE_DIR", raising=False)
+    obs_trace.reset_for_tests()
+    try:
+        assert not obs_trace.get_ring().enabled
+        assert obs_trace._gc_span not in gc.callbacks
+        monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+        monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+        obs_trace.reset_for_tests()
+        ring = obs_trace.get_ring()
+        assert gc.callbacks.count(obs_trace._gc_span) == 1
+        was_on = gc.isenabled()
+        gc.disable()                 # no automatic collection frees it first
+        try:
+            junk = [[] for _ in range(3)]
+            for a, b in zip(junk, junk[1:] + junk[:1]):
+                a.append(b)                      # a cycle for it to free
+            del junk, a, b
+            gc.collect()
+        finally:
+            if was_on:
+                gc.enable()
+        last = [ev for ev in ring.to_chrome()["traceEvents"]
+                if ev["name"] == "gc"][-1]
+        assert last["args"]["gen"] == 2 and last["args"]["collected"] >= 3
+        assert last["tid"] == threading.get_ident() % 100_000
+        assert last["dur"] >= 0
+    finally:
+        obs_trace.reset_for_tests()
+    assert obs_trace._gc_span not in gc.callbacks
+
+
 # -- merge: clock alignment --------------------------------------------------
 
 def _fake_trace(label: str, events: list[tuple[str, float, float]]) -> dict:
@@ -452,6 +569,30 @@ def test_merge_dir_uses_fleet_summary_offsets(tmp_path):
     assert merged["traceEvents"][-1]["ts"] == pytest.approx(1e6)
     # estimate quality rides the merged metadata for triage
     assert merged["metadata"]["offset_samples"] == {"actor-0": 9}
+
+
+def test_merge_dir_joins_segments_and_reads_an_older_single_file(tmp_path):
+    ring = TraceRing("learner", enabled=True)
+    ring.complete("a", 1.0, 0.1, track="loop")
+    ring.flush(str(tmp_path))
+    ring.complete("b", 2.0, 0.1, track="loop")
+    ring.flush(str(tmp_path))
+    # a run from before segments: one file a process
+    with open(tmp_path / "trace-actor-0-77.json", "w") as fh:
+        json.dump(_fake_trace("actor-0", [("c", 1.5, 0.1)]), fh)
+    merged = obs_merge.merge_dir(str(tmp_path), str(tmp_path / "m.json"))
+    assert merged["metadata"]["merged_from"] == ["actor-0", "learner"]
+    events = merged["traceEvents"]
+    names = {ev["name"] for ev in events if ev.get("ph") == "X"}
+    assert names == {"a", "b", "c", "ring_flush"}
+    # one process group each, its metadata once however many segments
+    assert {ev["pid"] for ev in events} == {1, 2}
+    learner = [ev for ev in events if ev["pid"] == 2 and ev.get("ph") == "M"]
+    assert sorted(ev["name"] for ev in learner) == [
+        "process_name", "thread_name", "thread_name"]
+    # the last flush's own span is in no segment yet
+    assert sum(1 for ev in events if ev.get("ph") == "X"
+               and ev["name"] == "ring_flush") == 1
 
 
 def test_merge_cli_main(tmp_path, capsys):

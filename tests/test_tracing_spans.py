@@ -298,15 +298,45 @@ def test_pipelined_loop_spans_and_train_alone_kind(tmp_path, monkeypatch):
     kinds = {ev["args"]["kind"] for ev in events
              if ev["name"] == "loop_iter"}
     assert "train" in kinds and ("fused" in kinds or "ingest" in kinds)
-    staged = {ev["name"] for ev in _on_track(chrome, "ingest-staging")}
-    # the staging thread's events keep the names an operator knows
-    assert staged & {"stage_single", "stage_merged"}
-    assert staged <= {"stage_single", "stage_merged", "stage_scan",
-                      "stage_batch", "publish", "prio_writeback"}
+    on_staging = _on_track(chrome, "ingest-staging")
+    staged = {ev["name"] for ev in on_staging}
+    # the staging thread's events keep the names an operator knows; a
+    # slot's build is one ``stage`` span that carries the slot's kind
+    assert staged <= {"stage", "publish", "prio_writeback"}
+    kinds = {ev["args"]["kind"] for ev in on_staging if ev["name"] == "stage"}
+    assert kinds & {"single", "merged"}
+    assert kinds <= {"single", "merged", "scan", "batch"}
     _host_gap_leaves_out_the_dispatch_interval(events)
     sizes = {name: getattr(trainer, name)._cache_size()
              for name in ("_fused", "_train")}
     assert set(sizes.values()) <= {0, 1}            # no second program
+
+
+def test_the_chunk_path_builds_each_slot_under_one_stage_span(monkeypatch):
+    """The staging thread's slot build is a span (ring event AND profiler
+    annotation, so it is on the host plane), given the slot's kind once
+    the build knows it; no ``stage_<kind>`` event is written after the
+    fact."""
+    from apex_tpu.training.ingest_pipeline import IngestPipeline
+
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _FakeAnnotation)
+    _FakeAnnotation.entered = []
+    pipe = IngestPipeline(ScriptedPool(_scripted_messages(4)), depth=4,
+                          merge_max=1, put_device=False)
+    pipe.ring = TraceRing("learner", enabled=True)
+    pipe.start()
+    try:
+        got = [pipe.poll_slot(timeout=5.0) for _ in range(4)]
+    finally:
+        pipe.stop()
+    assert all(slot is not None for slot in got)
+    events = [ev for ev in pipe.ring.to_chrome()["traceEvents"]
+              if ev.get("ph") == "X"]
+    stages = [ev for ev in events if ev["name"] == "stage"]
+    assert len(stages) == len(got)
+    assert [ev["args"]["kind"] for ev in stages] == [s.kind for s in got]
+    assert not [ev for ev in events if ev["name"].startswith("stage_")]
+    assert _FakeAnnotation.entered.count(("stage", {})) == len(got)
 
 
 def test_scan_dispatch_splits_its_keys_outside_the_gap(tmp_path, monkeypatch):
@@ -941,6 +971,157 @@ def test_idle_seconds_by_span_follow_the_alignment():
     assert {k: round(v * 1e6, 3) for k, v in by_span.items()} == {
         "dispatch_key": 10.0, "beta": 20.0, "dispatch": 20.0}
     assert loose == 25 * ps
+
+
+# -- the host's other threads: gc, the trace exporter, staging -------------------------
+
+HOST_METRICS = ("ring_flush_share_pct", "gc_pause_share_pct",
+                "idle_staging_pct")
+
+
+@pytest.fixture(scope="module")
+def threads_trace(tmp_path_factory):
+    """One pass of the loop beside the staging thread and the trace
+    exporter, times in microseconds: the device busy in four runs of 100,
+    so idle 100-300, 400-700 and 800-1000."""
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(1)", []), 10: ("%fusion = f32[8]", []),
+    }, {
+        "XLA Modules": (0, [(1, 0, 1100, [])]),
+        "XLA Ops": (0, [(10, 0, 100, []), (10, 300, 100, []),
+                        (10, 700, 100, []), (10, 1000, 100, [])]),
+    })
+    host = _plane("/host:CPU", {
+        1: ("loop_iter", []), 2: ("dispatch", []), 3: ("adopt", []),
+        4: ("beta", []), 5: ("gc", []), 6: ("stage", []), 7: ("publish", []),
+        8: ("ring_flush", []),
+    }, {
+        "python3": (0, [(1, 0, 1100, []), (2, 50, 100, []), (3, 120, 30, []),
+                        (4, 400, 100, []), (5, 450, 30, [])]),
+        "apex-ingest-staging": (0, [(6, 150, 100, []), (5, 200, 20, []),
+                                    (7, 600, 50, [])]),
+        "apex-trace-flush": (0, [(8, 820, 80, [])]),
+    })
+    path = tmp_path_factory.mktemp("threads") / "threads.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, host)))
+    return spans.read_xspace(str(path))
+
+
+def test_idle_seconds_by_the_loop_and_the_other_threads(threads_trace):
+    from benchmark import host_threads
+
+    red = host_threads.idle_by_threads(threads_trace)
+    us = 1e-6
+    assert red["idle_s"] == pytest.approx(700 * us)
+    # stage (a gc inside it included) and publish open on the staging line
+    assert red["staging_s"] == pytest.approx(150 * us)
+    got = {k: round(v / us, 3) for k, v in red["pairs"]}
+    assert got == {
+        ("loop_iter (own)", "-"): 320.0, ("loop_iter (own)", "stage"): 80.0,
+        ("loop_iter (own)", "ring_flush"): 80.0,
+        ("loop_iter (own)", "publish"): 50.0,
+        ("loop_iter (own)", "gc"): 20.0, ("beta", "-"): 70.0,
+        ("beta", "gc"): 30.0, ("adopt", "-"): 30.0, ("dispatch", "-"): 20.0}
+    assert sum(got.values()) == pytest.approx(700.0)
+    # the loop's own reduction is not moved by the new annotations
+    host = spans.host_attribution(threads_trace)
+    assert host["idle_s"] == pytest.approx(700 * us)
+    assert host["annotations"] == {"loop_iter": 1, "dispatch": 1,
+                                   "adopt": 1, "beta": 1}
+    # a trace from before ``stage`` was a span reads nothing
+    small = spans.read_xspace(os.path.join(FIXTURES, "scoped.xplane.pb"))
+    assert host_threads.idle_by_threads(small) is None
+
+
+def _x(name, ts, dur, tid=1000, **args):
+    return {"name": name, "ph": "X", "tid": tid, "ts": ts, "dur": dur,
+            "args": args}
+
+
+def test_gc_and_flush_reductions_on_hand_made_ring_events():
+    from benchmark import host_threads
+
+    events = [
+        _x("loop_iter", 0, 1000, it=1, kind="fused"),
+        _x("dispatch", 100, 200, it=1, program="jit_fused_step"),
+        _x("adopt", 250, 50, it=1),
+        _x("gc", 120, 20, tid=77, gen=0, collected=5),
+        _x("gc", 260, 30, tid=77, gen=0, collected=0),
+        _x("gc", 500, 60, tid=88, gen=2, collected=100),
+        _x("gc", 1100, 10, tid=77, gen=1, collected=1),
+        # the wait for the update before last is a phase of its own here
+        _x("in_flight_wait", 700, 100, it=1),
+        _x("gc", 750, 10, tid=77, gen=0, collected=0),
+        _x("ring_flush", 2000, 2000, tid=1003, events=900, bytes=90_000),
+        _x("ring_flush", 9000, 3000, tid=1003, events=1100, bytes=110_000),
+    ]
+    gc_red = host_threads.gc_pauses(events)
+    assert gc_red["n"] == 5 and gc_red["s"] == pytest.approx(130e-6)
+    assert {k: round(v * 1e6, 3) for k, v in gc_red["by_phase"].items()} == {
+        "loop_iter (own)": 60.0, "adopt": 30.0, "dispatch": 20.0,
+        "outside any pass": 10.0, "in_flight_wait": 10.0}
+    assert gc_red["by_gen"] == {
+        0: {"n": 3, "s": pytest.approx(60e-6), "collected": 5},
+        1: {"n": 1, "s": pytest.approx(10e-6), "collected": 1},
+        2: {"n": 1, "s": pytest.approx(60e-6), "collected": 100}}
+    flush = host_threads.flushes(events)
+    assert flush == {"n": 2, "s": pytest.approx(5e-3),
+                     "longest_s": pytest.approx(3e-3), "events": 2000,
+                     "bytes": 200_000}
+    # the loop's own reduction does not count them
+    assert set(spans.loop_phases(events)["by_name"]) == {
+        "loop_iter", "dispatch", "adopt"}
+    parent = [ev for ev in events if ev["name"] not in ("gc", "ring_flush")]
+    assert host_threads.gc_pauses(parent) is None
+    assert host_threads.flushes(parent) is None
+
+
+@pytest.mark.parametrize("name,want", [
+    ("ring_flush_share_pct", 0.5), ("gc_pause_share_pct", 0.012),
+    ("idle_staging_pct", 100.0 * 150 / 700)])
+def test_the_host_thread_readers(name, want, threads_trace, monkeypatch):
+    """Each reader on the synthetic ring and plane, and on what a parent
+    without the spans leaves: nothing, and no error."""
+    events = [
+        _x("loop_iter", 0, 1000, it=1, kind="fused"),
+        _x("gc", 500, 120, tid=88, gen=2, collected=100),
+        _x("ring_flush", 2000, 2000, tid=1003, events=900, bytes=90_000),
+        _x("ring_flush", 9000, 3000, tid=1003, events=1100, bytes=110_000),
+    ]
+    said = []
+    monkeypatch.setattr(spans, "load", lambda c: c["_spans"])
+    ctx = dict(_spans={"planes": threads_trace, "ring": events},
+               window_s=1.0, say=said.append)
+    assert _reader(name).read(ctx) == pytest.approx(want)
+    assert said                                 # its breakdown on stderr
+    bare = dict(ctx, _spans={"planes": None, "ring": events[:1]})
+    assert _reader(name).read(bare) is None
+
+
+@pytest.mark.parametrize("name", HOST_METRICS)
+def test_host_thread_reader_returns_none_without_ring_or_trace(
+        name, monkeypatch):
+    monkeypatch.delenv("APEX_TRACE_DIR", raising=False)
+    obs_trace.reset_for_tests()
+    ctx = dict(window_s=51.0, open={"wall": 0.0}, close={"wall": 4e9},
+               trace=None, traced_s=None, say=[].append)
+    try:
+        assert _reader(name).read(ctx) is None
+    finally:
+        obs_trace.reset_for_tests()
+
+
+def test_benchmark_json_lists_the_host_thread_readers_for_the_hostfed_cell():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(HOST_METRICS[0])
+    assert at > names.index("delta_roofline")   # after what was there
+    tail = bench["per_layer"][at:at + len(HOST_METRICS)]
+    assert [m["name"] for m in tail] == list(HOST_METRICS)
+    assert all(m["workloads"] == ["dqn_hostfed"] for m in tail)
+    assert [m["layer"] for m in tail] == ["Trainer loop", "Trainer loop",
+                                          "Ingest staging"]
 
 
 def test_device_scopes_account_for_the_step_programs(synthetic_trace):
