@@ -5,14 +5,16 @@ A ``model_spec`` is the plain dict that travels to worker processes and
 into checkpoint metadata.  Without a ``torso`` key it is today's dueling
 network's keyword arguments, unchanged; with one it names a preset of a
 token-torso family (:mod:`apex_tpu.models.glm4_moe_lite`,
-:mod:`apex_tpu.models.nemotron_h`, :mod:`apex_tpu.models.qwen3_next`),
-found by the preset's name.  The families share ONE expert layer
+:mod:`apex_tpu.models.nemotron_h`, :mod:`apex_tpu.models.qwen3_next`,
+:mod:`apex_tpu.models.lfm2_moe`), found by the preset's name.  The four
+families share ONE expert layer
 (:class:`apex_tpu.models.glm4_moe_lite.MoE`); what a model's differs by
 is a field of it, among them the **scoring rule** (how the router's
 outputs become picks and weights: ``sigmoid_bias``, sigmoid scores ranked
 with a selection-only learned bias, or ``softmax`` over all outputs, no
-bias) and the **shared gate** (whether the shared expert's output is
-multiplied by ``sigmoid(h w_g)``, one learned scalar a token).
+bias), the **shared gate** (whether the shared expert's output is
+multiplied by ``sigmoid(h w_g)``, one learned scalar a token) and
+``n_shared_experts`` (LFM2's layer has none).
 """
 
 from __future__ import annotations
@@ -28,11 +30,13 @@ def _token_torsos() -> dict[str, tuple[type, dict]]:
     """``{preset name: (module class, preset)}`` over the token-torso
     families: a family is a module with ``PRESETS`` and the class that
     reads them."""
-    from apex_tpu.models import glm4_moe_lite, nemotron_h, qwen3_next
+    from apex_tpu.models import (glm4_moe_lite, lfm2_moe, nemotron_h,
+                                 qwen3_next)
     return {name: (cls, preset) for module, cls in (
         (glm4_moe_lite, glm4_moe_lite.Glm4MoeLiteQ),
         (nemotron_h, nemotron_h.NemotronHQ),
-        (qwen3_next, qwen3_next.Qwen3NextQ))
+        (qwen3_next, qwen3_next.Qwen3NextQ),
+        (lfm2_moe, lfm2_moe.Lfm2MoeQ))
         for name, preset in module.PRESETS.items()}
 
 

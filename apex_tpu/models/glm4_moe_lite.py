@@ -251,15 +251,18 @@ class MLA(_Base):
         return mm("bhtd,hdf->btf", o, w_o.reshape(nh, vd, d))
 
 
-def expert_rounds(pairs: int, held: int,
-                  routed: int) -> tuple[tuple[int, ...], ...]:
+def expert_rounds(pairs: int, held: int, routed: int,
+                  quarters: int = 1) -> tuple[tuple[int, ...], ...]:
     """The rows of each round of :meth:`MoE.routed` over a call's
     ``pairs`` (token, expert) pairs, which lie sorted with the held
     experts' first, grouped by the ``lax.cond`` that runs them; together
     the rounds cover every pair.  Round 0 runs always: twice the held
     experts' even share of the pairs (``held / routed``), up to a multiple
     of a grouped product's best row tile (:data:`apex_tpu.ops.grouped.BEST`,
-    so the tiled kernel keeps it), a quarter of the pairs at most.  The
+    so the tiled kernel keeps it), ``quarters`` quarters of the pairs at
+    most (two where the held experts' even share is itself a quarter, as
+    in LFM2: capped at one quarter, whether round 1 runs would turn on a
+    few pairs either side of the mean, and with it an update's work).  The
     later rounds hold a quarter each, the last what is left; each group
     runs only when routing filled the rounds before it.  Every group hands
     back a gradient of each stacked expert kernel, so there are at most
@@ -269,7 +272,8 @@ def expert_rounds(pairs: int, held: int,
     took 0.2-0.36 GB more of the update's temporaries, rounds larger than
     a quarter 0.02-0.14)."""
     quarter, tile = max(pairs // 4, 1), grouped.BEST
-    rounds = [min(-(-2 * pairs * held // (routed * tile)) * tile, quarter)]
+    rounds = [min(-(-2 * pairs * held // (routed * tile)) * tile,
+                  max(pairs * quarters // 4, 1))]
     while sum(rounds) < pairs:
         rounds.append(min(quarter, pairs - sum(rounds)))
     groups = [(rows,) for rows in rounds[:3]]
@@ -300,8 +304,9 @@ def no_routing(held: int):
 
 
 class MoE(_Base):
-    """One shared expert and this chip's share of the routed experts, for
-    every token torso: what differs between models is a field (``act``:
+    """One shared expert (or none) and this chip's share of the routed
+    experts, for every token torso: what differs between models is a field
+    (``n_shared_experts``: 1, or 0 for a model without one; ``act``:
     gated three-matrix ``silu`` experts or two-matrix ``relu^2`` ones; the
     shared expert's width; the counts; the scaling; ``scoring``, the rule
     that turns the router's outputs into picks and weights, see
@@ -320,6 +325,8 @@ class MoE(_Base):
     shared_width: int = 0       # 0 = the routed experts' width
     scoring: str = "sigmoid_bias"       # or "softmax" (:meth:`route`)
     shared_gate: bool = False   # the shared expert times sigmoid(h w_g)
+    n_shared_experts: int = 1   # or 0: no shared expert, no such leaves
+    round0_quarters: int = 1    # round 0's most rows (:func:`expert_rounds`)
 
     def grouped(self, xs, w, group_sizes, live, tiles=None):
         """``xs[i] @ w[g(i)]`` -> float32 for the rows the groups cover,
@@ -379,7 +386,7 @@ class MoE(_Base):
         """``(first pair, rows of each round)`` of each ``lax.cond`` of
         :meth:`routed` over a call's ``pairs`` (:func:`expert_rounds`)."""
         groups = expert_rounds(pairs, self.n_held_experts,
-                               self.n_routed_experts)
+                               self.n_routed_experts, self.round0_quarters)
         return [(sum(map(sum, groups[:g])), group)
                 for g, group in enumerate(groups)]
 
@@ -440,12 +447,16 @@ class MoE(_Base):
     def __call__(self, h32):
         b, t, d = h32.shape
         dt = self.compute_dtype
-        with jax.named_scope("shared_expert"):
-            y = FeedForward(dt, self.shared_width or self.width, 0, self.act,
-                            name="shared")(h32)
-            if self.shared_gate:
-                y = y * jax.nn.sigmoid(
-                    Linear(dt, 1, name="shared_gate")(h32, jnp.float32))
+        if self.n_shared_experts not in (0, 1):
+            raise ValueError(f"{self.n_shared_experts} shared experts")
+        y = None
+        if self.n_shared_experts:
+            with jax.named_scope("shared_expert"):
+                y = FeedForward(dt, self.shared_width or self.width, 0,
+                                self.act, name="shared")(h32)
+                if self.shared_gate:
+                    y = y * jax.nn.sigmoid(
+                        Linear(dt, 1, name="shared_gate")(h32, jnp.float32))
         # router: scores, picks, and the sort / gather / scatter that take
         # pairs to their experts and back (``experts``: the products alone)
         with jax.named_scope("router"):
@@ -465,7 +476,8 @@ class MoE(_Base):
         overflow = jnp.zeros((), jnp.int32)
         for a, group in self.rounds(picks.size)[1:]:
             overflow += len(group) * (n_local > a).astype(jnp.int32)
-        return y + part.reshape(b, t, d), (counts, overflow)
+        part = part.reshape(b, t, d)
+        return part if y is None else y + part, (counts, overflow)
 
 
 class Block(_Base):

@@ -50,12 +50,24 @@ LANES = 128
 BLOCK = 512
 
 
+#: head widths under :data:`LANES` the kernel takes: its row statistics
+#: are 128 lanes wide and it slices them to a narrower head
+#: (``flash_attention.py``'s ``l_broadcast``); 64 is LFM2-8B-A1B's
+#: (``lfm2_moe``), the only such width a token torso has at published size
+NARROW = (64,)
+
+
 def kernel_eligible(context: int, qk_head_dim: int, v_head_dim: int) -> bool:
     """What the kernel takes: a context that is a multiple of 128 and of
-    its block, head widths that are multiples of 128, and ``q k^T``'s
-    width equal to ``v``'s."""
+    its block; ``q k^T``'s width equal to ``v``'s; and that width a
+    multiple of 128 (a head fills whole vector registers) or one of
+    :data:`NARROW` (half a register, sliced in the kernel; checked against
+    ``plain`` in interpret mode, forward and gradients, and compiled by
+    Mosaic for a v5e).  Other narrow widths, the toy presets' among them,
+    take ``plain``."""
     return (context % LANES == 0 and context % min(BLOCK, context) == 0
-            and qk_head_dim % LANES == 0 and qk_head_dim == v_head_dim)
+            and (qk_head_dim % LANES == 0 or qk_head_dim in NARROW)
+            and qk_head_dim == v_head_dim)
 
 
 def _blocks(context: int) -> _flash.BlockSizes:

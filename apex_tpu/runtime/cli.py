@@ -94,7 +94,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "softmax-routed experts) at published widths, one "
                         "of 16 chips' share of each layer, "
                         "'qwen3_next_tiny' its toy "
-                        "(apex_tpu/models/qwen3_next.py)")
+                        "(apex_tpu/models/qwen3_next.py); "
+                        "'lfm2_8b_a1b_ep4' is LFM2-8B-A1B's stack "
+                        "(double-gated short convolutions and QK-norm "
+                        "grouped-query attention, a dense layer, then 4 "
+                        "of 32 sigmoid-routed experts and no shared "
+                        "expert; tied head) at published widths, one of 4 "
+                        "chips' share of each layer, 'lfm2_tiny' its toy "
+                        "(apex_tpu/models/lfm2_moe.py)")
     p.add_argument("--token-vocab", type=int, default=0,
                    help="ApexTokens-v0 under a token torso: the ids the "
                         "env draws from, which are the actions and the "

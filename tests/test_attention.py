@@ -5,7 +5,8 @@ The kernel runs here in Pallas's TPU interpret mode (the same kernel body,
 interpreted on the CPU) at shapes that are eligible but small: one where a
 row of blocks is the whole context (the kernel's single-step form) and one
 where the online softmax walks several blocks with some above the diagonal
-skipped.  ``tests/test_glm4_moe_lite.py`` holds the compiled program.
+skipped, each at a head width of 128 and of 64.
+``tests/test_glm4_moe_lite.py`` holds the compiled program.
 """
 
 from __future__ import annotations
@@ -23,9 +24,13 @@ from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 from apex_tpu.models import glm4_moe_lite as glm  # noqa: E402
 from apex_tpu.ops import attention  # noqa: E402
 
-#: ``[b, H, T, d]``: one block a row; several blocks a row
+#: ``[b, H, T, d]``: one block a row; several blocks a row; both again at
+#: LFM2-8B-A1B's head width of 64, which the kernel slices its 128-lane
+#: row statistics to
 SHAPES = {"one_block": (2, 2, 256, 128),
-          "online": (1, 2, 2 * attention.BLOCK, 128)}
+          "online": (1, 2, 2 * attention.BLOCK, 128),
+          "one_block_narrow": (2, 2, 256, 64),
+          "online_narrow": (1, 2, 2 * attention.BLOCK, 64)}
 #: largest distance allowed, as a share of the largest plain value: float32
 #: differs by the order of its sums alone; bfloat16 operands round the
 #: probabilities at another point (after the row sum here, before it there)
@@ -87,6 +92,10 @@ def widths(preset: dict) -> tuple[int, int, int]:
     ("a context the blocks do not divide", 1024 + 128, 256, 256, "tpu", 0),
     ("q.k narrower than v", 1024, 128, 256, "tpu", 0),
     ("a head width of 192", 1024, 192, 192, "tpu", 0),
+    ("LFM2's head width of 64", 1024, 64, 64, "tpu", 1),
+    ("LFM2's head width on a CPU", 1024, 64, 64, "cpu", 0),
+    ("a head width of 32", 1024, 32, 32, "tpu", 0),
+    ("q.k of 64 and v of 128", 1024, 64, 128, "tpu", 0),
     ("a short eligible context", 256, 128, 128, "tpu", 1),
     ("a short eligible context on a GPU", 256, 128, 128, "gpu", 0),
 ])

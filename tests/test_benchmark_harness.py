@@ -4,7 +4,8 @@
 ``tests/`` only) keeps its 17 cases where a ``benchmark`` PR edits them;
 this module imports them, fixtures included, so that every PR runs them.
 Plus the token cells' CPU rehearsals: ``glmq_ondevice``,
-``twotowerq_ondevice`` and ``qnextq_ondevice`` through ``build()``, ``checked_steps()``,
+``twotowerq_ondevice``, ``qnextq_ondevice`` and ``lfm2q_ondevice`` through
+``build()``, ``checked_steps()``,
 ``reference()`` and ``judge()`` read ``correct``, and read not ``correct``
 with the routed experts left out of the program's side.
 """
@@ -72,6 +73,11 @@ def twotowerq():
 @pytest.fixture(scope="module")
 def qnextq():
     return _rehearsal("qnextq_ondevice", 3_500_000_016)
+
+
+@pytest.fixture(scope="module")
+def lfm2q():
+    return _rehearsal("lfm2q_ondevice", 4_100_000_016)
 
 
 def _verdict(run):
@@ -240,3 +246,62 @@ def test_qnextq_ondevice_rehearsal_reads_correct_where_a_pick_flipped(qnextq):
     assert numbers["dparam_gap"][2].endswith("router_kernel")
     assert 0.02 < numbers["dparam_gap"][0] < numbers["dparam_gap"][1]
     assert 0.002 < numbers["dparam_median_gap"][0]
+
+
+def test_lfm2q_ondevice_rehearsal_reads_correct(lfm2q):
+    """The LFM2 cell on the CPU at the toy preset: the family's reference,
+    costs and scope table are found by the config's ``family``, the
+    trainer acts on the device and holds its share, and the three checked
+    updates through the window's own programs agree with the float32
+    reference inside the cell's limits."""
+    run = lfm2q
+    assert run.config["family"] == "lfm2_moe_q"
+    assert run.family.__name__ == "benchmark.reference.lfm2_moe_q"
+    assert run.cfg.learner.torso == "lfm2_tiny"
+    assert run.cfg.env.token_context == 32
+    assert run.cfg.env.token_vocab == run.config["check"]["action_count"]
+    assert type(run.trainer.pool).__name__ == "AnakinPool"
+    layout = run.trainer.model.torso_layout()
+    assert (layout["pattern"], layout["experts"], layout["tied_head"]) == (
+        "CACCC", "2/8", 1)
+    run.checked_steps()
+    ok, numbers = _verdict(run)
+    assert ok, numbers
+    assert set(numbers) == {"writeback_miss", "loss_gap", "grad_median_gap",
+                            "dparam_median_gap", "dparam_gap"}
+    assert run.trainer._fused._cache_size() == 1
+    assert run.trainer._train._cache_size() == 1
+    from benchmark import costs, family_scopes
+    cost = costs.step_cost(run.config)
+    assert cost["params"] == 507_820_288
+    assert costs.program_cost(run.config, dict(
+        learner_steps=1, acting_forwards=16))["flops"] > cost["flops"]
+    table = family_scopes.table_for(run.config["family"])
+    assert {"shortconv", "conv", "qk_norm_attention"} <= set(table.SCOPES)
+    # every catalog key the configuration's file holds is the published
+    # one, or is listed as reduced
+    import json
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "lfm2_8b_a1b_q_ep4")
+    assert set(entry["reduced"]) == set(run.config["reduced_why"])
+    assert set(entry["reduced"]) <= set(run.config["published"])
+
+
+def test_lfm2q_ondevice_without_its_routed_experts_is_not_correct(lfm2q):
+    """The fault ``readings_big.py`` plants swaps the ONE expert layer's
+    ``routed``: with no shared expert, the expert layers then add nothing
+    at all, and the worst leaf's change reads 1.0."""
+    run = lfm2q
+    real = run.trainer._fused, run.trainer._train
+    put_back = _load("readings_big").plant_left_out_experts(run)
+    try:
+        run.reset_state(4_100_000_015)
+        run.checked_steps()
+        ok, numbers = _verdict(run)
+    finally:
+        put_back()
+        run.trainer._fused, run.trainer._train = real
+    assert not ok, numbers
+    assert numbers["dparam_gap"][0] == pytest.approx(1.0)
+    assert numbers["dparam_gap"][0] > numbers["dparam_gap"][1]

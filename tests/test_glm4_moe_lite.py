@@ -238,7 +238,8 @@ def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(
 #: the ``lax.cond`` that runs them, a call of 16 contexts (the update's
 #: passes and the 16-lane acting call; Qwen3-Next's blocks of 8) and of
 #: one (the call that initialises the parameters): round 0 twice the held
-#: share, later rounds a quarter, the fifth in the fourth's cond
+#: share (at most a quarter of the pairs, half in LFM2, whose held share is
+#: a quarter), later rounds a quarter, the fifth in the fourth's cond
 ROUNDS = {
     ("glm47_flash_ep8", 16): ((16384,),) * 4,
     ("glm47_flash_ep8", 1): ((1024,),) * 4,
@@ -248,6 +249,8 @@ ROUNDS = {
     ("qwen3_next_80b_ep16", 16): ((10240,), (20480,), (20480,),
                                   (20480, 10240)),
     ("qwen3_next_80b_ep16", 1): ((1536,), (2560,), (2560,), (2560, 1024)),
+    ("lfm2_8b_a1b_ep4", 16): ((32768,), (16384,), (16384,)),
+    ("lfm2_8b_a1b_ep4", 1): ((2048,), (1024,), (1024,)),
 }
 
 
@@ -257,15 +260,17 @@ def test_the_first_round_holds_twice_the_held_share(monkeypatch, preset,
     """Each expert layer of a published preset, traced at a call's shape:
     the rounds cover every pair, round 0 is the rule's value (twice the
     held experts' even share up to a multiple of 512 rows, a quarter of
-    the pairs at most), no round is larger than a quarter and no more
-    conds hand back gradients than four rounds of a quarter did.  At
+    the pairs at most or the quarters the family gives), no later round
+    is larger than a quarter and no more conds hand back gradients than
+    four rounds of a quarter did.  At
     Nemotron's widths every round is one the tiled kernel takes."""
     from apex_tpu.models import token_preset
     from apex_tpu.ops import grouped
     real, calls = glm.expert_rounds, []
 
-    def spied(pairs, held, routed):
-        calls.append((pairs, held, routed, real(pairs, held, routed)))
+    def spied(pairs, held, routed, quarters=1):
+        calls.append((pairs, held, routed, quarters,
+                      real(pairs, held, routed, quarters)))
         return calls[-1][-1]
 
     monkeypatch.setattr(glm, "expert_rounds", spied)
@@ -277,13 +282,14 @@ def test_the_first_round_holds_twice_the_held_share(monkeypatch, preset,
     # each of the four expert layers asks, for its rounds and its counter
     # (Qwen3-Next's block loop traces a body twice)
     assert len(calls) >= 8
-    for pairs, held, routed, groups in calls:
+    for pairs, held, routed, quarters, groups in calls:
         assert groups == ROUNDS[preset, contexts]
+        assert quarters == (2 if preset.startswith("lfm2") else 1)
         rounds = [rows for group in groups for rows in group]
         assert sum(rounds) == pairs
         assert rounds[0] == min(-(-2 * pairs * held // (routed * 512)) * 512,
-                                pairs // 4)
-        assert max(rounds) <= pairs // 4 and len(groups) <= 4
+                                pairs * quarters // 4)
+        assert max(rounds[1:]) <= pairs // 4 and len(groups) <= 4
         if preset.startswith("nemotron"):
             assert all(grouped.plan(2688, 1856, rows, "tpu") is not None
                        for rows in rounds)

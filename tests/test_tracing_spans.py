@@ -540,7 +540,11 @@ def test_grouped_path_instant_says_what_the_grouped_kernel_is_handed(
     # 512 divides 2,048 and IS the experts' width: left on ``ragged_dot``
     ("qwen3_next_80b_ep16", "tpu",
      dict(hidden=2048, width=512, hidden_handed=2048, width_handed=512,
-          tile_k=512, tile_n=512, impl="ragged_dot"))])
+          tile_k=512, tile_n=512, impl="ragged_dot")),
+    # 1,792 = 2 x 896: the tiled kernel, nothing padded
+    ("lfm2_8b_a1b_ep4", "tpu",
+     dict(hidden=2048, width=1792, hidden_handed=2048, width_handed=1792,
+          tile_k=512, tile_n=896, impl="megablox_gmm"))])
 def test_grouped_path_of_the_presets(torso, platform, want):
     """What each family's published preset says of its grouped products
     in a program compiled for ``platform``."""
@@ -603,6 +607,61 @@ def test_the_third_family_says_its_layout_and_its_paths(
         "site": site, "torso": torso, "fused": 0, "context": 32,
         "qk_head_dim": 16, "v_head_dim": 16, "platform": "cpu"}]
     assert f"grouped_path site={site} torso={torso}" in err
+    assert f"attention_path site={site} torso={torso} fused=0" in err
+
+
+@pytest.mark.parametrize("site", ["trainer", "rollout"])
+def test_the_fourth_family_says_its_layout_and_its_paths(
+        site, tmp_path, monkeypatch, capfd):
+    """Whoever builds the LFM2 torso leaves one ``torso_layout``, one
+    ``grouped_path`` and one ``attention_path`` instant in the ring and a
+    line each on stderr: the mixer pattern, the feed-forward kinds, the
+    experts held over published, no shared expert, the vocabulary held
+    over published and the tied head."""
+    from apex_tpu.config import (ActorConfig, ApexConfig, EnvConfig,
+                                 LearnerConfig, ReplayConfig)
+    from apex_tpu.models.lfm2_moe import PRESETS, param_count
+    from apex_tpu.training.anakin import make_anakin_engine
+    from apex_tpu.training.apex import ApexTrainer
+
+    torso = "lfm2_tiny"
+    cfg = ApexConfig(
+        env=EnvConfig(env_id="ApexTokens-v0", frame_stack=1,
+                      clip_rewards=False, episodic_life=False,
+                      token_context=32),
+        replay=ReplayConfig(capacity=256, warmup=32),
+        learner=LearnerConfig(batch_size=8, compute_dtype="float32",
+                              torso=torso),
+        actor=ActorConfig(n_actors=1, n_envs_per_actor=2, send_interval=16))
+    monkeypatch.setenv("APEX_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("APEX_TRACE_FLUSH_S", "0")
+    obs_trace.reset_for_tests()
+    try:
+        if site == "trainer":
+            ApexTrainer(cfg, pool=ScriptedPool([]), respawn_workers=False)
+        else:
+            make_anakin_engine(cfg, rollout_len=4)
+        events = obs_trace.get_ring().to_chrome()["traceEvents"]
+    finally:
+        obs_trace.reset_for_tests()
+    by_name = {name: [ev["args"] for ev in events if ev.get("name") == name]
+               for name in ("torso_layout", "grouped_path", "attention_path")}
+    err = capfd.readouterr().err
+    vocab = cfg.env.token_vocab
+    assert by_name["torso_layout"] == [{
+        "site": site, "torso": torso, "pattern": "CACCC", "ffn": "DEEEE",
+        "experts": "2/8", "expert_rank": 0, "shared_experts": 0,
+        "vocab": f"{vocab}/65536", "tied_head": 1,
+        "params": param_count(dict(PRESETS[torso], vocab_held=vocab))}]
+    assert (f"torso_layout site={site} torso={torso} pattern=CACCC "
+            "ffn=DEEEE experts=2/8") in err
+    assert by_name["grouped_path"] == [{
+        "site": site, "torso": torso, "hidden": 64, "width": 32,
+        "platform": "cpu", "hidden_handed": 64, "width_handed": 32,
+        "tile_k": 0, "tile_n": 0, "impl": "ragged_dot"}]
+    assert by_name["attention_path"] == [{
+        "site": site, "torso": torso, "fused": 0, "context": 32,
+        "qk_head_dim": 16, "v_head_dim": 16, "platform": "cpu"}]
     assert f"attention_path site={site} torso={torso} fused=0" in err
 
 
@@ -1482,6 +1541,115 @@ def test_the_third_familys_metrics_read_their_scopes(qnext_ctx, metric,
     assert least > 2 * 18 * fam.delta_macs(shapes, 16_384) / 197e12
     assert got == pytest.approx(100.0 * least / 300e-6)
     assert any("torso scopes hold" in line for line in qnext_ctx["said"])
+
+
+@pytest.fixture(scope="module")
+def lfm2_ctx(tmp_path_factory):
+    """The device plane of one update of the LFM2 torso, and the context a
+    metric's reader is handed over it."""
+    from benchmark import costs
+
+    step = "jit(fused_step)/update/loss_grad/"
+    fwd = step + "jvp(Lfm2MoeQ)/checkpoint/layers_0/shortconv/short_conv/"
+    bwd = (step + "transpose(jvp(Lfm2MoeQ))/jvp(Lfm2MoeQ)/checkpoint/"
+           "rematted_computation/layers_2/shortconv/short_conv/")
+    attn = (step + "jvp(Lfm2MoeQ)/checkpoint/layers_1/qk_norm_attention/"
+            "self_attn/")
+    device = _plane("/device:TPU:0", {
+        1: ("jit_fused_step(123)", []),
+        10: ("%fusion.1 = f32[16384,6144] fusion(",
+             [_stat("tf_op", fwd + "in_proj/in_proj.dot/dot_general:")]),
+        11: ("%fusion.2 = f32[16,1024,2048] fusion(",
+             [_stat("tf_op", fwd + "conv/mul:")]),
+        12: ("%fusion.3 = f32[16384,2048] fusion(",
+             [_stat("tf_op", bwd + "conv/add:")]),
+        13: ("%fusion.4 = f32[16384,2048] fusion(",
+             [_stat("tf_op", bwd + "mul:")]),
+        14: ("%fusion.5 = f32[16,32,1024,64] fusion(",
+             [_stat("tf_op", attn + "q_layernorm/mul:")]),
+        15: ("%flash.6 = bf16[16,32,1024,64] custom-call(", [_stat(
+            "tf_op", attn + "cond/branch_0_fun/jit(flash_attention)/"
+            "pallas_call:")]),
+        16: ("%gmm.7 = f32[16384,1792]{1,0} custom-call(", [_stat(
+            "tf_op", step + "jvp(Lfm2MoeQ)/checkpoint/layers_2/feed_forward/"
+            "router/feed_forward.routed/checkpoint/experts/gmm:")]),
+        17: ("%fusion.8 = f32[16384,2048] fusion(", [_stat(
+            "tf_op", step + "jvp(Lfm2MoeQ)/checkpoint/layers_2/feed_forward/"
+            "router/feed_forward.routed/checkpoint/mul:")]),
+        18: ("%fusion.9 = f32[2048] fusion(",
+             [_stat("tf_op", "jit(fused_step)/update/optimizer/mul:")]),
+    }, {
+        "XLA Modules": (0, [(1, 100, 1000, [])]),
+        "XLA Ops": (0, [
+            (10, 100, 100, []), (11, 200, 50, []), (12, 250, 40, []),
+            (13, 290, 60, []), (14, 350, 20, []), (15, 370, 130, []),
+            (16, 500, 120, []), (17, 620, 30, []), (18, 650, 100, [])]),
+    })
+    path = tmp_path_factory.mktemp("lfm2") / "lfm2.xplane.pb"
+    path.write_bytes(_msg((1, device), (1, _plane("/host:metadata", {}, {}))))
+    planes = spans.read_xspace(str(path))
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2_8b_a1b_q_ep4.json")) as f:
+        config = json.load(f)
+    said = []
+    return dict(_spans={"planes": planes, "ring": []},
+                traffic={"step_programs": {
+                    "jit_fused_step": {"learner_steps": 1}}},
+                trace={"busy_s": 650e-6}, config=config,
+                peaks=costs.peaks_for("TPU v5 lite"), say=said.append,
+                said=said)
+
+
+def test_the_fourth_familys_table_places_its_operations(lfm2_ctx):
+    """The short convolution's own operations count under ``shortconv``,
+    the three-tap sum's under ``conv`` inside it (the module's name
+    ``short_conv`` on the path is no scope of the table), the attention
+    layer's and its kernel under ``qk_norm_attention``, the grouped
+    products under ``experts`` by their path."""
+    from benchmark import family_scopes
+
+    table = family_scopes.table_for("lfm2_moe_q")
+    got = family_scopes.reduce_planes(
+        table, lfm2_ctx["_spans"]["planes"], ("jit_fused_step",))[
+        "jit_fused_step"]
+    us = 1e-6
+    assert {k: round(v / us, 2) for k, v in got["scopes"].items() if v} == {
+        "shortconv": 160.0, "conv": 90.0, "qk_norm_attention": 150.0,
+        "experts": 120.0, "router": 30.0}
+    assert {k: round(v / us, 2) for k, v in got["rest"].items()} == {
+        "%fusion.9": 100.0}
+
+
+@pytest.mark.parametrize("metric,want_us", [
+    ("shortconv_ms", 250.0), ("qk_norm_attention_ms", 150.0),
+    ("shortconv_roofline", None)])
+def test_the_fourth_familys_metrics_read_their_scopes(lfm2_ctx, metric,
+                                                      want_us):
+    """``shortconv_ms`` is the scope with ``conv`` inside it,
+    ``qk_norm_attention_ms`` the attention layers', ``shortconv_roofline``
+    the block's least time by the family's counts (compute-bound: the two
+    projections) over its device time; over a run that left no trace each
+    reads nothing and does not raise."""
+    from benchmark import costs
+
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(spans, "load", lambda c: c["_spans"])
+    try:
+        got = _reader(metric).read(lfm2_ctx)
+        bare = dict(lfm2_ctx, _spans={"planes": None, "ring": []})
+        assert _reader(metric).read(bare) is None
+    finally:
+        monkey.undo()
+    if want_us is not None:
+        assert got == pytest.approx(want_us / 1000.0)
+        return
+    fam = costs.family_costs("lfm2_moe_q")
+    shapes = lfm2_ctx["config"]["shapes"]
+    passes = 6 * 4
+    least = 2 * passes * fam.shortconv_macs(shapes, 16_384) / 197e12
+    assert least > fam.shortconv_bytes(shapes, 16_384, passes) / 819e9
+    assert got == pytest.approx(100.0 * least / 250e-6)
+    assert any(line.startswith("shortconv:") for line in lfm2_ctx["said"])
 
 
 @pytest.mark.parametrize("scope", SHARED_SCOPES)
